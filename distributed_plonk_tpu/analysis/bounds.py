@@ -23,7 +23,7 @@ wherever
 Control flow: `lax.scan` / `lax.while_loop` bodies are interpreted to a
 carry fixpoint (join-until-stable, bounded iterations) — a carry whose
 bound keeps growing is itself reported (`scan carry bounds do not
-stabilize`). `pjit` / custom-call wrappers are entered transparently.
+stabilize`). `jit` / custom-call wrappers are entered transparently.
 
 Pallas kernels: a `pallas_call` eqn is entered too — the kernel IS a
 jaxpr. Every input/output/scratch ref becomes one interval cell
@@ -59,6 +59,7 @@ import math
 import numpy as np
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 
 
@@ -271,9 +272,9 @@ _SHAPE_ONLY = {
 
 # calls to enter transparently (sub-jaxpr under params['jaxpr'] or
 # params['call_jaxpr'])
-_CALL_PRIMS = {"pjit", "closed_call", "core_call", "custom_jvp_call",
+_CALL_PRIMS = {"jit", "closed_call", "core_call", "custom_jvp_call",
                "custom_vjp_call", "custom_vjp_call_jaxpr", "remat",
-               "checkpoint", "xla_call", "named_call"}
+               "checkpoint", "named_call"}
 
 _MAX_FIXPOINT_ITERS = 8
 
@@ -304,7 +305,7 @@ class Interpreter:
     # -- environment ----------------------------------------------------------
 
     def _read(self, env, var):
-        if isinstance(var, jax.core.Literal):
+        if isinstance(var, jax.extend.core.Literal):
             return from_concrete(var.val)
         return env[var]
 
@@ -386,7 +387,7 @@ class Interpreter:
         if sub is None and "branches" in p:
             return None
         if sub is not None and not hasattr(sub, "consts"):
-            sub = jax.core.ClosedJaxpr(sub, ())
+            sub = jax.extend.core.ClosedJaxpr(sub, ())
         return sub
 
     def _eqn(self, eqn, ins, env):
@@ -817,7 +818,7 @@ class Interpreter:
         p = eqn.params
         sub = p["jaxpr"]
         if not hasattr(sub, "consts"):
-            sub = jax.core.ClosedJaxpr(sub, ())
+            sub = jax.extend.core.ClosedJaxpr(sub, ())
         nc, nk = p["num_consts"], p["num_carry"]
         consts = ins[:nc]
         carry = list(ins[nc:nc + nk])
@@ -957,7 +958,7 @@ class Interpreter:
                 getattr(gm, "num_index_operands", 0):
             return self._fallback(eqn, ins)
         if not hasattr(sub, "consts"):
-            sub = jax.core.ClosedJaxpr(sub, ())
+            sub = jax.extend.core.ClosedJaxpr(sub, ())
         n_in = gm.num_inputs
         grid = tuple(gm.grid or ())
         invars = sub.jaxpr.invars

@@ -50,6 +50,7 @@ from fractions import Fraction
 
 import numpy as np
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import jax.tree_util as jtu
 
@@ -133,6 +134,16 @@ def _scalar_of(x):
     if a.size != 1:
         raise UnsupportedPrim(f"expected scalar, got shape {a.shape}")
     return a.reshape(-1)[0]
+
+
+def _block_size(dim):
+    """One entry of a pallas BlockMapping.block_shape -> its int extent
+    (jax 0.9 wraps each in pallas.Blocked; other block kinds — squeezed,
+    element-indexed — are not modelled)."""
+    from jax.experimental import pallas as pl
+    if isinstance(dim, pl.Blocked):
+        return int(dim.block_size)
+    raise UnsupportedPrim(f"pallas block dim {dim!r} not modelled")
 
 
 # -- exact scalar ops matching XLA integer semantics ---------------------------
@@ -229,7 +240,7 @@ class ExactInterpreter:
         return [self._read(env, v) for v in jaxpr.outvars]
 
     def _read(self, env, v):
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, jax.extend.core.Literal):
             return to_exact(v.val)
         return env[v]
 
@@ -237,7 +248,7 @@ class ExactInterpreter:
         p = eqn.params
         sub = p.get("jaxpr") or p.get("call_jaxpr") or p.get("fun_jaxpr")
         if sub is not None and not hasattr(sub, "consts"):
-            sub = jax.core.ClosedJaxpr(sub, ())
+            sub = jax.extend.core.ClosedJaxpr(sub, ())
         return sub
 
     def _eqn(self, eqn, ins):
@@ -568,7 +579,7 @@ class ExactInterpreter:
         p = eqn.params
         inner = p["jaxpr"]
         if not hasattr(inner, "consts"):
-            inner = jax.core.ClosedJaxpr(inner, ())
+            inner = jax.extend.core.ClosedJaxpr(inner, ())
         gm = p["grid_mapping"]
         if getattr(gm, "num_index_operands", 0):
             raise UnsupportedPrim("pallas index operands not modelled")
@@ -590,7 +601,7 @@ class ExactInterpreter:
         def block_slices(bm, step):
             cj = bm.index_map_jaxpr
             bidx = self.run(cj, [_obj(i) for i in step])
-            bshape = tuple(bm.block_shape)
+            bshape = tuple(_block_size(d) for d in bm.block_shape)
             return tuple(
                 slice(int(_scalar_of(b)) * n, int(_scalar_of(b)) * n + n)
                 for b, n in zip(bidx, bshape))

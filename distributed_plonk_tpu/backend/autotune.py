@@ -294,6 +294,7 @@ class Autotuner:
         self.seed = seed
         self._deadline = None
         self._data = {}
+        self._errors = {}  # "kind:n" -> [{candidate, error}] of this run
 
     # -- public entry ---------------------------------------------------------
 
@@ -308,6 +309,7 @@ class Autotuner:
         self._deadline = t0 + self.budget_s
         self.metrics.inc("autotune_runs")
         plan = KernelPlan(machine_fingerprint())
+        self._errors = {}
         for n in self.shapes:
             for kind in self.kinds:
                 cell = self._tune_cell(kind, n)
@@ -320,6 +322,8 @@ class Autotuner:
             "run_s": round(time.monotonic() - t0, 3),
             "shapes": self.shapes,
             "platform": self._backend_platform(),
+            "candidate_errors": {k: v for k, v in self._errors.items()
+                                 if v},
         }
         if aot:
             prev = active_plan()
@@ -350,7 +354,8 @@ class Autotuner:
         measured = []  # (seconds, sig_tuple, resolved_params, aux)
         ref = None
         parity_s = None
-        rejects = errors = 0
+        rejects = 0
+        errors = self._errors.setdefault(f"{kind}:{n}", [])
         for cand in candidates:
             if ref is not None and self._out_of_budget():
                 break
@@ -362,11 +367,12 @@ class Autotuner:
             try:
                 with plan_override({(kind, n): cand}):
                     out, dt, aux = self._run_candidate(kind, n, cand)
-            except Exception:  # noqa: BLE001 - a candidate that cannot
-                # build/trace/run is skipped, never fatal to the
-                # calibration pass (e.g. an interpret-mode kernel a
-                # platform refuses)
-                errors += 1
+            except Exception as e:  # noqa: BLE001 - a candidate that
+                # cannot build/trace/run loses its place in the grid,
+                # not the calibration pass — but what it raised rides the
+                # plan's meta["candidate_errors"], so a kernel the
+                # compiler refuses is never a silent skip
+                errors.append({"candidate": dict(cand), "error": repr(e)})
                 self.metrics.inc("autotune_candidate_errors")
                 if ref is None:
                     # the PARITY CORE itself failed: without a
@@ -401,7 +407,7 @@ class Autotuner:
                 "parity_s": round(parity_s, 6),
                 "candidates": len(measured),
                 "parity_rejects": rejects,
-                "errors": errors}
+                "errors": len(errors)}
         # default_s: what the knob-free defaults would have run (the
         # resolved empty-candidate config) — the per-cell record of what
         # the plan is worth on this machine
@@ -460,15 +466,18 @@ class Autotuner:
 
         return jax.default_backend()
 
-    def _pallas_ok(self):
+    def _pallas_ok(self, kind):
         """Pallas kernels join the candidate grid only where they can
-        actually win: on TPU (interpret mode elsewhere is a test
-        vehicle, orders of magnitude off the XLA paths and far too slow
-        to measure inside a calibration budget). DPT_AUTOTUNE_INTERPRET=1
-        forces them in for harness tests."""
+        actually win. The fused multiplier: on TPU, where it compiles in
+        seconds. The fused NTT and MSM kernels: not by platform — on the
+        v5e one MSM shape takes Mosaic 387 s and the NTT kernel has not
+        yet come back at all (CHANGES.md PR 21), either of which would
+        eat a whole calibration budget in one uninterruptible compile.
+        DPT_AUTOTUNE_INTERPRET=1 forces every kind in for harness tests
+        (interpret mode is orders of magnitude off the XLA paths)."""
         if os.environ.get("DPT_AUTOTUNE_INTERPRET") == "1":
             return True
-        return self._backend_platform() == "tpu"
+        return kind == "field" and self._backend_platform() == "tpu"
 
     def _candidates(self, kind, n):
         if kind == "ntt":
@@ -476,7 +485,7 @@ class Autotuner:
 
             grid = [{"kernel": "xla", "radix": r}
                     for r in ntt_jax.RADIX_CHOICES]
-            if self._pallas_ok():
+            if self._pallas_ok(kind):
                 for vmem in (2, 6, 12):
                     for rows in (16, 64):
                         grid.append({"kernel": "pallas", "radix": 4,
@@ -486,7 +495,8 @@ class Autotuner:
             from . import msm_jax
 
             grid = []
-            kernels = ["xla"] + (["pallas"] if self._pallas_ok() else [])
+            kernels = ["xla"] + (["pallas"] if self._pallas_ok(kind)
+                                 else [])
             # the measured context is (n + 3) bases padded even (the
             # prover's blinded-handle width; MsmContext.padded_n) —
             # c_batch applies from 256 padded points up. _resolved uses
@@ -510,7 +520,7 @@ class Autotuner:
         from . import field_jax as FJ
 
         grid = [{"mul": m} for m in ("f32", "u32")]
-        if self._pallas_ok():
+        if self._pallas_ok(kind):
             for tile in (256, 512, 1024):
                 grid.append({"mul": "pallas", "lane_tile": tile})
         del FJ

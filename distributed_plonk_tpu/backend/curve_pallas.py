@@ -298,9 +298,8 @@ def _add_flat(mixed, interpret, *coords):
 
 
 def _dispatch(mixed, parts):
-    from .field_jax import FQ
+    from .field_jax import FQ, pallas_interpret
 
-    interpret = jax.default_backend() != "tpu"
     L = FQ.n_limbs
     shape = jnp.broadcast_shapes(*[p.shape for p in parts])
     lanes = 1
@@ -311,7 +310,7 @@ def _dispatch(mixed, parts):
     for p in parts:
         f = jnp.broadcast_to(p, shape).reshape(L, lanes)
         flat.append(jnp.pad(f, ((0, 0), (0, pad))) if pad else f)
-    out = _add_flat(mixed, interpret, *flat)
+    out = _add_flat(mixed, pallas_interpret(), *flat)
     if pad:
         out = [o[:, :lanes] for o in out]
     return tuple(o.reshape(shape) for o in out)

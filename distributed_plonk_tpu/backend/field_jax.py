@@ -24,41 +24,40 @@ import jax.numpy as jnp
 
 # Persistent compilation cache: limb-arithmetic graphs are large (O(log n)
 # fused stages, ~1k ops each) and compile time dominates cold-start
-# wall-clock. Defer to the standard JAX env knob when the user set it.
-# The cache is partitioned per machine fingerprint: XLA:CPU AOT entries
-# embed host CPU features, and loading another host's entries fails with
-# "machine feature mismatch" warnings (round-2 weakness) — separate
-# subdirectories make every host build/read only its own entries.
-# machine_fingerprint lives in backend/autotune.py now (the calibration
-# artifact key and the compile-cache partition are ONE machine identity);
-# re-exported here for the existing import sites.
+# wall-clock. The cache is partitioned per machine fingerprint: XLA:CPU
+# AOT entries embed host CPU features, and loading another host's entries
+# fails with "machine feature mismatch" warnings — separate subdirectories
+# make every host build/read only its own entries.
+# machine_fingerprint lives in backend/autotune.py (the calibration
+# artifact key and the compile-cache partition are ONE machine identity).
 from .autotune import machine_fingerprint
 from . import autotune
 
 
 def configure_compile_cache(base_dir, min_compile_secs=1.0):
-    """Point JAX's persistent compile cache at `base_dir/<machine_fp>`.
+    """Point JAX's persistent compile cache at `base_dir/<machine_fp>` and
+    return the directory in use.
 
-    Called at import with the repo-local default; the artifact store calls
-    it again (store/warmstart.py) to move the cache under a store root so
-    compiled prover stages ride the same warm-start lifecycle as keys.
-    Returns the per-machine directory, or None when this jax has no
-    persistent-cache config (nothing to wire)."""
+    JAX_COMPILATION_CACHE_DIR, when set, places the cache from outside the
+    program: jax already read it into its config, so this returns that
+    directory and sets no other — the guard lives HERE so no caller can
+    bypass it. Called at import with the checkout-local default; the fleet
+    worker's --store calls it through store.set_jax_cache_env's
+    DPT_JAX_CACHE_DIR so synced compile-cache entries are the ones read."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
     path = os.path.join(base_dir, machine_fingerprint())
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_secs)
-    except Exception:  # pragma: no cover - older jax without these options
-        return None
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", min_compile_secs)
     return path
 
 
-if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-    configure_compile_cache(os.environ.get(
-        "DPT_JAX_CACHE_DIR",
-        os.path.normpath(os.path.join(
-            os.path.dirname(__file__), "..", "..", ".jax_cache"))))
+configure_compile_cache(os.environ.get(
+    "DPT_JAX_CACHE_DIR",
+    os.path.normpath(os.path.join(
+        os.path.dirname(__file__), "..", "..", ".jax_cache"))))
 
 from ..constants import (
     LIMB_BITS,
@@ -270,8 +269,8 @@ def _skew_colsum(m, shift, dtype=jnp.uint32):
 #       constant products).
 #   u32: the round-2 integer path (u32 multiply is an emulation ~50x
 #       below the f32 FMA rate; kept as a reference oracle).
-#   pallas: force the Pallas kernel for any wide-enough shape (interpret
-#       mode off-TPU — slow, test-only).
+#   pallas: force the Pallas kernel for any wide-enough shape (off-TPU
+#       that needs DPT_PALLAS_INTERPRET=1 — slow, test-only).
 MUL_CHOICES = ("pallas", "f32", "u32")
 _MUL_MODE = os.environ.get("DPT_FIELD_MUL", "auto")
 
@@ -319,6 +318,15 @@ def pallas_disabled():
         _pallas_off.v = prev
 
 
+def pallas_interpret():
+    """Whether Pallas kernels run in interpret mode: only when a test ASKS
+    for it (DPT_PALLAS_INTERPRET=1, which tests/conftest.py sets for the
+    CPU suite). The device path never falls into it — with the knob off a
+    pallas_call goes through Mosaic or raises what the compiler said, so a
+    kernel the chip refuses cannot pass as an emulated run."""
+    return os.environ.get("DPT_PALLAS_INTERPRET", "0") != "0"
+
+
 def _use_pallas(shape):
     if getattr(_pallas_off, "v", False):
         return False
@@ -331,6 +339,18 @@ def _use_pallas(shape):
     if mode == "pallas":
         return True
     return jax.default_backend() == "tpu"
+
+
+def pallas_mul_possible():
+    """Whether a mont_mul of ANY width could dispatch the Pallas kernel
+    under the current guard, mode and platform — _use_pallas without the
+    shape (parallel/ntt_mesh decides check_vma with it before it knows
+    what widths the traced body multiplies at)."""
+    if getattr(_pallas_off, "v", False):
+        return False
+    mode = _mul_path()
+    return mode == "pallas" or (mode == "auto"
+                                and jax.default_backend() == "tpu")
 
 
 def pack_limb_pairs(v):

@@ -1,7 +1,7 @@
 """Pallas fused MSM bucket accumulation: VMEM-resident bucket planes.
 
-WHY (BENCH_r05 + scripts/scatter_ab.py round 4): after the radix-4 NTT
-landed, the variable-base MSM is the prover's dominant kernel by an
+WHY (round-5 bench line + scripts/scatter_ab.py round 4): after the
+radix-4 NTT landed, the variable-base MSM is the prover's dominant kernel by an
 order of magnitude (2^20 MSM 49.2 s vs 2^20 NTT 5.6 s), and it runs at
 `mfu_msm_pct` 19.4 against a 63.7% multiplier — ~3x headroom that the
 scatter A/B already attributed to bucket-plane MEMORY TRAFFIC, not the
@@ -37,10 +37,11 @@ Bit-identity: digits, skip/sign derivation, gather, RCB15 add, and
 update replicate the EXACT op sequence of msm_jax._bucket_scan /
 _bucket_scan_signed with fully-reduced canonical intermediates, so the
 output planes are limb-identical to the XLA path at the same group
-width (tests/test_msm_pallas.py), and everything downstream (fold /
-finish / proof bytes) is unchanged. Select DPT_MSM_KERNEL=pallas|xla
-(auto: pallas on TPU); the XLA scan remains the parity/debug core
-exactly like DPT_NTT_RADIX=2.
+width (tests/test_msm_pallas.py in interpret mode; on the v5e in PR 21's
+chip run at the 2^13 commit shape), and everything downstream (fold /
+finish / proof bytes) is unchanged. Select DPT_MSM_KERNEL=pallas; `auto`
+stays on the XLA scan because one shape takes Mosaic 387 s to compile
+(msm_jax._use_pallas_kernel).
 """
 
 import functools
@@ -55,7 +56,7 @@ from jax.experimental import pallas as pl
 from ..constants import FQ_LIMBS
 from . import autotune
 from .curve_pallas import add_mixed_val, consts_env, fq_consts, _mod_sub
-from .field_jax import pack_limb_pairs, unpack_limb_pairs
+from .field_jax import pallas_interpret, unpack_limb_pairs
 
 # op-word encoding shared by the wrapper (XLA side) and the kernel:
 # bits [0, 8) bucket index, bit 8 negate-y, bit 9 skip (zero digit /
@@ -94,6 +95,30 @@ def plane_lanes_cap(n_buckets, packed):
     return max(8, 1 << max(3, cap.bit_length() - 1))
 
 
+def _pack_rows(v):
+    """field_jax.pack_limb_pairs for in-kernel values: the row pairs come
+    from a major-axis reshape (the same split field_pallas._cols_to_limbs
+    lowers with) because the strided v[0::2] lowers to a gather Mosaic
+    refuses. Same words bit for bit."""
+    pairs = v.reshape((v.shape[0] // 2, 2) + v.shape[1:])
+    return pairs[:, 0] | jnp.left_shift(pairs[:, 1], 16)
+
+
+def _lane_repeat(v, mt):
+    """(rows, G) -> (rows, G*mt) with out[:, g*mt + ml] = v[:, g]
+    (jnp.repeat on the lane axis). Built from G lane-broadcast selects:
+    jnp.repeat lowers to a lane reshape Mosaic refuses, while a static
+    one-lane slice broadcast against a lane-range mask is plain VPU work,
+    G (<= 32) cheap passes beside the RCB15 add's ~12 multiplies."""
+    rows, group = v.shape
+    lane = lax.broadcasted_iota(jnp.int32, (1, group * mt), 1)
+    out = jnp.zeros((rows, group * mt), v.dtype)
+    for g in range(group):
+        sel = (lane >= g * mt) & (lane < (g + 1) * mt)
+        out = jnp.where(sel, v[:, g:g + 1], out)
+    return out
+
+
 def _bucket_kernel(sx_ref, sy_ref, ops_ref, ox_ref, oy_ref, oz_ref,
                    px_ref, py_ref, pz_ref, t_ref, *, kc, n_buckets,
                    signed, packed, steps, mt, one_rows):
@@ -113,12 +138,13 @@ def _bucket_kernel(sx_ref, sy_ref, ops_ref, ox_ref, oy_ref, oz_ref,
     @pl.when(s == 0)
     def _init():
         # projective identity (0 : 1 : 0), row-packed like the carries
+        # each row a full-shape splat: broadcasting a (rows, 1, 1) column
+        # over buckets AND lanes at once is not implemented in Mosaic
         zero = jnp.zeros(plane_shape, jnp.uint32)
-        one_col = jnp.concatenate(
-            [jnp.full((1, 1, 1), int(v), jnp.uint32) for v in one_rows],
-            axis=0)
         px_ref[...] = zero
-        py_ref[...] = jnp.broadcast_to(one_col, plane_shape)
+        py_ref[...] = jnp.concatenate(
+            [jnp.full((1,) + plane_shape[1:], int(v), jnp.uint32)
+             for v in one_rows], axis=0)
         pz_ref[...] = zero
 
     ops = ops_ref[...].reshape(1, ops_ref.shape[-1])      # (1, lanes)
@@ -135,14 +161,23 @@ def _bucket_kernel(sx_ref, sy_ref, ops_ref, ox_ref, oy_ref, oz_ref,
     # would multiply the sum bound by B
     hit = (lax.broadcasted_iota(jnp.uint32, (1,) + plane_shape[1:], 1)
            == idx[:, None, :])
-    cur_p = tuple(
-        jnp.sum(jnp.where(hit, r[...], 0), axis=1, dtype=jnp.uint32)
-        for r in (px_ref, py_ref, pz_ref))
+
+    def gather(word):
+        # summed as i32: Mosaic has no unsigned reduction. Every operand
+        # is a 16-bit limb, so the conversion is exact, and it comes
+        # BEFORE the select so the masked sum keeps its one-hot shape
+        return jnp.sum(jnp.where(hit, word.astype(jnp.int32), 0), axis=1)
+
+    planes = tuple(r[...] for r in (px_ref, py_ref, pz_ref))
     if packed:
-        cur = tuple(unpack_limb_pairs(c) for c in cur_p)
+        # the two limbs of each packed word are gathered apart (a packed
+        # word can exceed 2^31) and interleaved as unpack_limb_pairs does
+        cur = tuple(
+            jnp.stack([gather(w & 0xFFFF), gather(jnp.right_shift(w, 16))],
+                      axis=1).reshape(2 * plane_shape[0], plane_shape[2])
+            for w in planes)
     else:
-        cur = cur_p
-    cur = tuple(c.astype(jnp.int32) for c in cur)
+        cur = tuple(gather(w) for w in planes)
 
     sx = sx_ref[...].reshape(FQ_LIMBS, sx_ref.shape[-1]).astype(jnp.int32)
     sy = sy_ref[...].reshape(FQ_LIMBS, sy_ref.shape[-1]).astype(jnp.int32)
@@ -150,17 +185,16 @@ def _bucket_kernel(sx_ref, sy_ref, ops_ref, ox_ref, oy_ref, oz_ref,
         # negate once per point tile (the XLA scan's FJ.neg), select per
         # lane after the window broadcast
         nsy = _mod_sub(jnp.zeros_like(sy), sy, L, k["p_col"])
-        qy = jnp.where(negb, jnp.repeat(nsy, mt, axis=1),
-                       jnp.repeat(sy, mt, axis=1))
+        qy = jnp.where(negb, _lane_repeat(nsy, mt), _lane_repeat(sy, mt))
     else:
-        qy = jnp.repeat(sy, mt, axis=1)
-    sxb = jnp.repeat(sx, mt, axis=1)
+        qy = _lane_repeat(sy, mt)
+    sxb = _lane_repeat(sx, mt)
 
     res = add_mixed_val(t_ref, k, cur, (sxb, qy))
     nv = tuple(jnp.where(skipb, c, r).astype(jnp.uint32)
                for c, r in zip(cur, res))
     if packed:
-        nv = tuple(pack_limb_pairs(v) for v in nv)
+        nv = tuple(_pack_rows(v) for v in nv)
     for r, v in zip((px_ref, py_ref, pz_ref), nv):
         r[...] = jnp.where(hit, v[:, None, :], r[...])
 
@@ -204,13 +238,17 @@ def _bucket_call(interpret, group, n_buckets, signed, packed, mt, wt,
                                         jnp.uint32)] * 3,
         grid=(wt, steps),
         in_specs=[pt_spec, pt_spec,
-                  pl.BlockSpec((1, 1, lanes), lambda w, s: (w, s, 0))],
+                  # (wt, steps, 1, lanes): a unit sublane axis makes the
+                  # block's last two dims equal the array's, which is
+                  # what the Mosaic block-shape rule asks of a 1-row block
+                  pl.BlockSpec((1, 1, 1, lanes),
+                               lambda w, s: (w, s, 0, 0))],
         out_specs=[plane_spec] * 3,
         scratch_shapes=[pltpu.VMEM((rows, n_buckets, lanes), jnp.uint32)
                         for _ in range(3)]
         + [pltpu.VMEM((4 * FQ.n_limbs, 6 * lanes), jnp.float32)],
         interpret=interpret,
-    )(sx, sy, ops)
+    )(sx, sy, ops[:, :, None, :])
 
 
 def _scan_pallas(ax, ay, ops, group, n_buckets, signed, packed):
@@ -234,9 +272,8 @@ def _scan_pallas(ax, ay, ops, group, n_buckets, signed, packed):
     sops = sops.reshape(steps, group, wt, mt).transpose(2, 0, 1, 3)
     sops = sops.reshape(wt, steps, group * mt)
 
-    interpret = jax.default_backend() != "tpu"
-    outs = _bucket_call(interpret, group, n_buckets, signed, packed,
-                        mt, wt, sx, sy, sops)
+    outs = _bucket_call(pallas_interpret(), group, n_buckets, signed,
+                        packed, mt, wt, sx, sy, sops)
     planes = []
     for o in outs:
         rows = o.shape[1]

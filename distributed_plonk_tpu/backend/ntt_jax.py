@@ -21,8 +21,8 @@ Design notes (TPU-first):
   identities e(s, p+n/4) = e(s,p) and e(s+1, 2p+b) = e(s,p)/2 + b*n/4 hold
   for s <= k-2, which every fused pair satisfies). The radix-2 kernel pays
   log2(n) full HBM round trips plus a per-stage (16, n/2) twiddle gather
-  and measured ~2% MFU against the field-mul roofline (BENCH_r05); radix-4
-  HALVES the stage count (one fixup radix-2 stage when log2(n) is odd) and
+  and measured ~2% MFU against the field-mul roofline (round-5 bench
+  line); radix-4 HALVES the stage count (one fixup radix-2 stage when log2(n) is odd) and
   cuts per-two-stage twiddle gather volume from n to 3n/4 lanes at the
   same multiply/add count, because the fused-pair twiddles come from three
   precomputed exponent tables instead of being recombined on the fly.
@@ -97,10 +97,17 @@ def _active_radix(radix=None, n=None):
 #     log2(rows) butterfly stages per HBM round trip instead of the
 #     radix-4 scan's two; coset pre-scale and inverse post-scales fused
 #     into the first/last group.
-#   xla: the radix-4/radix-2 lax.scan cores (the parity/debug reference,
-#     exactly like DPT_MSM_KERNEL=xla keeps the bucket scan).
-#   auto (default): pallas on TPU, xla elsewhere (CPU interpret-mode
-#     pallas is test-only).
+#   xla: the radix-4/radix-2 lax.scan cores — the path every chip run to
+#     date has proved with.
+#   auto (default): xla on every platform. The fused kernel has never
+#     compiled on the v5e (PR 21: Mosaic refuses it, and a variant that
+#     got past the refusal did not finish compiling — ntt_pallas
+#     docstring, CHANGES.md), so it is never what a device path falls
+#     into: asking for it by name (DPT_NTT_KERNEL=pallas, the `kernel`
+#     argument, or a plan cell) runs it and raises what the compiler
+#     says. No handler substitutes the XLA core at run time. It goes back
+#     into `auto` only by a platform or shape rule with a measured cell
+#     on each side (ROADMAP).
 # field_jax.pallas_disabled() / mesh.pallas_guard override even a forced
 # "pallas" — a pallas_call has no GSPMD partitioning rule, so sharded
 # operands outside shard_map must never meet one.
@@ -122,7 +129,7 @@ def _use_pallas_kernel(n=None):
     if mode != "auto":
         raise ValueError(
             f"DPT_NTT_KERNEL must be auto|pallas|xla, got {_NTT_KERNEL!r}")
-    return jax.default_backend() == "tpu"
+    return False
 
 
 def _active_kernel(kernel=None, n=None):
@@ -681,22 +688,25 @@ class NttPlan:
         MsmContext.aot_compile: under DPT_NTT_KERNEL=pallas the lowered
         programs ARE the fused multi-stage Mosaic kernels, so
         `warm_stages` / `scripts/warmup.py --aot` pre-bake those too.
-        Returns {"compiled": k, "failed": j, "radix": r, "kernel": mode}.
+        Returns {"compiled": k, "failed": j, "errors": [...], "radix": r,
+        "kernel": mode}; `errors` holds what the compiler said for every
+        variant counted in `failed`.
         """
         radix = self._effective_radix(radix)
         kmode = self._effective_kernel(kernel)
-        compiled = failed = 0
+        compiled = 0
+        errors = []
         v_spec = jax.ShapeDtypeStruct((FR_LIMBS, self.n), jnp.uint32)
 
         def aot(fn, consts, spec):
-            nonlocal compiled, failed
+            nonlocal compiled
             cspec = {k: jax.ShapeDtypeStruct(a.shape, a.dtype)
                      for k, a in consts.items()}
             try:
                 fn.lower(spec, cspec).compile()
                 compiled += 1
-            except Exception:  # pragma: no cover - older jax without AOT
-                failed += 1
+            except Exception as e:  # noqa: BLE001 - reported, never hidden
+                errors.append(repr(e))
 
         for inverse in (False, True):
             for coset in (False, True):
@@ -714,8 +724,8 @@ class NttPlan:
                     aot(fn, consts,
                         jax.ShapeDtypeStruct((FR_LIMBS, b, self.n),
                                              jnp.uint32))
-        return {"compiled": compiled, "failed": failed, "radix": radix,
-                "kernel": kmode}
+        return {"compiled": compiled, "failed": len(errors),
+                "errors": errors, "radix": radix, "kernel": kmode}
 
     # --- host-boundary convenience (int lists, zero-padded to n) -------------
 

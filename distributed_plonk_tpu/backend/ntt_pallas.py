@@ -1,7 +1,7 @@
 """Pallas fused multi-stage NTT: radix-16/64 worth of butterflies per
 HBM round trip.
 
-WHY (BENCH_r05 + ROADMAP direction 3): after the fused MSM landed, the
+WHY (round-5 bench line + ROADMAP direction 3): after the fused MSM landed, the
 NTT is the prover's dominant non-MSM kernel and it is pure
 HBM-bandwidth-bound — `mfu_ntt_pct` ~2.15 against a ~64% Fq multiplier,
 because every butterfly stage of the constant-geometry core round-trips
@@ -52,9 +52,16 @@ pipeline skips this gather entirely on every producer launch
 (NttPlan kernel defer_perm — accumulators stay in constant-geometry
 order) and pays ONE input gather at the consuming coset-iNTT instead.
 
-Select with DPT_NTT_KERNEL=auto|pallas|xla (auto: pallas on TPU;
-interpret mode elsewhere is test-only, like msm_pallas). The radix-4
-XLA core stays the parity/debug reference. Tiles are sized against
+Select with DPT_NTT_KERNEL=pallas (interpret mode is test-only:
+DPT_PALLAS_INTERPRET=1). `auto` resolves to the radix-4 XLA core on every
+platform, because this kernel has never compiled on the v5e: Mosaic
+(PR 21, libtpu 0.0.34) refuses it with "Not implemented: Broadcast in
+both sublanes and lanes" at the (L, 1, 1) modulus columns of _col3, which
+is what asking for it by name raises there. An experiment in that PR
+(lane-splat columns, twiddle tables stored repeated to 8 sublanes; not
+kept, it was only ever verified interpreted) got past that refusal, and
+then the compile of one 2^13 group program did not return within 225 s,
+nor a 64-point one within 640 s — CHANGES.md PR 21. Tiles are sized against
 DPT_NTT_PALLAS_VMEM_MB; DPT_NTT_PALLAS_ROWS caps the per-group row
 count (the analog of msm's group cap).
 """
@@ -68,6 +75,7 @@ import jax.numpy as jnp
 
 from . import autotune
 from .curve_pallas import _mod_add, _mod_sub, _row0_mask, field_consts
+from .field_jax import pallas_interpret
 from .field_pallas import _carry_sweep_val, _cols_to_limbs, _to_bytes_f32
 
 # peak VMEM one grid cell may occupy; the lane tile (and then the fused
@@ -362,7 +370,7 @@ def run_groups(v, consts):
     schedule = schedule_from_consts(log_n, consts)
     if not schedule:
         raise ValueError("no pallas NTT tables in consts")
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     last = len(schedule) - 1
     for g, (s0, r) in enumerate(schedule):
         tws = [consts.get(f"pg{g}s{t}") for t in range(r)]

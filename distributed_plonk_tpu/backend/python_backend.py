@@ -25,6 +25,11 @@ class PythonBackend:
 
     name = "python"
 
+    def device_info(self):
+        """Where this backend computes (the service's listening line and
+        the MFU gauges read it): the host, no accelerator."""
+        return {"platform": "host", "device_kind": None, "devices": 0}
+
     # --- plain int-list compute API (worker daemon / dispatcher surface) ----
 
     def fft(self, domain, values):

@@ -51,7 +51,8 @@ Kernel dispatch and device tuning (backend/, parallel/):
     DPT_AUTOTUNE_BUDGET_S     autotune sweep wall-clock budget (120)
     DPT_AUTOTUNE_SHAPES       comma list of shapes to calibrate
     DPT_AUTOTUNE_INTERPRET    allow pallas interpret-mode candidates
-    DPT_JAX_CACHE_DIR         persistent compile-cache directory
+    DPT_PALLAS_INTERPRET      run Pallas kernels interpreted: tests only (0)
+    DPT_JAX_CACHE_DIR         fleet worker's compile-cache dir (--store)
     DPT_JAX_TRACE             jax.profiler span annotations on hot paths
 
 Proof service and autoscaling (service/):
@@ -67,7 +68,6 @@ Proof service and autoscaling (service/):
     DPT_JOURNAL_FSYNC         fsync the job journal per append (1)
     DPT_JOURNAL_COMPACT_EVERY journal compaction cadence, appends (512)
     DPT_PEER_FETCH_TIMEOUT_MS peer artifact-fetch timeout (5000)
-    DPT_PEAK_TFLOPS           MFU denominator for gflops gauges (1.0)
     DPT_AUTOSCALE             autoscaler arm: 0|dry|1 (0)
     DPT_AUTOSCALE_TICK_S      autoscaler control-loop period (2)
     DPT_AS_MIN_WORKERS        autoscaler floor (1)

@@ -66,6 +66,13 @@ class MeshBackend(JaxBackend):
     # getattr falls back to commit_many_h (and mesh placements are
     # single-job groups anyway — big proves shard, they don't batch)
     commit_batch = None
+    # nor a deferred-decode one, and the inherited eval_many_async would
+    # trace the evaluation outside the pallas_disabled wrapper below: the
+    # prover's getattr falls back to commit_many_h / eval_many_h (a mesh
+    # prove is one job on its own lease, there is no second member whose
+    # host work the deferral could overlap)
+    commit_many_async = None
+    eval_many_async = None
 
     # minimum per-device coefficient count for sharding a handle: below
     # this, elementwise/scan round math runs REPLICATED on the mesh

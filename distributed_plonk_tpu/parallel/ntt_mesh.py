@@ -31,12 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-# version-compat shard_map wrapper (check_vma/check_rep) — needed to
-# disable the replication checker when the body traces a pallas_call,
-# which has no replication rule (same workaround as the mesh MSM's
-# pallas scans; the shim owns the jax-version fallback too)
-from .msm_mesh import _shard_map as _shard_map_compat
-
 from ..constants import R_MOD, FR_GENERATOR, FR_LIMBS
 from ..fields import fr_inv, fr_root_of_unity
 from ..backend import autotune
@@ -113,12 +107,15 @@ class MeshNttPlan:
         key = autotune.cache_key(
             inverse, coset, boundary, ntt_jax._active_radix(n=self.n),
             ntt_jax._active_kernel(n=self.n))
-        # will the TRACED body actually run pallas? Resolve under the
-        # same guard the trace runs under (pallas_guard disables it for
-        # a non-TPU mesh), so check_vma below is only relaxed for
-        # programs that genuinely contain a pallas_call
+        # can the TRACED body contain a pallas_call — the fused NTT
+        # kernel, or the fused multiplier the XLA stage cores dispatch
+        # for wide shapes on a TPU? Resolve under the same guard the
+        # trace runs under (pallas_guard disables both for a non-TPU
+        # mesh), so check_vma below is only relaxed for programs that
+        # can genuinely contain one
         with pallas_guard(self.mesh):
-            pallas_active = ntt_jax._active_kernel() == "pallas"
+            pallas_active = (ntt_jax._active_kernel() == "pallas"
+                             or FJ.pallas_mul_possible())
         if key in self._fns:
             fn, consts = self._fns[key]
             return lambda v: fn(v, consts)
@@ -180,10 +177,10 @@ class MeshNttPlan:
         # checker ONLY when the traced body will contain one — every
         # XLA-core program (including pallas-requested-but-guarded-off
         # on a non-TPU mesh) keeps the full replication check
-        smapped = _shard_map_compat(
+        smapped = jax.shard_map(
             sharded_body, mesh=self.mesh,
             in_specs=(row_spec, const_specs), out_specs=row_spec,
-            **({"check_vma": False} if pallas_active else {}))
+            check_vma=not pallas_active)
 
         lane_sh = jax.sharding.NamedSharding(self.mesh, P(None, SHARD_AXIS))
 

@@ -168,7 +168,8 @@ class WorkerState:
         if flops:
             self.metrics.observe_kernels(
                 [{"span": stage, "flops": flops, "dur_s": dur_s,
-                  "data_bytes": data_bytes}])
+                  "data_bytes": data_bytes}],
+                device_kind=self.backend.device_info()["device_kind"])
 
     def tracer_for(self, ctx):
         """The per-trace Tracer an incoming traced frame records under

@@ -34,7 +34,7 @@ from .metrics import Metrics
 from .placement import PlacementScheduler, SubmeshLeaser
 from .pool import WorkerPool, WorkerKilled, JobTimeout, WorkerDrained
 from .scheduler import BucketCache, Scheduler
-from .server import ProofService
+from .server import ProofService, make_backend, start_service
 from .client import ServiceClient
 
 __all__ = [
@@ -42,5 +42,5 @@ __all__ = [
     "JobJournal", "JobQueue", "Rejected", "Metrics", "WorkerPool",
     "WorkerKilled", "JobTimeout", "WorkerDrained", "BucketCache",
     "Scheduler", "PlacementScheduler", "SubmeshLeaser", "ProofService",
-    "ServiceClient",
+    "ServiceClient", "make_backend", "start_service",
 ]

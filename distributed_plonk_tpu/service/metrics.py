@@ -240,7 +240,9 @@ Tracing vocabulary (trace.py, service/pool.py, server.py --obs-port):
     kernel_*_gflops / mfu_*_pct (gauges)     live per-stage throughput
                                              and model-flops MFU from
                                              kernel span attrs (peak set
-                                             by DPT_PEAK_TFLOPS)
+                                             by the DEVICE_PEAKS entry
+                                             of the backend's chip; none
+                                             for an unknown device)
 
 Fleet observability vocabulary (obs/log.py, obs/fleet.py,
 runtime/worker.py METRICS_FETCH/LOG_FETCH/PROFILE — the one-pane plane,
@@ -361,11 +363,14 @@ import time
 
 _RESERVOIR = 2048
 
-# MFU denominator: the chip's peak f32 FMA rate in TFLOP/s (bench.py's
-# f32_fma_tflops_measured is the number to use). The default 1.0 makes
-# mfu_*_pct read as GFLOP/s / 10 until an operator calibrates it — a
-# consistent relative signal either way.
-PEAK_TFLOPS = float(os.environ.get("DPT_PEAK_TFLOPS", "1.0"))
+# Published peaks of one chip, keyed by the `device_kind` string jax
+# reports for it. The mfu_*_pct gauges divide by bf16_tflops; a kind that
+# is not in the table publishes no such gauge (an unknown chip, or the
+# host oracle, has no peak to be a share of).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_tflops": 197.0, "hbm_gbps": 819.0,
+                    "source": 'Google Cloud documentation, "TPU v5e"'},
+}
 
 
 class Histogram:
@@ -459,13 +464,14 @@ class Metrics:
         for span, dur in totals.items():
             self.observe(f"prove_round/{span}", dur)
 
-    def observe_kernels(self, events, peak_tflops=None):
+    def observe_kernels(self, events, device_kind=None):
         """Fold kernel spans carrying `flops` attrs (trace.Tracer events
         of a finished prove — see prover.py / trace.ntt_flops) into live
         per-stage gauges: kernel_<stage>_gflops (model-flops throughput)
-        and mfu_<stage>_pct (against DPT_PEAK_TFLOPS). The serving-path
-        counterpart of bench.py's one-shot MFU numbers."""
-        peak = (peak_tflops if peak_tflops is not None else PEAK_TFLOPS) \
+        and, when `device_kind` (the proving backend's
+        device_info()["device_kind"]) is in DEVICE_PEAKS,
+        mfu_<stage>_pct against that chip's published bf16 peak."""
+        peak = DEVICE_PEAKS.get(device_kind, {}).get("bf16_tflops", 0.0) \
             * 1e12
         for ev in events:
             flops = ev.get("flops")

@@ -833,7 +833,7 @@ class WorkerPool:
                          batch_size=job.batch_size)
         return tracer
 
-    def _finish_proved(self, job, res, ckt, proof, tracer, backend=None):
+    def _finish_proved(self, job, res, ckt, proof, tracer, backend):
         """Post-prove completion shared by the single and batched paths:
         verify-before-serve, round/kernel metrics, finished-proof
         durability, trace artifact, client-visible done. ORDER IS THE
@@ -844,9 +844,12 @@ class WorkerPool:
         totals = tracer.totals(depth=1)
         self.metrics.observe_rounds(totals)
         # kernel spans carry flops attrs (prover.py): fold them into
-        # live per-stage MFU/throughput gauges — the serving-path
-        # replacement for bench-only MFU numbers
-        self.metrics.observe_kernels(tracer.events)
+        # live per-stage throughput gauges, and MFU gauges where the
+        # backend's chip has a published peak (the host oracle and the
+        # fleet backend name no device, so they publish none)
+        self.metrics.observe_kernels(
+            tracer.events,
+            device_kind=backend.device_info()["device_kind"])
         proof_bytes = serialize_proof(proof)
         pub = ckt.public_input()
         if self.faults is not None and self.faults.on_proof(job.id):

@@ -36,6 +36,36 @@ from .queue import JobQueue, Rejected
 from .scheduler import BucketCache
 
 
+BACKENDS = ("jax", "python")
+
+
+def make_backend(name):
+    """The service's backend as a deployment setting: "jax" is JaxBackend
+    on the local accelerator (the daemon's default), "python" the pure-host
+    oracle. jax loads only when asked for, so an oracle service stays
+    importable with no XLA present."""
+    if name == "jax":
+        from ..backend.jax_backend import JaxBackend
+        return JaxBackend()
+    if name == "python":
+        from ..backend.python_backend import PythonBackend
+        return PythonBackend()
+    raise ValueError(f"unknown backend {name!r} (want one of {BACKENDS})")
+
+
+def start_service(backend="jax", **service_kwargs):
+    """Build and start a ProofService the way the daemon does — the ONE
+    start-up path scripts/serve.py and chip_smoke.py share. Every pool
+    worker proves on the SAME backend instance, so SRS, proving keys and
+    domain tables live on the device once however many worker threads
+    feed it (JaxBackend's caches are lock-guarded for exactly this).
+    Returns (service, runtime) where runtime names what the backend it
+    built runs on: {"backend", "platform", "device_kind", "devices"}."""
+    be = make_backend(backend)
+    svc = ProofService(backend_factory=lambda: be, **service_kwargs).start()
+    return svc, dict(be.device_info(), backend=be.name)
+
+
 class ProofService:
     def __init__(self, host="127.0.0.1", port=0, prover_workers=2,
                  queue_depth=64, max_batch=8, max_retries=2,
@@ -54,12 +84,9 @@ class ProofService:
         self.queue = JobQueue(max_depth=queue_depth)
         self.store = None
         if store_dir is not None:
-            # NOTE: the service does not repoint the JAX compile cache —
-            # an embedded ProofService (tests, bench) must not hijack its
-            # host process's cache config. Daemon entry points that OWN
-            # their process call store.set_jax_cache_env themselves
-            # (scripts/serve.py) so compiled stages warm-start alongside
-            # the keys they serve.
+            # the store holds keys, proofs and traces; the JAX compile
+            # cache is not the service's to place (JAX_COMPILATION_CACHE_DIR,
+            # else <checkout>/.jax_cache — backend/field_jax)
             self.store = ArtifactStore(store_dir,
                                        byte_budget=store_byte_budget,
                                        metrics=self.metrics.scoped("store"))

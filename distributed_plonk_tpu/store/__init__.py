@@ -27,8 +27,8 @@ from .keycache import (bucket_store_key, serialize_bucket,
                        proof_store_key, store_proof, load_proof,
                        trace_store_key, store_trace, load_trace,
                        profile_store_key, store_profile, load_profile)
-from .warmstart import (set_jax_cache_env, configure_jax_cache,
-                        aot_warmup, warm_spec)
+from .warmstart import (set_jax_cache_env, aot_errors, aot_warmup,
+                        warm_spec)
 from .remote import FetchError, fetch_blob, fetch_into
 from .calibration import (plan_store_key, store_plan, load_plan,
                           load_or_run, parse_shapes)
@@ -39,7 +39,7 @@ __all__ = [
     "proof_store_key", "store_proof", "load_proof",
     "trace_store_key", "store_trace", "load_trace",
     "profile_store_key", "store_profile", "load_profile",
-    "set_jax_cache_env", "configure_jax_cache", "aot_warmup", "warm_spec",
+    "set_jax_cache_env", "aot_errors", "aot_warmup", "warm_spec",
     "FetchError", "fetch_blob", "fetch_into",
     "plan_store_key", "store_plan", "load_plan", "load_or_run",
     "parse_shapes",

@@ -1,19 +1,23 @@
-"""Warm-start layer: store-owned compile cache + AOT shape warmup.
+"""Warm-start layer: AOT shape warmup + the fleet worker's store-parked
+compile cache.
 
 Two cold-start costs dominate serving a new circuit shape (PAPER.md's
 prover pays both once per shape): trusted-setup/key construction and the
 XLA compilation of the prover's NTT/MSM stages. The artifact store
 (artifacts.py + keycache.py) removes the first across restarts; this
-module removes the second by (a) parking JAX's persistent compilation
-cache under the store root, so compiled stages live and die with the
-artifacts they serve, and (b) an AOT warmup entry point that pre-builds
+module removes the second with an AOT warmup entry point that pre-builds
 keys AND pre-lowers/compiles the prover stages for a shape before any
-job arrives (WARMUP wire tag, scripts/warmup.py).
+job arrives (WARMUP wire tag, scripts/warmup.py). Compiled stages land in
+JAX's persistent compile cache, which the daemon keeps at one of two
+fixed places (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache —
+backend/field_jax.configure_compile_cache). A fleet worker started with
+--store still parks its cache under that store (`set_jax_cache_env`),
+because warm rejoin syncs those files between store peers.
 
-None of this imports jax at module scope: the proof service's default
-backend is the pure-host oracle and must stay importable (and testable)
+None of this imports jax at module scope: an embedded ProofService
+defaults to the pure-host oracle and must stay importable (and testable)
 with no XLA present. jax only loads when a jax-capable backend is
-actually handed in, or `configure_jax_cache` is called.
+actually handed in.
 """
 
 import os
@@ -26,41 +30,38 @@ from .artifacts import JAX_CACHE_SUBDIR  # one name for the GC'd subdir
 def set_jax_cache_env(store_root):
     """Point the (not-yet-imported) jax backend's persistent compile cache
     under `store_root`, via the DPT_JAX_CACHE_DIR knob field_jax reads at
-    import. Env-only — safe to call from processes that never load jax.
-    An explicit user setting (either knob) wins."""
+    import (runtime/worker.py --store). Env-only — safe to call from
+    processes that never load jax. An explicit user setting (either knob)
+    wins; JAX_COMPILATION_CACHE_DIR wins inside
+    field_jax.configure_compile_cache whatever this sets."""
     if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
         os.environ.setdefault(
             "DPT_JAX_CACHE_DIR",
             os.path.join(os.path.abspath(store_root), JAX_CACHE_SUBDIR))
 
 
-def configure_jax_cache(store_root, min_compile_secs=0.5):
-    """Repoint an already-imported jax at the store-owned compile cache
-    (machine-fingerprint partitioned). Imports jax; returns the cache dir
-    or None when this jax can't be wired.
-
-    Same precedence rule as set_jax_cache_env: an operator's explicit
-    JAX_COMPILATION_CACHE_DIR wins — otherwise an offline `warmup --aot`
-    would bake executables into a directory the (env-respecting) server
-    never reads, silently wasting the whole warmup pass."""
-    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
-        return None
-    from ..backend import field_jax
-    return field_jax.configure_compile_cache(
-        os.path.join(os.path.abspath(store_root), JAX_CACHE_SUBDIR),
-        min_compile_secs=min_compile_secs)
+def aot_errors(report):
+    """What the compiler said for every stage an aot_warmup report counts
+    as failed, flat (empty: every stage compiled)."""
+    errors = list(report.get("msm", {}).get("errors", ()))
+    for per_domain in report.get("ntt", {}).values():
+        errors += per_domain.get("errors", ())
+    return errors
 
 
 def aot_warmup(backend, domain_size, ck=None):
     """Pre-lower/compile the prover stages for one shape's domain on a
     backend that supports it (JaxBackend.warm_stages); the host oracle
-    has no compile step, so it reports `unsupported` and costs nothing."""
+    has no compile step, so it reports `unsupported` and costs nothing.
+    `aot` is "failed" when the compiler refused any stage (aot_errors
+    lists what it said); callers that must not continue past a refusal
+    — chip_smoke.py, scripts/warmup.py's exit code — read that."""
     if backend is None or not hasattr(backend, "warm_stages"):
         return {"aot": "unsupported",
                 "backend": getattr(backend, "name", None)}
     t0 = time.monotonic()
     report = backend.warm_stages(domain_size, ck=ck)
-    report["aot"] = "ok"
+    report["aot"] = "failed" if aot_errors(report) else "ok"
     report["aot_s"] = round(time.monotonic() - t0, 3)
     return report
 
@@ -90,8 +91,4 @@ def warm_spec(store, spec_obj, backend=None, aot_backend=None):
                "domain_size": vk.domain_size, "build_s": round(build_s, 6)}
     if aot_backend is not None:
         out["aot"] = aot_warmup(aot_backend, vk.domain_size, ck=pk.ck)
-        # the AOT pass is what grows the store-owned compile cache:
-        # re-bound it against the byte budget right after (the periodic
-        # put()-side sweep only runs while artifacts are being written)
-        out["jax_cache_swept"] = store.sweep_jax_cache()
     return out

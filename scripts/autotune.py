@@ -49,16 +49,13 @@ def main():
                     help="include the full per-cell plan in the output")
     args = ap.parse_args()
 
-    from distributed_plonk_tpu.store import (ArtifactStore,
-                                             configure_jax_cache)
-    from distributed_plonk_tpu.store import calibration
+    from distributed_plonk_tpu.store import ArtifactStore, calibration
     from distributed_plonk_tpu.backend import autotune
 
     t0 = time.time()
     store = ArtifactStore(args.store_dir)
-    # winners' AOT executables land in the store-owned compile cache so
-    # they warm-sync to workers alongside the plan itself
-    configure_jax_cache(args.store_dir)
+    # winners' AOT executables land in the compile cache the daemon
+    # reads: JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache
     shapes = calibration.parse_shapes(args.shapes) if args.shapes else None
 
     if args.force:

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Concurrent load generator + fault injector for the proof service.
 
-    JAX_PLATFORMS=cpu python scripts/loadgen.py            # self-hosted run
+    python scripts/loadgen.py                              # self-hosted run
     python scripts/loadgen.py --host 127.0.0.1 --port 9555 # external server
     python scripts/loadgen.py --jobs 12 --no-kill
     python scripts/loadgen.py --kill-rate 0.5 --corrupt-rate 0.3 \
@@ -626,12 +626,15 @@ def run_kill_service_soak(args):
     port = args.port
 
     def spawn(faults=None):
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env = dict(os.environ)
         env.pop("DPT_FAULTS", None)
         if faults:
             env["DPT_FAULTS"] = faults
+        # the soak is about the journal, not the prover: the oracle
+        # backend is asked for by name (the daemon's default is jax)
         p = subprocess.Popen(
             [sys.executable, os.path.join(here, "serve.py"),
+             "--backend", "python",
              "--port", str(port), "--workers", str(args.workers),
              "--journal-dir", jdir, "--store-dir", sdir, "--chaos",
              "--allow-remote-shutdown"],
@@ -982,7 +985,6 @@ def main():
     ap.add_argument("--timeout", type=float, default=600)
     args = ap.parse_args()
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.circuit_mix is not None:
         return run_circuit_mix_soak(args)
     if args.traffic is not None:

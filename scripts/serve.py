@@ -1,9 +1,17 @@
 #!/usr/bin/env python3
 """Run the proof service daemon.
 
-    JAX_PLATFORMS=cpu python scripts/serve.py --port 9555 --workers 2 \
+    python scripts/serve.py --port 9555 --workers 2 [--backend jax] \
         [--queue-depth 64] [--max-batch 8] [--retries 2] [--timeout 300] \
         [--journal-dir /var/dpt/journal] [--chaos] [--verify]
+
+--backend is a deployment setting: `jax` (the default) proves on the
+local accelerator through JaxBackend — one process per chip, so run one
+daemon per chip and start nothing else that needs it from a process that
+has imported jax; `python` is the pure-host oracle. A CPU run of the jax
+backend is something the caller asks for (JAX_PLATFORMS=cpu, as the tests
+and scripts/ci.sh do); the daemon pins no platform. The JAX compile cache
+is where JAX_COMPILATION_CACHE_DIR says, else <checkout>/.jax_cache.
 
 --journal-dir enables the crash-safe job journal: every submitted job
 survives a crash or deploy restart (in-flight ones resume from their
@@ -20,7 +28,8 @@ makes THIS PROCESS os._exit at exactly that journal occurrence; the
 restart-recovery tests and loadgen --kill-service drive it). Never
 enable it on a service you care about. --verify makes workers verify
 each proof server-side before marking it done.
-Prints one JSON line with the bound address once listening; SHUTDOWN tag
+Prints one JSON line once listening, with the bound address and what the
+backend runs on (backend, platform, device_kind, devices); SHUTDOWN tag
 stops it.
 """
 
@@ -76,6 +85,9 @@ def main():
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=9555)
     ap.add_argument("--workers", type=int, default=2)
+    ap.add_argument("--backend", choices=("jax", "python"), default="jax",
+                    help="prover backend: jax = JaxBackend on the local "
+                         "accelerator (default), python = host oracle")
     ap.add_argument("--queue-depth", type=int, default=64)
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--retries", type=int, default=2)
@@ -89,8 +101,8 @@ def main():
                          "SIGTERM graceful-drain surface")
     ap.add_argument("--store-dir", default=None,
                     help="artifact store root: persists SRS/keys across "
-                         "restarts and parks the JAX compile cache; warm "
-                         "it ahead of time with scripts/warmup.py")
+                         "restarts; warm it ahead of time with "
+                         "scripts/warmup.py")
     ap.add_argument("--store-budget", type=int, default=None,
                     help="store byte budget (LRU eviction past it)")
     ap.add_argument("--bucket-cap", type=int, default=64,
@@ -123,16 +135,9 @@ def main():
     if args.journal_dir is not None:
         journal_dir = validate_journal_dir(args.journal_dir)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if args.store_dir is not None:
-        # park the persistent compile cache under the store root BEFORE
-        # any jax backend import, so compiled prover stages warm-start
-        # alongside the keys they serve
-        from distributed_plonk_tpu.store import set_jax_cache_env
-        set_jax_cache_env(args.store_dir)
     from distributed_plonk_tpu.obs import log as olog
     from distributed_plonk_tpu.runtime.faults import FaultInjector
-    from distributed_plonk_tpu.service import ProofService
+    from distributed_plonk_tpu.service import start_service
     from distributed_plonk_tpu.service.server import ObsServer
 
     log_path = None
@@ -149,7 +154,8 @@ def main():
         faults = FaultInjector.from_env(
             kill_cb=lambda _label: os._exit(1))
 
-    svc = ProofService(
+    svc, runtime = start_service(
+        args.backend,
         host=args.host, port=args.port, prover_workers=args.workers,
         queue_depth=args.queue_depth, max_batch=args.max_batch,
         max_retries=args.retries, job_timeout_s=args.timeout,
@@ -160,7 +166,7 @@ def main():
         bucket_cap=args.bucket_cap, journal_dir=journal_dir,
         faults=faults,
         store_peers=parse_peers(args.store_peers)
-        if args.store_peers else None).start()
+        if args.store_peers else None)
 
     obs = None
     if args.obs_port is not None:
@@ -186,6 +192,7 @@ def main():
     signal.signal(signal.SIGINT, _drain_handler)
 
     print(json.dumps({"listening": f"{svc.host}:{svc.port}",
+                      **runtime,
                       "obs": f"{obs.host}:{obs.port}" if obs else None,
                       "workers": args.workers, "chaos": args.chaos,
                       "store": args.store_dir, "journal": journal_dir,

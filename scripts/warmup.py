@@ -14,7 +14,7 @@ Two modes:
 
 With no --spec, warms the default loadgen mix (toy gates 16/60/150/300).
 Prints one JSON line: per-shape source (memory|disk|built) + timings.
-Exit 0 iff every shape warmed.
+Exit 0 iff every shape warmed and, with --aot, every stage compiled.
 """
 
 import argparse
@@ -40,12 +40,12 @@ def main():
                     help="job spec JSON (repeatable); default: loadgen mix")
     ap.add_argument("--aot", action="store_true",
                     help="also precompile prover stages (wire mode: on the "
-                         "server's backend; offline: on a local JaxBackend)")
+                         "server's backend; offline: on a local JaxBackend, "
+                         "which takes the chip like any jax process)")
     args = ap.parse_args()
     if (args.host is None) == (args.store_dir is None):
         ap.error("exactly one of --host or --store-dir is required")
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     specs = [json.loads(s) for s in args.spec] or list(_DEFAULT_MIX)
     shapes, ok = [], True
     t0 = time.time()
@@ -60,13 +60,12 @@ def main():
                     ok = False
                     shapes.append({"spec": spec, "error": repr(e)})
     else:
-        from distributed_plonk_tpu.store import (ArtifactStore,
-                                                 configure_jax_cache,
-                                                 warm_spec)
+        from distributed_plonk_tpu.store import ArtifactStore, warm_spec
         store = ArtifactStore(args.store_dir)
         aot_backend = None
         if args.aot:
-            configure_jax_cache(args.store_dir)
+            # executables land in the compile cache the daemon reads:
+            # JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache
             from distributed_plonk_tpu.backend.jax_backend import JaxBackend
             aot_backend = JaxBackend()
         for spec in specs:
@@ -77,6 +76,10 @@ def main():
                 ok = False
                 shapes.append({"spec": spec, "error": repr(e)})
 
+    # a stage the compiler refused is in the shape's aot report with its
+    # message; it fails the warm-up like a shape that raised
+    if any(s.get("aot", {}).get("aot") == "failed" for s in shapes):
+        ok = False
     print(json.dumps({"ok": ok, "wall_s": round(time.time() - t0, 3),
                       "shapes": shapes}), flush=True)
     return 0 if ok else 1

@@ -46,6 +46,7 @@ def members8():
     return [_member(i) for i in range(8)]
 
 
+@pytest.mark.tier2
 def test_n8_mixed_kind_single_pairing_check(members8):
     """THE amortization claim: verifying an 8-member mixed-kind batch
     costs exactly one pairing check with two pairs."""
@@ -122,6 +123,7 @@ def test_empty_and_malformed_artifacts():
     assert not AGG.verify(b"junk")
 
 
+@pytest.mark.tier2
 def test_aggregate_all_or_nothing_on_pending_or_unknown_member():
     from distributed_plonk_tpu.service import ProofService
     svc = ProofService(port=0, prover_workers=1).start()

@@ -391,6 +391,7 @@ def test_retire_slot_graceful_drain_then_leave():
         _shutdown(d, sup)
 
 
+@pytest.mark.tier2
 def test_closed_loop_canary_scales_up_and_retires():
     """The live acceptance canary: ramp -> add_slot (warm join) -> every
     proof byte-verified -> idle -> drain-then-LEAVE retire back to the

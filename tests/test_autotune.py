@@ -266,6 +266,9 @@ def test_cell_abandoned_when_parity_core_fails():
                              metrics=m).run()
     assert plan.cell("ntt", N) is None
     assert m.snapshot()["counters"]["autotune_candidate_errors"] >= 1
+    # what the candidate raised is carried out in the plan, not swallowed
+    (err,) = plan.meta["candidate_errors"][f"ntt:{N}"]
+    assert "parity core refused to run" in err["error"]
     assert "autotune_parity_rejects" not in m.snapshot()["counters"]
 
 

@@ -51,6 +51,7 @@ def test_msm_matches_oracle(n):
     assert got == C.g1_msm(bases, scalars)
 
 
+@pytest.mark.tier2
 def test_msm_short_scalars_and_reuse():
     bases = _rand_points(32)
     ctx = msm_jax.MsmContext(bases)
@@ -60,6 +61,7 @@ def test_msm_short_scalars_and_reuse():
     assert ctx.msm(s2) == C.g1_msm(bases, s2)
 
 
+@pytest.mark.tier2
 def test_msm_aot_compile_then_correct():
     """warm_stages' true AOT path: lower().compile() every pipeline stage
     without executing anything — digit extraction at the COMMIT-handle
@@ -163,6 +165,7 @@ def test_batch_to_affine_roundtrip():
             assert (ax_i[k], ay_i[k]) == pt, k
 
 
+@pytest.mark.tier2
 def test_msm_signed_path_matches_oracle(monkeypatch):
     """The c=8 signed pipeline (32x128) must keep oracle coverage even
     though the single-chip default is now c=7 — the mesh context

@@ -86,6 +86,7 @@ def test_proj_add_mixed_matches_oracle_and_xla():
     assert _proj_to_affine(got) == exp
 
 
+@pytest.mark.tier2
 def test_dispatch_gate_respects_mask_and_bitmatch():
     """curve_jax.proj_add_mixed with the fused path forced must equal the
     XLA path limb-for-limb, including the q_inf select."""

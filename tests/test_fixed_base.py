@@ -33,6 +33,7 @@ def test_device_srs_matches_host_setup():
     assert srs_d.tau_g2 == srs_h.tau_g2
 
 
+@pytest.mark.tier2
 def test_device_preprocess_matches_host(proven_inputs):
     """Device SRS + backend preprocess produce the identical pk/vk (and so
     the identical transcript/proof downstream) as the host-oracle path."""

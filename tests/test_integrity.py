@@ -545,6 +545,7 @@ def test_self_verify_blocks_corrupt_proof(tmp_path, monkeypatch):
         svc.shutdown()
 
 
+@pytest.mark.tier2
 def test_self_verify_off_and_auto_parity(tmp_path):
     """DPT_SELF_VERIFY=0 (and the default auto mode on pool-placed
     local proves) adds ZERO checks and zero counters; proof bytes are

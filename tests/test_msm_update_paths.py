@@ -15,8 +15,11 @@ from distributed_plonk_tpu.backend import msm_jax as M
 RNG = random.Random(0x1407)
 
 
+# tier-1 keeps the TPU default (onehot, packed planes); `put` is what
+# every other CPU test already runs
 @pytest.mark.parametrize("mode,pack", [
-    ("put", True), ("onehot", True), ("onehot", False)])
+    pytest.param("put", True, marks=pytest.mark.tier2), ("onehot", True),
+    pytest.param("onehot", False, marks=pytest.mark.tier2)])
 def test_update_strategies_match_oracle(mode, pack, monkeypatch):
     monkeypatch.setattr(M, "_BUCKET_UPDATE", mode)
     monkeypatch.setattr(M, "_PLANE_PACK", pack)

@@ -203,8 +203,10 @@ def jax_fleet(tmp_path_factory):
     yield from _spawn_fleet(tmp_path_factory, "jax", 21000, 60)
 
 
-@pytest.mark.parametrize("coset", [False, True])
-@pytest.mark.parametrize("inverse", [False, True])
+@pytest.mark.parametrize("inverse,coset", [
+    (False, False), (True, True),
+    pytest.param(False, True, marks=pytest.mark.tier2),
+    pytest.param(True, False, marks=pytest.mark.tier2)])
 def test_jax_fleet_sharded_fft(jax_fleet, inverse, coset):
     """Cross-worker 4-step FFT on jax workers (batched stage kernels) ==
     oracle, all mode combos, square and uneven splits."""

@@ -102,6 +102,7 @@ def test_batch_kernel_matches_single():
         assert (got == want).all(), (inverse, coset)
 
 
+@pytest.mark.tier2
 def test_shared_stage_core_radix_parity():
     """run_stages (the core the mesh NTT and fleet panels call) is
     bit-identical across the radix-2 and radix-4 table sets, forward and

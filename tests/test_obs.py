@@ -271,6 +271,7 @@ def serve_proc(tmp_path):
     env.pop("DPT_FAULTS", None)
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "scripts", "serve.py"),
+         "--backend", "python",
          "--port", "0", "--obs-port", "0", "--workers", "1",
          "--store-dir", str(tmp_path / "store"),
          "--allow-remote-shutdown"],
@@ -313,12 +314,13 @@ def test_serve_subprocess_obs_endpoints_and_merged_trace(serve_proc):
         assert h["ok"] is True and h["queue_depth"] == 0
 
         # /metrics: Prometheus text exposition with round latency
-        # histograms AND MFU gauges (the acceptance criterion's curl)
+        # histograms and kernel throughput gauges; the host oracle has
+        # no chip peak to be a share of, so it publishes no MFU gauge
         text = _get(base + "/metrics").decode()
         assert "# TYPE dpt_jobs_completed_total counter" in text
         assert "dpt_jobs_completed_total 1" in text
         assert 'dpt_prove_round_round1_seconds{quantile="0.5"}' in text
-        assert "dpt_mfu_commit_wires_pct" in text
+        assert "dpt_mfu_" not in text
         assert "dpt_kernel_commit_wires_gflops" in text
         assert "dpt_queue_depth 0" in text
 

@@ -11,6 +11,7 @@ mesh backend keep using).
 import random
 
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from distributed_plonk_tpu.constants import R_MOD
@@ -33,6 +34,7 @@ def test_pack_unpack_roundtrip():
     assert np.array_equal(np.asarray(FJ.unpack_limb_pairs(p)), np.asarray(v))
 
 
+@pytest.mark.tier2
 def test_quotient_streamed_matches_unpacked_multislice():
     """The streaming round 3 (accumulating gate/acc2 plane by plane,
     sliced final combine) must be VALUE-IDENTICAL to the one-shot

@@ -65,7 +65,9 @@ def _members(specs):
 
 # --- byte-identity across depths, mixed kinds --------------------------------
 
-@pytest.mark.parametrize("depth", [1, 2, 4])
+@pytest.mark.parametrize("depth", [
+    pytest.param(1, marks=pytest.mark.tier2),
+    pytest.param(2, marks=pytest.mark.tier2), 4])
 def test_pipeline_byte_identity(depth):
     """Depth-D pipelined prove of 4 mixed-kind jobs == 4 sequential
     proves, byte for byte. The depth-4 run also checks the stage
@@ -125,6 +127,7 @@ class _LatchCheckpoint(ProverCheckpoint):
             raise self.exc(f"latch fired after round {round_no}")
 
 
+@pytest.mark.tier2
 def test_pipeline_member_kill_resumes_alone(tmp_path):
     """A member-local failure at its round-2 latch takes down ONLY that
     member: the others complete in-flight (same call, correct bytes),
@@ -155,6 +158,7 @@ def test_pipeline_member_kill_resumes_alone(tmp_path):
     assert not resume_ck.has_snapshot()  # cleared on success
 
 
+@pytest.mark.tier2
 def test_pipeline_drain_parks_every_member(tmp_path):
     """An abort_on exception (the pool's drain signal) at one member's
     latch aborts the whole pipeline: every member parks at its OWN next
@@ -184,6 +188,7 @@ def test_pipeline_drain_parks_every_member(tmp_path):
 
 # --- service routing: queue coalescing fills the pipeline --------------------
 
+@pytest.mark.tier2
 def test_service_coalesces_queue_into_pipeline(monkeypatch):
     """With shape-batching OFF (jobs arrive as single dispatch units),
     a worker that pops one unit coalesces its queue neighbors into a

@@ -100,7 +100,8 @@ def test_leaser_blocking_handoff():
 
 # --- batched-vs-sequential byte-identity -------------------------------------
 
-@pytest.mark.parametrize("n_jobs", [2, 4])
+@pytest.mark.parametrize("n_jobs", [
+    2, pytest.param(4, marks=pytest.mark.tier2)])
 def test_prove_many_byte_identity_mixed_rngs(n_jobs):
     """prove_many == N sequential proves, bit for bit, with a DIFFERENT
     blinding rng per member (the per-member rng/transcript isolation the
@@ -129,6 +130,7 @@ def _batched_service_run(specs, **svc_kwargs):
     return svc, jobs
 
 
+@pytest.mark.tier2
 def test_service_batch_byte_identity():
     """The whole service path: 4 same-shape jobs pop as ONE placement
     batch, prove data-parallel, and every proof is byte-identical to an
@@ -149,6 +151,7 @@ def test_service_batch_byte_identity():
         svc.shutdown()
 
 
+@pytest.mark.tier2
 def test_batch_prove_knob_off_parity(monkeypatch):
     """DPT_BATCH_PROVE=0: same traffic takes the sequential per-job pool
     path — zero batched attempts — and lands on the identical bytes."""
@@ -168,6 +171,7 @@ def test_batch_prove_knob_off_parity(monkeypatch):
 
 # --- batch member kill: resumes alone, others unaffected ---------------------
 
+@pytest.mark.tier2
 def test_batch_member_kill_resumes_alone():
     """A kill armed at round 2 fires on exactly ONE batch member (the
     first to reach that boundary). The member's snapshot is durable, so
@@ -203,6 +207,7 @@ def test_batch_member_kill_resumes_alone():
         svc.shutdown()
 
 
+@pytest.mark.tier2
 def test_batch_member_kill_by_job_id():
     """A JOB-targeted kill inside a running batch takes down only that
     member. Uses a bigger shape so the kill lands mid-prove."""
@@ -265,6 +270,7 @@ class _RecordingMeshFactory:
         return _SlowBackend()
 
 
+@pytest.mark.tier2
 def test_submesh_lease_interleaved(monkeypatch):
     """A big 'mesh'-classified job leases a disjoint submesh of the
     injected 4-device pool while a small batch still gets served (and
@@ -310,6 +316,7 @@ def test_submesh_lease_interleaved(monkeypatch):
         svc.shutdown()
 
 
+@pytest.mark.tier2
 def test_mesh_retry_replaces_on_submesh(monkeypatch):
     """A mesh-placed job whose attempt is killed mid-prove goes BACK
     through the scheduler for re-placement: the retry runs on a fresh

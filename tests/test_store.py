@@ -258,6 +258,32 @@ def test_second_cache_instance_hits_disk_skips_build(tmp_path, monkeypatch):
     assert m2.snapshot()["counters"]["bucket_hits"] == 1
 
 
+def test_aot_warmup_reports_a_refused_stage_as_failed():
+    """A stage the compiler refuses to the AOT warmers is not a warm
+    shape: aot_warmup says "failed" and aot_errors hands out what the
+    compiler said (chip_smoke.py and scripts/warmup.py stop on it)."""
+    from distributed_plonk_tpu.store import aot_errors, aot_warmup
+
+    class Refusing:
+        name = "fake"
+
+        def warm_stages(self, domain_size, ck=None):
+            return {"ntt": {domain_size: {"compiled": 7, "failed": 1,
+                                          "errors": ["Mosaic said no"]}},
+                    "msm": {"compiled": 3, "failed": 0, "errors": []}}
+
+    class Compiling(Refusing):
+        def warm_stages(self, domain_size, ck=None):
+            return {"ntt": {domain_size: {"compiled": 8, "failed": 0,
+                                          "errors": []}}}
+
+    report = aot_warmup(Refusing(), 16)
+    assert report["aot"] == "failed"
+    assert aot_errors(report) == ["Mosaic said no"]
+    assert aot_warmup(Compiling(), 16)["aot"] == "ok"
+    assert aot_warmup(object(), 16)["aot"] == "unsupported"
+
+
 # --- bounded in-memory tier --------------------------------------------------
 
 def test_memory_tier_entry_cap_and_eviction_counter():

@@ -225,16 +225,24 @@ def test_prometheus_exposition():
 
 
 def test_observe_kernels_mfu_gauges():
-    from distributed_plonk_tpu.service.metrics import Metrics
+    from distributed_plonk_tpu.service.metrics import DEVICE_PEAKS, Metrics
     m = Metrics()
-    m.observe_kernels(
-        [{"span": "round1/commit_wires", "dur_s": 2.0, "flops": 4e9},
-         {"span": "round1", "dur_s": 1.0}],        # no flops: skipped
-        peak_tflops=0.004)
+    events = [{"span": "round1/commit_wires", "dur_s": 2.0, "flops": 4e9},
+              {"span": "round1", "dur_s": 1.0}]        # no flops: skipped
+    m.observe_kernels(events, device_kind="TPU v5 lite")
     g = m.snapshot()["gauges"]
     assert g["kernel_commit_wires_gflops"] == 2.0
-    assert g["mfu_commit_wires_pct"] == 50.0
+    peak = DEVICE_PEAKS["TPU v5 lite"]["bf16_tflops"] * 1e12
+    assert g["mfu_commit_wires_pct"] == round(100.0 * 4e9 / (2.0 * peak), 4)
     assert not any(k.endswith("round1_gflops") for k in g)
+    # a device kind with no published peak (or no device at all) still
+    # reports throughput but never an MFU against a made-up denominator
+    for kind in ("some future chip", None):
+        m = Metrics()
+        m.observe_kernels(events, device_kind=kind)
+        g = m.snapshot()["gauges"]
+        assert g["kernel_commit_wires_gflops"] == 2.0
+        assert not any(k.startswith("mfu_") for k in g)
 
 
 def test_obs_lint_catches_undocumented_metric():
