@@ -104,17 +104,17 @@ KERNEL_DIRS = ("backend", "parallel", "runtime")
 # (runtime/ added with the fleet fault domain: LivenessTracker state,
 # WorkerState task tables, peer-connection caches are all cross-thread;
 # obs/ added with the fleet observability plane: the log ring and the
-# scraper's latest-snapshot state are cross-thread too; prover.py /
+# scraper's latest-snapshot state are cross-thread too; prover/ /
 # circuits/ / aggregate.py added with ISSUE 19 — PipelinedProver and
 # the aggregation plane run under the pool's threads and had never
 # been linted. Entries ending in ".py" are single top-level modules.)
 LOCK_DIRS = ("service", "store", "runtime", "obs", "circuits",
-             "prover.py", "aggregate.py")
+             "prover", "aggregate.py")
 # modules that record metrics into the shared registry: the OBS01
 # glossary lint runs here; LOG01 (structured-log subsystem glossary)
 # shares the same scope
 OBS_DIRS = ("service", "store", "runtime", "obs", "circuits",
-            "prover.py", "aggregate.py")
+            "prover", "aggregate.py")
 
 # mutating container-method names treated as writes by LOCK01 (calls on
 # self.<attr>.<name>(...)); read-only or thread-safe APIs (queue.put,
@@ -1058,7 +1058,7 @@ def _module_globals(tree):
 
 def _iter_py(root, subdirs):
     """Yield .py files under each subdir; an entry ending in ".py" is a
-    single top-level module (prover.py / aggregate.py)."""
+    single top-level module (aggregate.py)."""
     for sub in subdirs:
         d = os.path.join(root, sub)
         if sub.endswith(".py"):

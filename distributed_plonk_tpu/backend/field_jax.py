@@ -54,6 +54,20 @@ def configure_compile_cache(base_dir, min_compile_secs=1.0):
     return path
 
 
+def named_jit(name, fn, **jit_kwargs):
+    """`jax.jit(fn, **jit_kwargs)` whose program is called `jit_<name>` in
+    every profile, HLO dump and compile-cache key. jax names a program
+    after the callable's `__name__`; a `functools.partial` has none
+    (`jit__unknown`), a lambda is `<lambda>` and the NTT plans' inner
+    kernels are all `fn`, so the device trace could not tell MSM from NTT.
+    Use it at every jit of a partial, a lambda or an inner function; a
+    function that already has a name of its own keeps it."""
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named, **jit_kwargs)
+
+
 configure_compile_cache(os.environ.get(
     "DPT_JAX_CACHE_DIR",
     os.path.normpath(os.path.join(

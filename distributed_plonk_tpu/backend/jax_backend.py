@@ -61,10 +61,16 @@ class _DevicePending:
     the device→host transfer. The prover's pipeline driver forces only at
     the owning member's host-finalize."""
 
-    __slots__ = ("force",)
+    __slots__ = ("force", "_arrays")
 
-    def __init__(self, force):
+    def __init__(self, force, arrays=()):
         self.force = force
+        self._arrays = arrays
+
+    def arrays(self):
+        """The device arrays whose readiness is this result's completion
+        (what the device ledger's watcher blocks on)."""
+        return self._arrays
 
 
 class JaxBackend:
@@ -95,6 +101,13 @@ class JaxBackend:
         self.lifts = 0
         self.lowers = 0
         self.drains = 0
+        # completion stamps and the fed/unfed account of THE device: one
+        # ledger, shared by every pool worker that proves on this backend
+        self.device_ledger = self._make_ledger()
+
+    def _make_ledger(self):
+        from ..trace import DeviceLedger
+        return DeviceLedger()
 
     def device_info(self):
         """Platform, device kind and device count as jax reports them for
@@ -667,7 +680,7 @@ class JaxBackend:
         def force():
             self.lowers += 1  # B scalars cross in one transfer
             return limbs_to_ints(np.asarray(out))
-        return _DevicePending(force)
+        return _DevicePending(force, (out,))
 
     def degree_is(self, h, d):
         if h.shape[1] <= d:

@@ -732,26 +732,29 @@ class MsmContext:
             self.point = tuple(jax.device_put(p)
                                for p in points_to_device(bases, pad))
         self._platform = next(iter(self.point[0].devices())).platform
+        # every program of the commit pipeline has a name of its own in
+        # the device trace (field_jax.named_jit): msm_digits,
+        # msm_digits_many, msm_bucket_scan, msm_merge, msm_finish
         if self.c_batch == 7:
-            self._digits_batch_fn = jax.jit(
-                partial(signed_digits7_from_mont, padded_n=self.padded_n))
+            digits = partial(signed_digits7_from_mont,
+                             padded_n=self.padded_n)
         elif self.signed:
-            self._digits_batch_fn = jax.jit(
-                partial(signed_digits_from_mont, padded_n=self.padded_n))
+            digits = partial(signed_digits_from_mont, padded_n=self.padded_n)
         else:
-            self._digits_batch_fn = jax.jit(
-                partial(digits_from_mont, c=self.c_batch,
-                        padded_n=self.padded_n))
+            digits = partial(digits_from_mont, c=self.c_batch,
+                             padded_n=self.padded_n)
+        self._digits_batch_fn = FJ.named_jit("msm_digits", digits)
         # stacked digit extraction (the cross-job commit_batch path): one
         # vmapped launch turns B same-width coefficient handles into the
         # (B, W, padded_n) digit tensor, instead of B separate dispatches.
         # vmap of the same elementwise program — bit-identical digits.
-        self._digits_many_fn = jax.jit(jax.vmap(self._digits_batch_fn))
+        self._digits_many_fn = FJ.named_jit(
+            "msm_digits_many", jax.vmap(self._digits_batch_fn))
         self._chunk_fns = {}
         self._chunk_calls = {}  # (nc, g) -> times executed (warm detection)
         self._finish_fns = {}
-        self._merge_fn = jax.jit(
-            lambda a, b: CJ.proj_add(tuple(a), tuple(b)))
+        self._merge_fn = FJ.named_jit(
+            "msm_merge", lambda a, b: CJ.proj_add(tuple(a), tuple(b)))
 
     # one device execution is kept under a lane-add budget: the runtime of
     # rounds 2-5 killed executions in the ~60 s range ("TPU worker process
@@ -794,14 +797,16 @@ class MsmContext:
             # key above): a plan whose nearest cell at the chunk width
             # disagrees must not make the traced branch diverge from
             # the key, the seeded rate, and the AOT-compiled variant
-            self._chunk_fns[key] = jax.jit(
+            self._chunk_fns[key] = FJ.named_jit(
+                "msm_bucket_scan",
                 partial(fn, group=group, kernel=self._mode()))
         return self._chunk_fns[key]
 
     def _finish_fn(self, batch):
         key = autotune.cache_key(batch)
         if key not in self._finish_fns:
-            self._finish_fns[key] = jax.jit(
+            self._finish_fns[key] = FJ.named_jit(
+                "msm_finish",
                 partial(finish_batch, batch=batch, signed=self.signed))
         return self._finish_fns[key]
 
@@ -1130,6 +1135,12 @@ class _MsmPending:
 
     def __init__(self, parts):
         self._parts = parts
+
+    def arrays(self):
+        """The device totals still to be decoded: their readiness is the
+        commit's completion (what the device ledger's watcher blocks on)."""
+        return [a for part in self._parts if part[0] == "dev"
+                for a in part[2]]
 
     def force(self):
         out = []

@@ -547,7 +547,6 @@ class NttPlan:
             plain = boundary == "plain"
             consts = self._kernel_consts(inverse, coset, radix, kmode)
 
-            @jax.jit
             def fn(v, consts):
                 if plain:
                     v = FJ.to_mont(FR, v)
@@ -557,7 +556,7 @@ class NttPlan:
                     v = FJ.from_mont(FR, v)
                 return v
 
-            self._fns[key] = (fn, consts)
+            self._fns[key] = (FJ.named_jit("ntt_plain", fn), consts)
         fn, consts = self._fns[key]
         return lambda v: fn(v, consts)
 
@@ -580,12 +579,11 @@ class NttPlan:
         if key not in self._fns:
             consts = self._kernel_consts(inverse, coset, radix, kmode)
 
-            @jax.jit
             def fn(v, consts):
                 return self._apply_batched(v, consts, radix, kmode,
                                            defer_perm=defer_perm)
 
-            self._fns[key] = (fn, consts)
+            self._fns[key] = (FJ.named_jit("ntt_batch", fn), consts)
         fn, consts = self._fns[key]
         return lambda v: fn(v, consts)
 
@@ -625,7 +623,6 @@ class NttPlan:
         if ck not in self._fns:
             consts = self._kernel_consts(inverse, coset, radix, kmode)
 
-            @jax.jit
             def fn(pro_args, epi_args, consts):
                 v = prologue(*pro_args) if prologue is not None \
                     else pro_args[0]
@@ -641,7 +638,13 @@ class NttPlan:
             # semantics (docstring) — callers rebuild structurally
             # identical closures per key; folding closure ids into the
             # key would retrace every prove for nothing
-            self._fns[ck] = (fn, consts)  # analysis: ok(key identifies prologue/epilogue by contract)
+            # the program is named after `key` (ntt_fused_r3gate_0_8,
+            # ntt_fused_r3combine, ...): the quotient stream's programs
+            # read apart from the plain NTTs in a device trace
+            name = "ntt_fused_" + "_".join(
+                str(part) for part in (key if isinstance(key, tuple)
+                                       else (key,)))
+            self._fns[ck] = (FJ.named_jit(name, fn), consts)  # analysis: ok(key identifies prologue/epilogue by contract)
         fn, consts = self._fns[ck]
         return lambda pro_args, epi_args=(): fn(tuple(pro_args),
                                                 tuple(epi_args), consts)

@@ -416,8 +416,8 @@ def tail_is_zero(poly, degree):
 
 # --- module-level jitted entry points (stable wrappers => no retracing) ------
 
-_from_mont_jit = jax.jit(partial(FJ.from_mont, FR))
-_to_mont_jit = jax.jit(partial(FJ.to_mont, FR))
+_from_mont_jit = FJ.named_jit("fr_from_mont", partial(FJ.from_mont, FR))
+_to_mont_jit = FJ.named_jit("fr_to_mont", partial(FJ.to_mont, FR))
 poly_eval_jit = jax.jit(poly_eval)
 poly_eval_many_jit = jax.jit(poly_eval_many)
 synthetic_divide_jit = jax.jit(synthetic_divide)
@@ -435,5 +435,6 @@ quotient_combine_slice_jit = jax.jit(quotient_combine_slice,
                                      static_argnames=("chunk",))
 domain_tables_jit = jax.jit(domain_tables, static_argnums=(0, 1, 2, 3))
 pack_jit = jax.jit(FJ.pack_limb_pairs)
-roll_jit = jax.jit(lambda v, r: jnp.roll(v, -r, axis=1), static_argnums=1)
+roll_jit = FJ.named_jit("roll", lambda v, r: jnp.roll(v, -r, axis=1),
+                        static_argnums=1)
 perm_product_jit = jax.jit(perm_product)

@@ -83,6 +83,11 @@ class MeshBackend(JaxBackend):
     # this knob only gates GSPMD propagation through the round math.
     _MIN_LOCAL = int(os.environ.get("DPT_MESH_MIN_LOCAL", "1024"))
 
+    def _make_ledger(self):
+        # the ledger's charge rule reads ONE device's queue; a mesh prove
+        # is sync here (no async hooks above), its spans time the compute
+        return None
+
     def __init__(self, mesh):
         super().__init__()
         self.mesh = mesh

@@ -45,14 +45,14 @@ import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 
-from .checkpoint import (_point_dec, _point_enc, dump_handle, load_handle,
-                         workload_fingerprint)
-from .constants import R_MOD
-from .fields import fr_inv
-from .poly import Domain
-from .circuit import NUM_WIRE_TYPES, Q_LC, Q_MUL, Q_HASH, Q_O, Q_C, Q_ECC
-from .trace import NULL_TRACER, msm_flops, ntt_flops
-from .transcript import StandardTranscript
+from ..checkpoint import (_point_dec, _point_enc, dump_handle, load_handle,
+                          workload_fingerprint)
+from ..constants import R_MOD
+from ..fields import fr_inv
+from ..poly import Domain
+from ..circuit import NUM_WIRE_TYPES, Q_LC, Q_MUL, Q_HASH, Q_O, Q_C, Q_ECC
+from ..trace import NULL_TRACER, msm_flops, ntt_flops
+from ..transcript import StandardTranscript
 
 # DPT_PIPELINE=0 is the bit-parity escape hatch: prove_pipelined degrades
 # to a plain sequential prove loop and the worker pool stops coalescing.
@@ -96,31 +96,53 @@ class _Ready:
         return self._values
 
 
+# how long force() waits for the watcher's completion stamp of a result it
+# has just fetched: the stamp is normally there already (both wake on the
+# same device event); past this the round goes without a device-true time
+# rather than the worker waiting on its own instrument
+_STAMP_WAIT_S = 2.0
+
+
 class _KernelPending:
-    """A dispatched-but-unforced device result. force() blocks until the
-    device delivers, then records a `kernels/<name>` trace event covering
-    dispatch→force with the flops/bytes attribution the sync path carries
-    on its kernel span — the `kernels/` prefix keeps these events out of
-    Tracer.totals(depth=1) round accounting (they overlap other members'
-    rounds under the pipeline, so adding them to per-round wall time would
-    double-count), while Metrics.observe_kernels still folds them into the
-    same per-stage MFU gauges via the last path segment."""
+    """A dispatched-but-unforced device result, the last of its round.
+    force() blocks until the device delivers, then records two events on
+    the device's own clock (trace.DeviceLedger's completion stamps):
+    `device/round<N>`, the device-true seconds the round is charged with
+    the round's summed flops/bytes model, and `kernels/<name>`, whose
+    `dur_s` is that same charge (an upper bound on the kernel's own time)
+    and whose `wait_s` is dispatch→force on the host's clock, which under
+    the pipeline includes other members' turns. Neither name is a
+    top-level span, so Tracer.totals(depth=1) round accounting never
+    double-books them; Metrics.observe_kernels folds both into the
+    per-stage gflops/MFU gauges via the last path segment. Without a
+    stamp (no ledger, or the watcher is late) the kernels/ event keeps
+    the host-clock duration and carries no flops."""
 
-    __slots__ = ("_force", "_tr", "_name", "_attrs", "_w0", "_p0")
+    __slots__ = ("_force", "_tr", "_name", "_attrs", "_w0", "_p0", "_round")
 
-    def __init__(self, force, tr, name, **attrs):
+    def __init__(self, force, tr, name, device_round=None, **attrs):
         self._force = force
         self._tr = tr
         self._name = name
         self._attrs = attrs
+        self._round = device_round      # (round no, DeviceRound, work)
         self._w0 = time.time()
         self._p0 = time.perf_counter()
 
     def force(self):
         values = self._force()
+        wait_s = dur_s = time.perf_counter() - self._p0
+        attrs = {}
+        if self._round is not None \
+                and self._round[1].done.wait(_STAMP_WAIT_S):
+            no, rnd, (flops, data_bytes) = self._round
+            self._tr.add_event("device/round%d" % no,
+                               ts=self._tr.wall(rnd.start),
+                               dur_s=rnd.charge, flops=flops,
+                               data_bytes=data_bytes)
+            dur_s, attrs = rnd.charge, self._attrs
         self._tr.add_event("kernels/" + self._name, ts=self._w0,
-                           dur_s=time.perf_counter() - self._p0,
-                           **self._attrs)
+                           dur_s=dur_s, wait_s=round(wait_s, 6), **attrs)
         return values
 
 
@@ -156,6 +178,28 @@ class _ProveCtx:
         self.stream_poly = getattr(backend, "quotient_poly_streamed", None)
         self.commit_async = getattr(backend, "commit_many_async", None)
         self.eval_async = getattr(backend, "eval_many_async", None)
+        # the device's completion-stamp ledger (trace.DeviceLedger), on
+        # backends that dispatch asynchronously to one device
+        self.ledger = (getattr(backend, "device_ledger", None)
+                       if self.commit_async is not None else None)
+
+    def round_work(self, no):
+        """Model (flops, data_bytes) of what round `no` puts on the
+        device for one member: the sums of the attributions its kernel
+        spans carry on a sync backend."""
+        n, m, nw = self.n, self.m, self.nw
+        if no == 1:
+            return (ntt_flops(n, nw) + msm_flops(n + 2, nw),
+                    nw * n * 32 + nw * (n + 2) * 32)
+        if no == 2:
+            return ntt_flops(n) + msm_flops(n + 3), n * 32 + (n + 3) * 32
+        if no == 3:
+            polys = len(self.sel_h) + 2 * nw + 2
+            return (ntt_flops(m, polys + 1) + msm_flops(n + 2, nw),
+                    (polys + 1) * m * 32 + nw * (n + 2) * 32)
+        if no == 5:
+            return msm_flops(n + 2, 2), 2 * (n + 2) * 32
+        return 0, 0
 
 
 class _Member:
@@ -175,15 +219,57 @@ class _Member:
         self.fp = None
         self.ck_arrays = {}
         self.ck_meta = {}
+        self.dev = None        # (round no, trace.DeviceRound) while open
+
+
+def _feed(cx, mb, no):
+    """The round's first dispatch is about to go out: open its interval
+    on the device ledger (the device is fed from here on, and the round's
+    device-true time starts no earlier). Placed by each launch half after
+    its host prelude; idempotent within a round."""
+    if cx.ledger is not None and mb.dev is None:
+        mb.dev = (no, cx.ledger.open(mb.tr.worker))
+
+
+def _unfeed(cx, mb):
+    """The launch half is over: a round that never reached its commit
+    dispatch (the launch raised) is closed here, so the ledger never
+    believes the device fed by a round that will not complete."""
+    if mb.dev is not None:
+        cx.ledger.close(mb.dev[1])
+        mb.dev = None
+
+
+def _watched(cx, mb, dev):
+    """Hand the round's last device arrays to the ledger's watcher;
+    returns what _KernelPending needs to report the round."""
+    if mb.dev is None:
+        return None
+    no, rnd = mb.dev
+    cx.ledger.watch(rnd, dev.arrays())
+    return no, rnd, cx.round_work(no)
+
+
+def _kspan(cx, mb, name, **attrs):
+    """A kernel span. On a backend with async dispatch the span times the
+    enqueue, so it carries no flops/bytes attribution (the round's
+    device/round<N> event does, against device-true time); on a sync
+    backend the span times the compute and keeps them."""
+    if cx.commit_async is not None:
+        attrs.pop("flops", None)
+        attrs.pop("data_bytes", None)
+    return mb.tr.span(name, **attrs)
 
 
 def _save_member(cx, mb, round_no):
     """THE round-boundary checkpoint latch — the one shared implementation
-    (sequential, lockstep, and pipelined drivers all land here), so the
-    snapshot payload can never drift between paths. Every guard control
-    point (kill/drain/TTL check, journal ROUND record, fault injection)
-    fires inside checkpoint.save's subclass hooks, so pipelined members
-    still hit them at their OWN stage boundaries."""
+    (sequential, lockstep, and pipelined drivers all call it right after
+    the round's finalize half, as a top-level `checkpoint_save` span of
+    its own, never inside the round's span), so the snapshot payload can
+    never drift between paths. Every guard control point (kill/drain/TTL
+    check, journal ROUND record, fault injection) fires inside
+    checkpoint.save's subclass hooks, so pipelined members still hit them
+    at their OWN stage boundaries."""
     if mb.checkpoint is None:
         return
     with mb.tr.span("checkpoint_save", round=round_no):
@@ -209,15 +295,15 @@ def _dispatch_commit(cx, mb, hs, name, span_attrs):
     span the sequential prover always recorded, so the host-oracle and
     mesh trace/MFU attribution is unchanged. `span_attrs` carries the
     flops/bytes model: on the kernel span for the sync path, moved onto
-    the force-side `kernels/<name>` event for the async path."""
+    the force-side `kernels/<name>` event for the async path, where the
+    commit's device arrays also close the round on the device ledger."""
     if cx.commit_async is not None:
-        lite = {k: v for k, v in span_attrs.items()
-                if k not in ("flops", "data_bytes")}
-        with mb.tr.span(name, **lite):
+        with _kspan(cx, mb, name, **span_attrs):
             dev = cx.commit_async(cx.ck, hs)
         attrs = {k: span_attrs[k] for k in ("flops", "data_bytes")
                  if k in span_attrs}
-        return _KernelPending(dev.force, mb.tr, name, **attrs)
+        return _KernelPending(dev.force, mb.tr, name,
+                              device_round=_watched(cx, mb, dev), **attrs)
     with mb.tr.span(name, **span_attrs):
         return _Ready(cx.backend.commit_many_h(cx.ck, hs))
 
@@ -226,14 +312,16 @@ def _dispatch_evals(cx, mb, pairs):
     """Round-4 evaluation dispatch; same contract as _dispatch_commit."""
     if cx.eval_async is not None:
         dev = cx.eval_async(pairs)
-        return _KernelPending(dev.force, mb.tr, "eval_many")
+        return _KernelPending(dev.force, mb.tr, "eval_many",
+                              device_round=_watched(cx, mb, dev))
     return _Ready(cx.backend.eval_many_h(pairs))
 
 
 # -- the five round stages ----------------------------------------------------
 # Each launch half runs challenges + host math + kernel dispatch and returns
-# a pending; each finalize half forces it, absorbs into the transcript, and
-# saves the round checkpoint (the stage latch). Each restore half reproduces
+# a pending; each finalize half takes its forced values and absorbs them
+# into the transcript, after which the driver saves the round checkpoint
+# (`_Stage.latch`, the stage latch). Each restore half reproduces
 # the resume path from a round-`no` snapshot, bit-for-bit the pre-stage
 # behavior. The cumulative checkpoint payload rule still holds: every
 # snapshot carries all state the REMAINING rounds read (wire/perm/quotient
@@ -246,11 +334,14 @@ def _launch_r1(cx, mb):
     # the merged timeline and the live MFU gauges (Metrics.observe_kernels)
     # can say where device time went, not just that it went
     be, n, nw = cx.backend, cx.n, cx.nw
-    with mb.tr.span("ifft_wires", polys=nw, flops=ntt_flops(n, nw),
-                    data_bytes=nw * n * 32):
+    with _kspan(cx, mb, "ifft_wires", polys=nw, flops=ntt_flops(n, nw),
+                data_bytes=nw * n * 32):
+        # the witness tables are built and uploaded by the host first
+        wires = be.wire_values(mb.ckt)
+        _feed(cx, mb, 1)
         # one batch call: concurrent across the fleet (join_all,
         # reference dispatcher2.rs:294-306) / one launch on device
-        wire_coeffs = be.ifft_many(cx.domain, be.wire_values(mb.ckt))
+        wire_coeffs = be.ifft_many(cx.domain, wires)
         mb.wire_polys = [be.blind(coeffs, _rand(mb.rng, 2), n)
                          for coeffs in wire_coeffs]
     return _dispatch_commit(
@@ -268,7 +359,6 @@ def _finalize_r1(cx, mb, comms):
                              for i, h in enumerate(mb.wire_polys)})
         mb.ck_meta["wires_poly_comms"] = [_point_enc(p)
                                           for p in mb.wires_poly_comms]
-    _save_member(cx, mb, 1)
 
 
 def _restore_r1(cx, mb, ck_state):
@@ -285,9 +375,10 @@ def _launch_r2(cx, mb):
     be, n = cx.backend, cx.n
     mb.beta = mb.transcript.get_and_append_challenge(b"beta")
     mb.gamma = mb.transcript.get_and_append_challenge(b"gamma")
+    _feed(cx, mb, 2)
     with mb.tr.span("perm_product"):
         product_h = be.perm_product(mb.ckt, mb.beta, mb.gamma, n)
-    with mb.tr.span("ifft_perm", flops=ntt_flops(n), data_bytes=n * 32):
+    with _kspan(cx, mb, "ifft_perm", flops=ntt_flops(n), data_bytes=n * 32):
         perm_coeffs = be.ifft_h(cx.domain, product_h)
     mb.permutation_poly = be.blind(perm_coeffs, _rand(mb.rng, 3), n)
     return _dispatch_commit(
@@ -305,7 +396,6 @@ def _finalize_r2(cx, mb, comms):
         mb.ck_meta["gamma"] = hex(mb.gamma)
         mb.ck_meta["prod_perm_poly_comm"] = \
             _point_enc(mb.prod_perm_poly_comm)
-    _save_member(cx, mb, 2)
 
 
 def _restore_r2(cx, mb, ck_state):
@@ -325,30 +415,32 @@ def _launch_r3(cx, mb):
         cx.release(mb.ckt)
     mb.alpha = mb.transcript.get_and_append_challenge(b"alpha")
     alpha_sq_div_n = mb.alpha * mb.alpha % R_MOD * fr_inv(n % R_MOD) % R_MOD
-    pi_coeffs = be.ifft_h(
-        cx.domain, be.lift(mb.pub + [0] * (n - len(mb.pub))))
+    pub_h = be.lift(mb.pub + [0] * (n - len(mb.pub)))
+    _feed(cx, mb, 3)
+    pi_coeffs = be.ifft_h(cx.domain, pub_h)
     quot_evals = None
     n_coset_polys = len(cx.sel_h) + 2 * nw + 2
     if cx.stream_poly is not None:
-        with mb.tr.span("quotient_stream_fused", m=m, polys=n_coset_polys,
-                        flops=ntt_flops(m, n_coset_polys + 1),
-                        data_bytes=n_coset_polys * m * 32):
+        with _kspan(cx, mb, "quotient_stream_fused", m=m,
+                    polys=n_coset_polys,
+                    flops=ntt_flops(m, n_coset_polys + 1),
+                    data_bytes=n_coset_polys * m * 32):
             quotient_poly = cx.stream_poly(
                 n, m, cx.quot_domain, cx.pk.vk.k, mb.beta, mb.gamma,
                 mb.alpha, alpha_sq_div_n, cx.sel_h, cx.sigma_h,
                 mb.wire_polys, mb.permutation_poly, pi_coeffs)
     elif cx.stream is not None:
-        with mb.tr.span("quotient_stream", m=m, polys=n_coset_polys,
-                        flops=ntt_flops(m, n_coset_polys),
-                        data_bytes=n_coset_polys * m * 32):
+        with _kspan(cx, mb, "quotient_stream", m=m, polys=n_coset_polys,
+                    flops=ntt_flops(m, n_coset_polys),
+                    data_bytes=n_coset_polys * m * 32):
             quot_evals = cx.stream(
                 n, m, cx.quot_domain, cx.pk.vk.k, mb.beta, mb.gamma,
                 mb.alpha, alpha_sq_div_n, cx.sel_h, cx.sigma_h,
                 mb.wire_polys, mb.permutation_poly, pi_coeffs)
     else:
-        with mb.tr.span("coset_ffts", polys=n_coset_polys,
-                        flops=ntt_flops(m, n_coset_polys),
-                        data_bytes=n_coset_polys * m * 32):
+        with _kspan(cx, mb, "coset_ffts", polys=n_coset_polys,
+                    flops=ntt_flops(m, n_coset_polys),
+                    data_bytes=n_coset_polys * m * 32):
             # the 24 coset-FFTs go out as one batch (concurrent across
             # the fleet / one device launch; dispatcher2.rs:382-423)
             batch = be.coset_fft_many(
@@ -370,8 +462,8 @@ def _launch_r3(cx, mb):
             del batch, selectors_coset, sigmas_coset, wires_coset
             del z_coset, pi_coset
     if quot_evals is not None:
-        with mb.tr.span("coset_ifft_quot", flops=ntt_flops(m),
-                        data_bytes=m * 32):
+        with _kspan(cx, mb, "coset_ifft_quot", flops=ntt_flops(m),
+                    data_bytes=m * 32):
             quotient_poly = be.coset_ifft_h(cx.quot_domain, quot_evals)
 
     expected_degree = nw * (n + 1) + 2
@@ -396,7 +488,6 @@ def _finalize_r3(cx, mb, comms):
         mb.ck_meta["alpha"] = hex(mb.alpha)
         mb.ck_meta["split_quot_poly_comms"] = [
             _point_enc(p) for p in mb.split_quot_poly_comms]
-    _save_member(cx, mb, 3)
 
 
 def _restore_r3(cx, mb, ck_state):
@@ -420,6 +511,7 @@ def _launch_r4(cx, mb):
              + [(s, mb.zeta) for s in cx.sigma_h[:cx.nw - 1]]
              + [(mb.permutation_poly,
                  mb.zeta * cx.domain.group_gen % R_MOD)])
+    _feed(cx, mb, 4)
     return _dispatch_evals(cx, mb, pairs)
 
 
@@ -436,7 +528,6 @@ def _finalize_r4(cx, mb, evals):
         mb.ck_meta["wire_sigma_evals"] = [hex(v)
                                           for v in mb.wire_sigma_evals]
         mb.ck_meta["perm_next_eval"] = hex(mb.perm_next_eval)
-    _save_member(cx, mb, 4)
 
 
 def _restore_r4(cx, mb, ck_state):
@@ -452,6 +543,7 @@ def _launch_r5(cx, mb):
     # src/dispatcher2.rs:563-692)
     be, n, nw = cx.backend, cx.n, cx.nw
     vanish_eval = (pow(mb.zeta, n, R_MOD) - 1) % R_MOD
+    _feed(cx, mb, 5)
     with mb.tr.span("lin_poly"):
         lin_poly = _linearization_poly(
             be, cx.pk, cx.sel_h, cx.sigma_h, n, mb.beta, mb.gamma,
@@ -494,10 +586,11 @@ def _finalize_r5(cx, mb, comms):
 
 class _Stage:
     """One prover round as a pipeline stage: a device-launch half (returns
-    an unforced pending), a host-finalize half (forces it, absorbs into
-    the member's transcript, persists the round checkpoint — the stage
-    LATCH), and a restore half reproducing the resume path from a
-    round-`no` snapshot (round 5 never snapshots, so it has none)."""
+    an unforced pending), a host-finalize half (forces it and absorbs into
+    the member's transcript; the driver then persists the round
+    checkpoint with `_save_member` — the stage LATCH), and a restore half
+    reproducing the resume path from a round-`no` snapshot (round 5 never
+    snapshots, so it has none)."""
 
     __slots__ = ("no", "name", "launch", "finalize", "restore")
 
@@ -507,6 +600,24 @@ class _Stage:
         self.launch = launch
         self.finalize = finalize
         self.restore = restore
+
+    def run_launch(self, cx, mb, force=False):
+        """The launch half under its round span, returning the pending
+        (or, with `force`, its values: the sequential driver's round span
+        ends after the fetch). A round the launch opened on the device
+        ledger and did not hand to the watcher (it raised first) is closed
+        on the way out."""
+        with mb.tr.span(self.name):
+            try:
+                pending = self.launch(cx, mb)
+                return pending.force() if force else pending
+            finally:
+                _unfeed(cx, mb)
+
+    def latch(self, cx, mb):
+        """The round's checkpoint, after its finalize half."""
+        if self.restore is not None:
+            _save_member(cx, mb, self.no)
 
 
 _STAGES = (
@@ -542,21 +653,19 @@ def prove(rng, circuit, pk, backend, tracer=None, checkpoint=None):
     # recomputing, and the transcript sponge + blinder RNG rewind to the
     # snapshot point so the challenge schedule continues bit-for-bit
     start = 0
-    ck_state = None
     if checkpoint is not None:
-        mb.fp = workload_fingerprint(pk.vk, mb.pub)
-        ck_state = checkpoint.load(mb.fp)
-        if ck_state is not None:
-            start = ck_state["round"]
-            checkpoint.restore_into(ck_state, mb.rng, mb.transcript)
+        with mb.tr.span("guard_open"):
+            mb.fp = workload_fingerprint(pk.vk, mb.pub)
+            ck_state = checkpoint.load(mb.fp)
+            if ck_state is not None:
+                start = ck_state["round"]
+                checkpoint.restore_into(ck_state, mb.rng, mb.transcript)
+                for st in _STAGES[:start]:
+                    st.restore(cx, mb, ck_state)
 
-    for st in _STAGES:
-        if st.no <= start:
-            st.restore(cx, mb, ck_state)
-        else:
-            with mb.tr.span(st.name):
-                values = st.launch(cx, mb).force()
-            st.finalize(cx, mb, values)
+    for st in _STAGES[start:]:
+        st.finalize(cx, mb, st.run_launch(cx, mb, force=True))
+        st.latch(cx, mb)
     return mb.proof
 
 
@@ -591,12 +700,23 @@ def prove_many(rngs, circuits, pk, backend, tracers=None, checkpoints=None,
 
     Returns (proofs, errors): per-member Proof-or-None and
     exception-or-None lists."""
+    cx = _ProveCtx(pk, backend)
+    dev = []    # the batch's round open on the device ledger, if one is
+    try:
+        return _prove_many(cx, dev, rngs, circuits, pk, backend, tracers,
+                           checkpoints, abort_on)
+    finally:
+        if dev:   # a batch-wide failure between a round's feed and fed
+            cx.ledger.close(dev.pop())
+
+
+def _prove_many(cx, dev, rngs, circuits, pk, backend, tracers, checkpoints,
+                abort_on):
     N = len(circuits)
     rngs = list(rngs)
     tracers = list(tracers) if tracers is not None else [None] * N
     checkpoints = (list(checkpoints) if checkpoints is not None
                    else [None] * N)
-    cx = _ProveCtx(pk, backend)
     n, domain, num_wire_types = cx.n, cx.domain, cx.nw
     quot_domain, m, ck = cx.quot_domain, cx.m, cx.ck
     sel_h, sigma_h = cx.sel_h, cx.sigma_h
@@ -620,14 +740,19 @@ def prove_many(rngs, circuits, pk, backend, tracers=None, checkpoints=None,
             except Exception as e:
                 errors[i] = e
             continue
+        # a batch member is in a round (the batch's, whole), in a span of
+        # its own, or waiting for its batch-mates: `pipeline_wait`
+        mb.tr.waits = "pipeline_wait"
+        mb.tr.park("pipeline_wait")
         mb.transcript.append_vk_and_pub_input(pk.vk, mb.pub)
         if mb.checkpoint is not None:
-            mb.fp = workload_fingerprint(pk.vk, mb.pub)
             # round-0 control point, parity with prove(): loading the
             # (absent) snapshot runs the guard's pre-round check — a
             # kill/drain armed at round 0 fires for batch members too
             try:
-                mb.checkpoint.load(mb.fp)
+                with mb.tr.span("guard_open"):
+                    mb.fp = workload_fingerprint(pk.vk, mb.pub)
+                    mb.checkpoint.load(mb.fp)
             except abort_on:
                 raise
             except Exception as e:
@@ -651,19 +776,46 @@ def prove_many(rngs, circuits, pk, backend, tracers=None, checkpoints=None,
             kept.append(mb)
         live = kept
 
+    def begin_round():
+        # the members' wait for the batch ends where the round begins
+        for mb in live:
+            mb.tr.unpark()
+        return time.time(), time.perf_counter()
+
     def mark_round(name, wall0, dur):
         # every member's timeline shows the batch round it rode in (the
-        # launches are shared, so the span IS each job's wall time)
+        # launches are shared, so the span IS each job's wall time); the
+        # checkpoint latches that follow are spans of each member's own
         for mb in live:
             mb.tr.add_event(name, ts=wall0, dur_s=dur,
                             batched_jobs=len(live))
+            mb.tr.park("pipeline_wait")
+
+    # the batch's rounds on the device ledger (`dev` holds the open one):
+    # opened at the round's first dispatch, closed when its (sync) commit
+    # has returned, the charge shared equally among the members in it
+    def feed():
+        if cx.ledger is not None and not dev and live:
+            dev.append(cx.ledger.open(live[0].tr.worker))
+
+    def fed(no):
+        if not dev:
+            return
+        rnd = dev.pop()
+        cx.ledger.close(rnd)
+        flops, data_bytes = cx.round_work(no)
+        for mb in live:
+            mb.tr.add_event("device/round%d" % no, ts=mb.tr.wall(rnd.start),
+                            dur_s=rnd.charge / len(live), flops=flops,
+                            data_bytes=data_bytes, batched_jobs=len(live))
 
     # --- Round 1: wire polynomials (one iFFT + one commit launch set) -------
-    w0, p0 = time.time(), time.perf_counter()
+    w0, p0 = begin_round()
     if live:
         all_wires = []
         for mb in live:
             all_wires.extend(backend.wire_values(mb.ckt))
+        feed()
         coeffs = backend.ifft_many(domain, all_wires)
         polys = []
         for j, mb in enumerate(live):
@@ -672,18 +824,21 @@ def prove_many(rngs, circuits, pk, backend, tracers=None, checkpoints=None,
                              for c in cs]
             polys.extend(mb.wire_polys)
         comms = commit_many(ck, polys)
+        fed(1)
         for j, mb in enumerate(live):
             mb.wires_poly_comms = \
                 comms[num_wire_types * j:num_wire_types * (j + 1)]
         each_live(lambda mb: _finalize_r1(cx, mb, mb.wires_poly_comms))
         mark_round("round1", w0, time.perf_counter() - p0)
+        each_live(lambda mb: _save_member(cx, mb, 1))
 
     # --- Round 2: permutation product ---------------------------------------
-    w0, p0 = time.time(), time.perf_counter()
+    w0, p0 = begin_round()
     if live:
         def r2a(mb):
             mb.beta = mb.transcript.get_and_append_challenge(b"beta")
             mb.gamma = mb.transcript.get_and_append_challenge(b"gamma")
+            feed()
             mb.product_h = backend.perm_product(mb.ckt, mb.beta, mb.gamma, n)
         each_live(r2a)
     if live:
@@ -697,21 +852,24 @@ def prove_many(rngs, circuits, pk, backend, tracers=None, checkpoints=None,
         each_live(r2b)
     if live:
         comms = commit_many(ck, [mb.permutation_poly for mb in live])
+        fed(2)
         for mb, c in zip(live, comms):
             mb.prod_perm_poly_comm = c
         each_live(lambda mb: _finalize_r2(cx, mb, [mb.prod_perm_poly_comm]))
         mark_round("round2", w0, time.perf_counter() - p0)
+        each_live(lambda mb: _save_member(cx, mb, 2))
 
     if cx.release is not None:
         for mb in live:
             cx.release(mb.ckt)
 
     # --- Round 3: quotient polynomial (per-member pipeline, one commit) -----
-    w0, p0 = time.time(), time.perf_counter()
+    w0, p0 = begin_round()
     if live:
-        pis = backend.ifft_many(
-            domain, [backend.lift(mb.pub + [0] * (n - len(mb.pub)))
-                     for mb in live])
+        pubs = [backend.lift(mb.pub + [0] * (n - len(mb.pub)))
+                for mb in live]
+        feed()
+        pis = backend.ifft_many(domain, pubs)
         for mb, pi in zip(live, pis):
             mb.pi_coeffs = pi
 
@@ -753,14 +911,16 @@ def prove_many(rngs, circuits, pk, backend, tracers=None, checkpoints=None,
     if live:
         comms = commit_many(ck, [h for mb in live
                                  for h in mb.split_quot_polys])
+        fed(3)
         for j, mb in enumerate(live):
             mb.split_quot_poly_comms = \
                 comms[num_wire_types * j:num_wire_types * (j + 1)]
         each_live(lambda mb: _finalize_r3(cx, mb, mb.split_quot_poly_comms))
         mark_round("round3", w0, time.perf_counter() - p0)
+        each_live(lambda mb: _save_member(cx, mb, 3))
 
     # --- Round 4: evaluations (one launch across all members) ---------------
-    w0, p0 = time.time(), time.perf_counter()
+    w0, p0 = begin_round()
     if live:
         def r4a(mb):
             mb.zeta = mb.transcript.get_and_append_challenge(b"zeta")
@@ -773,18 +933,22 @@ def prove_many(rngs, circuits, pk, backend, tracers=None, checkpoints=None,
                 + [(s, mb.zeta) for s in sigma_h[:num_wire_types - 1]]
                 + [(mb.permutation_poly,
                     mb.zeta * domain.group_gen % R_MOD)])
+        feed()
         evals = backend.eval_many_h(pairs)
+        fed(4)
         per = 2 * num_wire_types  # 5 wires + 4 sigmas + z_next
         for j, mb in enumerate(live):
             mb._evs = evals[per * j:per * (j + 1)]
         each_live(lambda mb: _finalize_r4(cx, mb, mb._evs))
         mark_round("round4", w0, time.perf_counter() - p0)
+        each_live(lambda mb: _save_member(cx, mb, 4))
 
     # --- Round 5: linearization + openings (one commit launch) --------------
-    w0, p0 = time.time(), time.perf_counter()
+    w0, p0 = begin_round()
     if live:
         def r5a(mb):
             vanish_eval = (pow(mb.zeta, n, R_MOD) - 1) % R_MOD
+            feed()
             lin_poly = _linearization_poly(
                 backend, pk, sel_h, sigma_h, n, mb.beta, mb.gamma,
                 mb.alpha, mb.zeta, vanish_eval, mb.wires_evals,
@@ -807,6 +971,7 @@ def prove_many(rngs, circuits, pk, backend, tracers=None, checkpoints=None,
         comms = commit_many(ck, [h for mb in live
                                  for h in (mb.witness_poly,
                                            mb.shifted_witness_poly)])
+        fed(5)
         for j, mb in enumerate(live):
             mb._open_comms = (comms[2 * j], comms[2 * j + 1])
 
@@ -838,8 +1003,9 @@ class PipelinedProver:
     members, which no per-member state observes.
 
     observer: optional callable; called once per completed stage with
-    {round, depth, stage_wait_s, force_wait_s, finalize_s, device_idle_s}
-    — the pool turns these into the pipeline_* metrics."""
+    {round, depth, stage_wait_s, force_wait_s, finalize_s} (finalize_s
+    runs through the round's checkpoint latch) — the pool turns these
+    into the pipeline_* metrics."""
 
     def __init__(self, backend, depth=None, abort_on=(), observer=None):
         self.backend = backend
@@ -878,12 +1044,17 @@ class PipelinedProver:
                 except Exception as e:
                     errors[i] = e
                 continue
+            # from here on the member is in one of its own top-level
+            # spans or waiting for its turn: `pipeline_wait`, by stamps
+            mb.tr.waits = "pipeline_wait"
+            mb.tr.park("pipeline_wait")
             mb.transcript.append_vk_and_pub_input(mb.cx.pk.vk, mb.pub)
             if mb.checkpoint is not None:
-                mb.fp = workload_fingerprint(mb.cx.pk.vk, mb.pub)
                 # round-0 control point, parity with prove()
                 try:
-                    mb.checkpoint.load(mb.fp)
+                    with mb.tr.span("guard_open"):
+                        mb.fp = workload_fingerprint(mb.cx.pk.vk, mb.pub)
+                        mb.checkpoint.load(mb.fp)
                 except self.abort_on:
                     raise
                 except Exception as e:
@@ -897,16 +1068,12 @@ class PipelinedProver:
         ex = ThreadPoolExecutor(max_workers=1)
 
         def submit(mb):
-            st = _STAGES[mb.stage]
-
-            def _launch():
-                # the round span covers this member's launch half only;
-                # its finalize half gets its own roundN_finalize span, and
-                # forced device time lands on the kernels/* events — so a
-                # pipelined trace never double-books overlapped wall time
-                with mb.tr.span(st.name):
-                    return st.launch(mb.cx, mb)
-            mb._fut = ex.submit(_launch)
+            # the round span covers this member's launch half only; its
+            # finalize half gets its own roundN_finalize span, and the
+            # round's device-true time lands on the device/* and kernels/*
+            # events — so a pipelined trace never double-books overlapped
+            # wall time
+            mb._fut = ex.submit(_STAGES[mb.stage].run_launch, mb.cx, mb)
 
         try:
             while queue or inflight:
@@ -931,6 +1098,7 @@ class PipelinedProver:
                         values = pending.force()
                         force_s = time.perf_counter() - t1
                         st.finalize(mb.cx, mb, values)
+                    st.latch(mb.cx, mb)
                 except self.abort_on:
                     raise
                 except Exception as e:
@@ -947,7 +1115,6 @@ class PipelinedProver:
                         "stage_wait_s": wait_s,
                         "force_wait_s": force_s,
                         "finalize_s": fin_s,
-                        "device_idle_s": max(0.0, fin_s - force_s),
                     })
                 mb.stage += 1
                 if mb.stage >= len(_STAGES):

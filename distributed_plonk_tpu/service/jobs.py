@@ -292,6 +292,13 @@ class Job:
         self.proof_bytes = None
         self.public_input = None
         self.round_totals = {}
+        # seconds by phase outside the rounds (trace.PHASES, plus
+        # `unaccounted`) and device-true seconds by round, of the attempt
+        # that produced the proof; with `rounds` they tile run_s
+        self.phases = {}
+        self.device = {}
+        # (wall ts, seconds) of the scheduler's bucket-key lookup
+        self.key_lookup = None
         self.done_event = threading.Event()
 
     @property
@@ -308,10 +315,13 @@ class Job:
         end = self.finished_at or time.monotonic()
         return end - self.started_at
 
-    def finish_ok(self, proof_bytes, public_input, round_totals):
+    def finish_ok(self, proof_bytes, public_input, round_totals,
+                  phases=None, device=None):
         self.proof_bytes = proof_bytes
         self.public_input = public_input
         self.round_totals = round_totals
+        self.phases = phases or {}
+        self.device = device or {}
         self.state = DONE
         self.finished_at = time.monotonic()
         self.done_event.set()
@@ -361,5 +371,7 @@ class Job:
             "wait_s": round(self.wait_s, 6),
             "run_s": None if self.run_s is None else round(self.run_s, 6),
             "rounds": {k: round(v, 6) for k, v in self.round_totals.items()},
+            "phases": {k: round(v, 6) for k, v in self.phases.items()},
+            "device": {k: round(v, 6) for k, v in self.device.items()},
             "error": self.error,
         }

@@ -31,7 +31,22 @@ Job lifecycle (service/server.py, service/pool.py, service/queue.py):
     job_wait / job_run (histograms)                  submit->start and
                                                      start->done seconds
     prove_round/* (histograms)                       per-round prover
-                                                     latency (trace totals)
+                                                     latency (trace totals:
+                                                     round1..5, and
+                                                     roundN_finalize under
+                                                     the round pipeline)
+    prove_phase/* (histograms)                       a job's seconds on its
+                                                     worker OUTSIDE the
+                                                     rounds, by phase (STATUS
+                                                     `phases`): circuit_build,
+                                                     guard_open,
+                                                     checkpoint_save,
+                                                     pipeline_wait, serialize,
+                                                     self_verify, journal_done,
+                                                     trace_store, and
+                                                     unaccounted (run_s less
+                                                     every span above and the
+                                                     rounds)
     queue_depth / queue_high_water (gauges)          admission backlog
 
 Scheduler + shape buckets (service/scheduler.py):
@@ -88,11 +103,28 @@ Round-pipelined proving (prover.PipelinedProver via pool._run_pipeline):
                                              oldest ready stage (also per
                                              round: pipeline_stage_wait_s/
                                              round<N>)
-    pipeline_device_idle_s/round<N> (gauge)  host-finalize span not covered
-                                             by the device force — the
-                                             serial host work the pipeline
-                                             overlaps with other members'
-                                             launches
+
+Device ledger, the fed/unfed account of the chip (trace.DeviceLedger, owned
+by the backend, read at every snapshot through Metrics.add_source and
+charged up to that instant; float seconds, cumulative since the backend was
+made; absent on a backend with no ledger, the host oracle and the mesh):
+    phase_clock_s                            all time on the ledger's clock
+    device_unfed_s                           seconds in which no round of
+                                             ours was outstanding on the
+                                             device (nothing dispatched and
+                                             not yet complete): the chip had
+                                             nothing to run
+    device_unfed_s/*                         the same seconds by the phase
+                                             the worker whose dispatch ended
+                                             the gap was in during it
+                                             (worker_idle: waiting for a
+                                             job; other: between spans;
+                                             circuit_build, guard_open,
+                                             round<N>, round<N>_finalize,
+                                             checkpoint_save, serialize,
+                                             self_verify, journal_done,
+                                             trace_store); they sum to
+                                             device_unfed_s
 
 Artifact store, scoped `store_*` (store/artifacts.py, store/remote.py):
     store_hits / store_misses / store_evictions      blob cache activity
@@ -239,10 +271,18 @@ Tracing vocabulary (trace.py, service/pool.py, server.py --obs-port):
                                              requests served
     kernel_*_gflops / mfu_*_pct (gauges)     live per-stage throughput
                                              and model-flops MFU from
-                                             kernel span attrs (peak set
-                                             by the DEVICE_PEAKS entry
-                                             of the backend's chip; none
-                                             for an unknown device)
+                                             events that carry `flops`:
+                                             on the device backend the
+                                             device/round<N> and kernels/
+                                             <commit> events, whose dur_s
+                                             is device-true (completion
+                                             stamps, never the enqueue);
+                                             on a sync backend the kernel
+                                             spans, which time the
+                                             compute (peak set by the
+                                             DEVICE_PEAKS entry of the
+                                             backend's chip; none for an
+                                             unknown device)
 
 Fleet observability vocabulary (obs/log.py, obs/fleet.py,
 runtime/worker.py METRICS_FETCH/LOG_FETCH/PROFILE — the one-pane plane,
@@ -434,6 +474,7 @@ class Metrics:
         self._counters = {}
         self._gauges = {}
         self._hists = {}
+        self._sources = []
         self.started_at = time.monotonic()
 
     def inc(self, name, by=1):
@@ -451,6 +492,16 @@ class Metrics:
                 h = self._hists[name] = Histogram()
             h.record(seconds)
 
+    def add_source(self, source):
+        """Register an account that keeps its own cumulative counters and
+        is read at snapshot time: `source.counters()` -> {name: value},
+        charged up to the instant of the call (trace.DeviceLedger: the
+        fed/unfed account of the device has an interval open at any
+        moment, so it is asked, not told). Idempotent per source."""
+        with self._lock:
+            if not any(s is source for s in self._sources):
+                self._sources.append(source)
+
     def scoped(self, prefix):
         """A view of this registry that prefixes every metric name with
         `prefix_` — how subsystems with their own metric vocabulary (the
@@ -458,11 +509,15 @@ class Metrics:
         one service registry without hardcoding its namespace."""
         return _Scoped(self, prefix)
 
-    def observe_rounds(self, totals):
-        """Fold a prove's trace.Tracer.totals() into per-round histograms
-        (keys like round1..round5, checkpoint_save)."""
+    def observe_rounds(self, totals, phases=None):
+        """Fold a prove's round spans (trace.Tracer.totals(): round1..
+        round5, roundN_finalize) into prove_round/<name> histograms and
+        its phases (STATUS `phases`: circuit_build, checkpoint_save, ...)
+        into prove_phase/<name>."""
         for span, dur in totals.items():
             self.observe(f"prove_round/{span}", dur)
+        for span, dur in (phases or {}).items():
+            self.observe(f"prove_phase/{span}", dur)
 
     def observe_kernels(self, events, device_kind=None):
         """Fold kernel spans carrying `flops` attrs (trace.Tracer events
@@ -488,11 +543,18 @@ class Metrics:
 
     def snapshot(self):
         with self._lock:
+            sources = list(self._sources)
+        # sources first, outside our lock (each has its own): they charge
+        # their open interval, so the reading is true to this instant
+        pulled = {}
+        for source in sources:
+            pulled.update(source.counters())
+        with self._lock:
             done = self._counters.get("jobs_completed", 0)
             uptime = time.monotonic() - self.started_at
             return {
                 "uptime_s": round(uptime, 3),
-                "counters": dict(self._counters),
+                "counters": dict(self._counters, **pulled),
                 "gauges": dict(self._gauges),
                 # analysis: ok(Histogram.snapshot is a lockless data object)
                 "histograms": {k: h.snapshot()

@@ -46,7 +46,7 @@ from ..obs import log as olog
 from .. import prover as _prover
 from ..prover import prove, prove_many, prove_pipelined
 from ..proof_io import serialize_proof
-from ..trace import Tracer
+from ..trace import PHASES, Tracer
 from . import jobs as J
 from . import journal as JN
 
@@ -154,6 +154,10 @@ class _Worker:
         self.deadline = None
         self.busy_jobs = []        # jobs this slot is proving right now
         self.thread = None
+        # the device ledger of the backend this slot proves on
+        # (trace.DeviceLedger; None on a backend that has none): the
+        # slot's jobs report their top-level spans to it as its phase
+        self.ledger = None
         # pool-wide forced-drain flag: set once the drain deadline passes,
         # observed here at round boundaries (the snapshot just became
         # durable — the cheapest possible point to stop)
@@ -406,10 +410,19 @@ class WorkerPool:
 
     def _loop(self, worker):
         backend = self.backend_factory()
+        ledger = worker.ledger = getattr(backend, "device_ledger", None)
+        if ledger is not None:
+            # the fed/unfed account of the device is read, charged to the
+            # instant, with every METRICS snapshot
+            self.metrics.add_source(ledger)
         while True:
+            if ledger is not None:
+                ledger.idle(worker.index)
             item = self._dispatch_q.get()
             if item is _STOP:
                 return
+            if ledger is not None:
+                ledger.busy(worker.index)
             if not self._run_item(worker, backend, item):
                 return
 
@@ -573,9 +586,7 @@ class WorkerPool:
         self.metrics.inc("batch_proves")
         self.metrics.inc("batch_jobs", len(live))
         self.metrics.observe("batch_jobs_per_launch", len(live))
-        tracers = [self._job_tracer(worker, job) for job in live]
-        ckts = [J.build_circuit(job.spec) for job in live]
-        guards = [self._make_guard(job, worker) for job in live]
+        tracers, ckts, guards = self._open_members(worker, live)
         rngs = [random.Random(job.spec.seed) for job in live]
         if self.job_timeout_s is not None:
             worker.deadline = (min(j.started_at for j in live)
@@ -644,11 +655,31 @@ class WorkerPool:
         worker.kill_arm = None
         return True
 
+    def _open_members(self, worker, live):
+        """Tracers, circuits and checkpoint guards of the members of a
+        batched or pipelined attempt. The circuits are built one after
+        another, so from here until its prove ends a member is either in
+        a span of its own (`circuit_build`, `guard_open`, its rounds) or
+        waiting for its mates: every tracer is parked under
+        `pipeline_wait` between its top-level spans."""
+        tracers = [self._job_tracer(worker, job) for job in live]
+        for tracer in tracers:
+            tracer.waits = "pipeline_wait"
+            tracer.park("pipeline_wait")
+        ckts, guards = [], []
+        for job, tracer in zip(live, tracers):
+            with tracer.span("circuit_build"):
+                ckts.append(J.build_circuit(job.spec))
+        for job, tracer in zip(live, tracers):
+            with tracer.span("guard_open"):
+                guards.append(self._make_guard(job, worker))
+        return tracers, ckts, guards
+
     def _pipeline_observer(self):
         """Stage-level pipeline telemetry -> metrics: the live fill
-        gauge, the achieved-depth histogram, per-round stage-wait
-        histograms, and the device-idle estimate (host-finalize span not
-        covered by the device force — the overlap the pipeline buys)."""
+        gauge, the achieved-depth histogram and per-round stage-wait
+        histograms. (What the device waited for is the ledger's account:
+        device_unfed_s/<phase>.)"""
         m = self.metrics
 
         def observe(ev):
@@ -658,8 +689,6 @@ class WorkerPool:
             m.observe("pipeline_stage_wait_s", ev["stage_wait_s"])
             m.observe("pipeline_stage_wait_s/round%d" % r,
                       ev["stage_wait_s"])
-            m.gauge("pipeline_device_idle_s/round%d" % r,
-                    ev["device_idle_s"])
         return observe
 
     def _run_pipeline(self, worker, backend, units):
@@ -701,9 +730,7 @@ class WorkerPool:
                 self.metrics.observe("batch_jobs_per_launch", n)
         self.metrics.inc("pipelined_proves")
         self.metrics.inc("pipelined_jobs", len(live))
-        tracers = [self._job_tracer(worker, job) for job in live]
-        ckts = [J.build_circuit(job.spec) for job in live]
-        guards = [self._make_guard(job, worker) for job in live]
+        tracers, ckts, guards = self._open_members(worker, live)
         rngs = [random.Random(job.spec.seed) for job in live]
         pks = [res.pk for res in reses]
         if self.job_timeout_s is not None:
@@ -826,44 +853,72 @@ class WorkerPool:
         trace timeline shows how the scheduler routed the job."""
         tracer = Tracer(trace_id=job.trace_id,
                         parent_id=job.trace_parent,
-                        proc=f"pool/{worker.name}")
+                        proc=f"pool/{worker.name}",
+                        ledger=worker.ledger, worker=worker.index)
         tracer.add_event("service/queued", ts=job.submitted_wall,
                          dur_s=job.wait_s, job_id=job.id,
                          placement=job.placement,
                          batch_size=job.batch_size)
+        if job.key_lookup is not None:
+            # the scheduler's bucket-key lookup (memory, disk, peer or a
+            # full build), inside the queue wait
+            tracer.add_event("service/key_lookup", ts=job.key_lookup[0],
+                             dur_s=job.key_lookup[1], job_id=job.id)
         return tracer
 
     def _finish_proved(self, job, res, ckt, proof, tracer, backend):
         """Post-prove completion shared by the single and batched paths:
-        verify-before-serve, round/kernel metrics, finished-proof
-        durability, trace artifact, client-visible done. ORDER IS THE
+        verify-before-serve, finished-proof durability, trace artifact,
+        round/phase/kernel metrics, client-visible done. ORDER IS THE
         CONTRACT: the self-verify gate runs on the serialized bytes
         BEFORE the journal DONE append, so a corrupted proof can never
         be journaled as done, served from an artifact after a restart,
-        or handed to a client."""
-        totals = tracer.totals(depth=1)
-        self.metrics.observe_rounds(totals)
-        # kernel spans carry flops attrs (prover.py): fold them into
-        # live per-stage throughput gauges, and MFU gauges where the
-        # backend's chip has a published peak (the host oracle and the
-        # fleet backend name no device, so they publish none)
+        or handed to a client. Each step is a top-level span of the
+        job's tracer (`serialize`, `self_verify`, `journal_done`,
+        `trace_store`), and the totals are taken after the last of them,
+        so STATUS `phases` accounts for the whole of `run_s`."""
+        tracer.waits = None     # the job's own spans follow one another
+        with tracer.span("serialize"):
+            proof_bytes = serialize_proof(proof)
+            pub = ckt.public_input()
+            if self.faults is not None and self.faults.on_proof(job.id):
+                # at=proof chaos plane: SDC between prove and serve —
+                # flip one byte so only the verify gate below can catch it
+                mid = len(proof_bytes) // 2
+                proof_bytes = (proof_bytes[:mid]
+                               + bytes([proof_bytes[mid] ^ 0xFF])
+                               + proof_bytes[mid + 1:])
+        if self._should_self_verify(job, backend):
+            with tracer.span("self_verify"):
+                self._self_verify(job, res, pub, proof_bytes, tracer)
+        with tracer.span("journal_done"):
+            self._journal_done(job, proof_bytes, pub)
+        with tracer.span("trace_store"):
+            self._store_trace(job, tracer)
+        # the stored artifact cannot hold the span that stored it; the
+        # timeline kept on the Job (/trace/<job_id>) can
+        job.trace_dump["events"].append(dict(
+            tracer.events[-1], proc=tracer.proc, host=tracer.host,
+            pid=tracer.pid))
+        phases = tracer.phases()
+        rounds = {k: v for k, v in tracer.totals(depth=1).items()
+                  if k not in PHASES}
+        # what the spans do not cover is reported, never hidden
+        phases["unaccounted"] = (time.monotonic() - job.started_at
+                                 - sum(phases.values())
+                                 - sum(rounds.values()))
+        self.metrics.observe_rounds(rounds, phases)
+        # device/round<N> and kernels/<name> events carry flops attrs
+        # against device-true time, sync backends' kernel spans against
+        # the compute they time (prover.py): fold them into live
+        # per-stage throughput gauges, and MFU gauges where the backend's
+        # chip has a published peak (the host oracle and the fleet
+        # backend name no device, so they publish none)
         self.metrics.observe_kernels(
             tracer.events,
             device_kind=backend.device_info()["device_kind"])
-        proof_bytes = serialize_proof(proof)
-        pub = ckt.public_input()
-        if self.faults is not None and self.faults.on_proof(job.id):
-            # at=proof chaos plane: SDC between prove and serve — flip
-            # one byte so only the verify gate below can catch it
-            mid = len(proof_bytes) // 2
-            proof_bytes = (proof_bytes[:mid]
-                           + bytes([proof_bytes[mid] ^ 0xFF])
-                           + proof_bytes[mid + 1:])
-        if self._should_self_verify(job, backend):
-            self._self_verify(job, res, pub, proof_bytes, tracer)
-        self._journal_done(job, proof_bytes, pub)
-        self._store_trace(job, tracer)
-        job.finish_ok(proof_bytes, pub, totals)
+        job.finish_ok(proof_bytes, pub, rounds, phases=phases,
+                      device=tracer.family("device"))
         # per-kind served counter: the circuit-zoo mix as the server saw
         # it (aggregation eligibility and console's by-kind pane both
         # read job state; this is the cheap cumulative view)
@@ -927,8 +982,10 @@ class WorkerPool:
             worker.deadline = job.started_at + self.job_timeout_s
         try:
             tracer = self._job_tracer(worker, job)
-            ckt = J.build_circuit(job.spec)
-            guard = self._make_guard(job, worker)
+            with tracer.span("circuit_build"):
+                ckt = J.build_circuit(job.spec)
+            with tracer.span("guard_open"):
+                guard = self._make_guard(job, worker)
             try:
                 proof = prove(random.Random(job.spec.seed), ckt, res.pk,
                               backend, tracer=tracer, checkpoint=guard)

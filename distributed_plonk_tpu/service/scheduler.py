@@ -278,6 +278,7 @@ class Scheduler:
             # (key build OOM on an extreme-but-valid spec, backend error)
             # would kill scheduling forever while SUBMIT keeps accepting —
             # fail the batch loudly and keep serving instead
+            w0, p0 = time.time(), time.perf_counter()
             try:
                 res = self.buckets.get(batch[0].spec)
             except Exception as e:
@@ -288,7 +289,9 @@ class Scheduler:
             batch_id = "batch-%05d" % next(_batch_seq)
             self.metrics.inc("batches_dispatched")
             self.metrics.observe("batch_size", len(batch))
+            lookup = (w0, time.perf_counter() - p0)
             for job in batch:
+                job.key_lookup = lookup
                 job.scheduled_at = time.monotonic()
                 job.batch_id = batch_id
                 job.batch_size = len(batch)

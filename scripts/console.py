@@ -39,7 +39,9 @@ def _get_text(base, path, timeout=5):
 
 def _pipeline_pane(base):
     """Round-pipeline fill pane, parsed off the Prometheus exposition
-    (/metrics is the only surface that carries the dpt_pipeline* family).
+    (/metrics is the only surface that carries the dpt_pipeline* family),
+    with the device ledger's account of what the chip waited for beside
+    it (dpt_device_unfed_s*; absent on a backend with no ledger).
     A daemon that never ran a pipelined attempt — or DPT_PIPELINE=0 —
     renders as one quiet '(off)' line."""
     try:
@@ -48,7 +50,8 @@ def _pipeline_pane(base):
         return ["pipeline (off)"]
     vals = {}
     for line in text.splitlines():
-        if not line.startswith(("dpt_pipeline", "dpt_pipelined")):
+        if not line.startswith(("dpt_pipeline", "dpt_pipelined",
+                                "dpt_device_unfed_s", "dpt_phase_clock_s")):
             continue
         name, _, raw = line.partition(" ")
         try:
@@ -57,10 +60,18 @@ def _pipeline_pane(base):
             pass
     if not vals.get("dpt_pipelined_proves_total"):
         return ["pipeline (off)"]
-    idle = ", ".join(
-        "r%s=%.3gs" % (k.rsplit("round", 1)[-1], v)
-        for k, v in sorted(vals.items())
-        if k.startswith("dpt_pipeline_device_idle_s_round"))
+    clock = vals.get("dpt_phase_clock_s_total")
+    unfed = "-"
+    if clock:
+        by_phase = sorted(
+            ((v, k[len("dpt_device_unfed_s_"):-len("_total")])
+             for k, v in vals.items()
+             if k.startswith("dpt_device_unfed_s_") and v > 0
+             and k != "dpt_device_unfed_s_total"),
+            reverse=True)
+        unfed = "%.1f%% of %.0fs: %s" % (
+            100.0 * vals.get("dpt_device_unfed_s_total", 0.0) / clock, clock,
+            ", ".join("%s=%.3gs" % (name, v) for v, name in by_phase[:4]))
     return [
         "pipeline proves=%d jobs=%d depth=%g "
         "depth_p50=%g stage_wait_p95=%.3gs" % (
@@ -71,7 +82,7 @@ def _pipeline_pane(base):
                      '{quantile="0.5"}', 0),
             vals.get('dpt_pipeline_stage_wait_s_seconds'
                      '{quantile="0.95"}', 0)),
-        "  device_idle(%s)" % (idle or "-")]
+        "  device_unfed(%s)" % unfed]
 
 
 def _fmt_member(m):

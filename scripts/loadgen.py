@@ -65,14 +65,14 @@ def _job_mix(args):
 def _pipeline_summary(m):
     """Round-pipeline section for a soak summary: how full the pipeline
     actually ran (achieved-depth histogram), where members stalled
-    (per-round stage-wait breakdown), and the per-round device-idle
-    estimate. `{"enabled": False}` when nothing pipelined (DPT_PIPELINE=0
+    (per-round stage-wait breakdown), and the device ledger's account
+    of the seconds the chip had nothing to run, by the phase of the
+    worker that left it so. `{"enabled": False}` when nothing pipelined (DPT_PIPELINE=0
     or all traffic went down the single/batch/mesh paths)."""
     sc = m.get("counters") or {}
     if not sc.get("pipelined_proves"):
         return {"enabled": False}
     hg = m.get("histograms") or {}
-    gg = m.get("gauges") or {}
     depth = hg.get("pipeline_depth_achieved") or {}
     return {
         "enabled": True,
@@ -88,10 +88,10 @@ def _pipeline_summary(m):
             for name, h in sorted(hg.items())
             if name.startswith("pipeline_stage_wait_s/")
             and h.get("count")},
-        "device_idle_s": {
-            name.rsplit("/", 1)[-1]: v
-            for name, v in sorted(gg.items())
-            if name.startswith("pipeline_device_idle_s/")},
+        "device_unfed_s": {
+            name.partition("/")[2] or "total": round(v, 3)
+            for name, v in sorted(sc.items())
+            if name.startswith("device_unfed_s") and v},
     }
 
 

@@ -64,6 +64,35 @@ def pytest_collection_modifyitems(items):
     items.sort(key=lambda it: it.module.__name__ not in _HOST_TIER)
 
 
+def free_port_block(n, port_base):
+    """First port of `n` consecutive loopback ports that are free right
+    now. The search starts where the fleet tests always took their block
+    (`port_base` plus a pid-derived offset, so concurrent test processes
+    start apart) but PROBES it: those bases lie inside the ephemeral range,
+    where any client socket of the machine may be sitting on one, and a
+    worker that cannot listen loses every test of its module. A block with
+    a taken port is skipped for the next."""
+    import socket
+    base = port_base + (os.getpid() % 400) * (n + 1)
+    for _ in range(200):
+        socks = []
+        try:
+            for port in range(base, base + n):
+                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+                socks.append(s)
+                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+                s.bind(("127.0.0.1", port))
+            return base
+        except OSError:
+            base += n + 1
+            if base + n >= 65535:
+                base = port_base
+        finally:
+            for s in socks:
+                s.close()
+    raise RuntimeError(f"no block of {n} free ports from {port_base}")
+
+
 def build_test_circuit():
     """Small circuit exercising every selector type."""
     from distributed_plonk_tpu.circuit import PlonkCircuit

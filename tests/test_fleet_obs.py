@@ -32,6 +32,7 @@ import urllib.request
 import pytest
 
 from distributed_plonk_tpu.obs import fleet as OF
+from conftest import free_port_block
 from distributed_plonk_tpu.obs import log as olog
 from distributed_plonk_tpu.runtime import native, protocol
 from distributed_plonk_tpu.runtime.dispatcher import (Dispatcher,
@@ -46,7 +47,7 @@ RNG = random.Random(0x0B515)
 
 
 def _spawn_workers(tmp_path, n, port_base):
-    base = port_base + (os.getpid() % 400) * (n + 1)
+    base = free_port_block(n, port_base)
     cfg = NetworkConfig([f"127.0.0.1:{base + i}" for i in range(n)])
     cfg_path = str(tmp_path / "network.json")
     cfg.save(cfg_path)
@@ -65,6 +66,27 @@ def _spawn_workers(tmp_path, n, port_base):
             time.sleep(0.2)
     assert not pending, f"workers {sorted(pending)} did not come up"
     return cfg, procs
+
+
+def test_free_port_block_skips_a_block_with_a_taken_port():
+    """The fleet tests' ports lie in the ephemeral range: a block is
+    probed before the workers are told to listen on it, and one with a
+    port somebody holds is passed over (one tier-1 run of PR 25 lost seven
+    tests to 'cannot listen on 127.0.0.1:34556')."""
+    import socket
+    first = free_port_block(3, 36100)
+    held = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+    try:
+        held.bind(("127.0.0.1", first + 1))
+        held.listen(1)
+        second = free_port_block(3, 36100)
+        assert second > first + 1       # the whole block moved on
+        for port in range(second, second + 3):
+            probe = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+            probe.bind(("127.0.0.1", port))
+            probe.close()
+    finally:
+        held.close()
 
 
 def _kill_all(procs):

@@ -24,6 +24,7 @@ import time
 
 import pytest
 
+from conftest import free_port_block
 from distributed_plonk_tpu import curve as C
 from distributed_plonk_tpu import poly as P
 from distributed_plonk_tpu.constants import R_MOD
@@ -150,7 +151,7 @@ class EnvFleet:
 
     def __init__(self, tmp_path, n, port_base, envs=None):
         self.n = n
-        base = port_base + (os.getpid() % 400) * (n + 1)
+        base = free_port_block(n, port_base)
         self.cfg = NetworkConfig(
             [f"127.0.0.1:{base + i}" for i in range(n)])
         self.cfg_path = str(tmp_path / "network.json")

@@ -24,6 +24,7 @@ import urllib.request
 
 import pytest
 
+from conftest import free_port_block
 from distributed_plonk_tpu.runtime import protocol
 from distributed_plonk_tpu.runtime.dispatcher import (Dispatcher,
                                                       RemoteBackend,
@@ -56,7 +57,7 @@ def _assert_chrome_schema(ct):
 
 
 def _spawn_workers(tmp_path, n, port_base, trace_cap=None):
-    base = port_base + (os.getpid() % 400) * (n + 1)
+    base = free_port_block(n, port_base)
     cfg = NetworkConfig([f"127.0.0.1:{base + i}" for i in range(n)])
     cfg_path = str(tmp_path / "network.json")
     cfg.save(cfg_path)

@@ -17,6 +17,7 @@ import time
 
 import pytest
 
+from conftest import free_port_block
 from distributed_plonk_tpu import curve as C
 from distributed_plonk_tpu.constants import R_MOD
 from distributed_plonk_tpu.runtime import protocol
@@ -79,7 +80,7 @@ class Fleet:
     def __init__(self, tmp_path, n, port_base, backend="python"):
         self.n = n
         self.backend = backend
-        base = port_base + (os.getpid() % 400) * (n + 1)
+        base = free_port_block(n, port_base)
         self.cfg = NetworkConfig(
             [f"127.0.0.1:{base + i}" for i in range(n)])
         self.cfg_path = str(tmp_path / "network.json")
