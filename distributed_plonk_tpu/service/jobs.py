@@ -41,6 +41,7 @@ from ..circuit import PlonkCircuit
 from ..constants import R_MOD
 from ..trace import new_trace_id
 from .. import circuits
+from ..circuits import merkle_witness
 
 # same deterministic toxic-waste tau as tests/conftest.py's fixture SRS:
 # server and clients derive identical keys from a spec alone
@@ -198,8 +199,10 @@ def _toy_circuit(gates, seed):
     return ckt
 
 
-def build_circuit(spec):
-    """Spec -> finalized, satisfied circuit (deterministic in the spec)."""
+def build_circuit(spec, metrics=None):
+    """Spec -> finalized, satisfied circuit (deterministic in the spec).
+    A `merkle` spec builds its witness only, over the structure kept per
+    shape (circuits/merkle_witness.py), and counts the build on `metrics`."""
     if spec.kind == "toy":
         ckt = _toy_circuit(spec.params["gates"], spec.seed)
         ok, bad = ckt.check_satisfiability()
@@ -207,12 +210,7 @@ def build_circuit(spec):
         return ckt.finalize()
     if spec.kind in circuits.REGISTRY:
         return circuits.build(spec.kind, spec.params, spec.seed)
-    from ..workload import generate_circuit
-    ckt, _tree = generate_circuit(
-        rng=random.Random(spec.seed), height=spec.params["height"],
-        num_proofs=spec.params["num_proofs"],
-        num_leaves=spec.params["num_leaves"])
-    return ckt
+    return merkle_witness.build(spec.params, spec.seed, metrics=metrics)
 
 
 def build_bucket_keys(spec, backend=None):

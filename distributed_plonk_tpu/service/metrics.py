@@ -48,6 +48,22 @@ Job lifecycle (service/server.py, service/pool.py, service/queue.py):
                                                      every span above and the
                                                      rounds)
     queue_depth / queue_high_water (gauges)          admission backlog
+    circuit_builds                                   `merkle` circuits built
+                                                     for a job by a pool
+                                                     worker (circuits/
+                                                     merkle_witness.py)
+    circuit_template_hits                            of those, builds that
+                                                     found their shape's
+                                                     structure template (a
+                                                     miss runs the plain
+                                                     builder once and keeps
+                                                     its structure)
+    circuit_build_permutations                       Rescue permutations the
+                                                     witness-only builds
+                                                     computed: one per
+                                                     distinct tree node of a
+                                                     job (the plain build of
+                                                     a miss is not in it)
 
 Scheduler + shape buckets (service/scheduler.py):
     batches_dispatched / batch_size                  shape-batch activity

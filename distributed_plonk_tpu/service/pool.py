@@ -669,7 +669,7 @@ class WorkerPool:
         ckts, guards = [], []
         for job, tracer in zip(live, tracers):
             with tracer.span("circuit_build"):
-                ckts.append(J.build_circuit(job.spec))
+                ckts.append(J.build_circuit(job.spec, self.metrics))
         for job, tracer in zip(live, tracers):
             with tracer.span("guard_open"):
                 guards.append(self._make_guard(job, worker))
@@ -983,7 +983,7 @@ class WorkerPool:
         try:
             tracer = self._job_tracer(worker, job)
             with tracer.span("circuit_build"):
-                ckt = J.build_circuit(job.spec)
+                ckt = J.build_circuit(job.spec, self.metrics)
             with tracer.span("guard_open"):
                 guard = self._make_guard(job, worker)
             try:
