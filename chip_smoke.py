@@ -149,11 +149,17 @@ def kernel_paths(domain_size):
     }
 
 
-def check_mesh_spread(mesh_backends, chips):
+def check_mesh_spread(mesh_backends, chips, counters):
     """The mesh leg's proof that work was on every chip: the commit key a
-    MeshBackend keeps resident is sharded over all of them, and every
-    device has held live buffers."""
+    MeshBackend keeps resident is sharded over all of them, every device
+    has held live buffers, and no NTT of a mesh-placed job fell back to
+    the replicated kernel (the service's `mesh_ntt_*` counters)."""
     import jax
+    calls = counters.get("mesh_ntt_calls", 0)
+    sharded = counters.get("mesh_ntt_sharded", 0)
+    if not calls or sharded != calls:
+        raise RuntimeError(f"{sharded} of {calls} NTTs of the mesh-placed "
+                           "jobs took the sharded plan, want all")
     if not mesh_backends:
         raise RuntimeError("no job was placed on a mesh backend")
     spread = []
@@ -169,7 +175,8 @@ def check_mesh_spread(mesh_backends, chips):
         peaks[str(d.id)] = stats.get("peak_bytes_in_use")
         if not peaks[str(d.id)]:
             raise RuntimeError(f"device {d.id} never held a buffer")
-    return {"commit_key_devices": spread, "peak_bytes_in_use": peaks}
+    return {"commit_key_devices": spread, "peak_bytes_in_use": peaks,
+            "mesh_ntt_sharded": sharded, "mesh_ntt_calls": calls}
 
 
 def main():
@@ -235,9 +242,10 @@ def main():
     placed = sorted({job["placement"] for job in jobs})
     if placed != [want]:
         raise RuntimeError(f"jobs were placed {placed}, want all {want}")
-    if args.chips > 1:
-        say(phase="mesh", **check_mesh_spread(mesh_backends, args.chips))
     counters = metrics["counters"]
+    if args.chips > 1:
+        say(phase="mesh", **check_mesh_spread(mesh_backends, args.chips,
+                                              counters))
     say(phase="done",
         jobs_completed=counters.get("jobs_completed"),
         job_attempt_errors=counters.get("job_attempt_errors", 0),

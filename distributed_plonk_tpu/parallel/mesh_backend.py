@@ -29,12 +29,38 @@ SHARDED on a jax.sharding.Mesh for all 5 rounds:
 Domains too small to 2D-shard across the mesh (r or c not divisible by
 the device count) fall back to the replicated single-device kernels on
 the same mesh devices — correctness is placement-independent, and the
-tiny-domain case is exactly where sharding has nothing to win.
+tiny-domain case is exactly where sharding has nothing to win. Which path
+an NTT took is counted (`mesh_ntt_calls`, `mesh_ntt_sharded`), so a
+deployment whose NTTs fell back shows as one.
 
 prove(rng, ckt, pk, MeshBackend(mesh)) produces byte-identical proofs to
 the host oracle and the single-device backend (asserted in
-tests/test_mesh_backend_prove.py), matching the reference's invariant
-that the distributed result equals the single-node one (SURVEY.md §4).
+tests/test_mesh_backend_prove.py and, served, in
+tests/test_mesh_served.py), matching the reference's invariant that the
+distributed result equals the single-node one (SURVEY.md §4).
+
+What a mesh prove records. It is SYNCHRONOUS: no async hook, no round
+pipeline, no streamed round 3, one job alone on its lease. It is seen by
+the tracing every prove is seen by (trace.Tracer, trace.DeviceLedger,
+service Metrics), nothing of its own:
+  - a round opens on `device_ledger` at `prover._feed` and closes when
+    its commitments or evaluations are back on the host
+    (`prover._fetched`), so a mesh job has `device/round<N>` events and
+    STATUS `device` like any other. The charge is the whole mesh's wall
+    time for the round, host moments inside it included;
+  - a backend made alone keeps a ledger of its own. A service has ONE
+    fed/unfed account: the pool worker that runs a leased unit hands the
+    backend the ledger it reports its own phases to, and the service's
+    Metrics, through `attach` (service/pool.py::_run_item), so the mesh's
+    rounds feed the same `device_unfed_s` / `phase_clock_s` and no second
+    is charged twice;
+  - its programs go through field_jax.named_jit as `mesh_ntt_<mode>`,
+    `mesh_msm_digits` / `_chunk` / `_merge` / `_finish` and
+    `mesh_domain_tables`, so a device trace tells the all-to-all program
+    from the all-gather program and both from the inherited round math;
+  - counters, in the attached Metrics (none without one):
+    `mesh_ntt_calls`, `mesh_ntt_sharded`, `mesh_all_to_all_bytes`,
+    `mesh_msm_chunks`, `mesh_all_gather_bytes`.
 """
 
 import functools
@@ -83,16 +109,30 @@ class MeshBackend(JaxBackend):
     # this knob only gates GSPMD propagation through the round math.
     _MIN_LOCAL = int(os.environ.get("DPT_MESH_MIN_LOCAL", "1024"))
 
-    def _make_ledger(self):
-        # the ledger's charge rule reads ONE device's queue; a mesh prove
-        # is sync here (no async hooks above), its spans time the compute
-        return None
-
     def __init__(self, mesh):
+        # the inherited `device_ledger` is this backend's own until a
+        # service attaches its account: the mesh is then THE device of
+        # that account, fed while one of its (sync) rounds is open
         super().__init__()
         self.mesh = mesh
         self.d = mesh.devices.size
         self._mesh_plans = {}
+        self.metrics = None
+
+    def attach(self, ledger, metrics):
+        """Report to the service this backend is leased to: the mesh
+        counters count in its `metrics` and rounds open on its `ledger`
+        (trace.DeviceLedger; one fed/unfed account per service). A pool
+        whose own backend has no ledger passes None, and this backend
+        keeps its own."""
+        self.metrics = metrics
+        if ledger is not None and ledger is not self.device_ledger:
+            self.device_ledger.close_threads()   # its own: beacon, watcher
+            self.device_ledger = ledger
+
+    def _count(self, name, by=1):
+        if self.metrics is not None:
+            self.metrics.inc(name, by)
 
     # --- placement hooks ----------------------------------------------------
 
@@ -123,8 +163,20 @@ class MeshBackend(JaxBackend):
                                    else None)
         return self._mesh_plans[n]
 
+    def _count_ntts(self, plan, polys):
+        """`polys` NTTs are about to run: all on the 4-step plan, or all
+        on the replicated fallback where the domain has none."""
+        self._count("mesh_ntt_calls", polys)
+        if plan is not None:
+            self._count("mesh_ntt_sharded", polys)
+            # the one all_to_all: each chip keeps 1/d of its n/d elements
+            # (16 uint32 limbs, 64 bytes) and sends the rest
+            self._count("mesh_all_to_all_bytes",
+                        polys * 64 * plan.n * (self.d - 1) // self.d)
+
     def _kernel(self, domain, h, inverse, coset):
         plan = self._plan(domain.size)
+        self._count_ntts(plan, 1)
         if plan is None:
             return super()._kernel(domain, h, inverse, coset)
         if h.shape[1] < domain.size:
@@ -134,6 +186,7 @@ class MeshBackend(JaxBackend):
 
     def _kernel_many(self, domain, hs, inverse, coset):
         plan = self._plan(domain.size)
+        self._count_ntts(plan, len(hs))
         if plan is None:
             return super()._kernel_many(domain, hs, inverse, coset)
         # one 4-step mesh program per poly: at mesh-worthy sizes the
@@ -150,7 +203,7 @@ class MeshBackend(JaxBackend):
     # --- MSM: range-sharded signed Pippenger --------------------------------
 
     def _make_msm_ctx(self, bases):
-        return MeshMsmContext(self.mesh, bases)
+        return MeshMsmContext(self.mesh, bases, count=self._count)
 
     # --- quotient tables pinned to the mesh ---------------------------------
 
@@ -164,9 +217,10 @@ class MeshBackend(JaxBackend):
             hit = self._domain_tabs.get(key)
         if hit is None:
             sh = self._sharding1(m)
-            fn = jax.jit(PJ.domain_tables, static_argnums=(0, 1, 2, 3),
-                         out_shardings={"ep": sh, "zh_inv": sh,
-                                        "shifted_inv": sh})
+            fn = FJ.named_jit("mesh_domain_tables", PJ.domain_tables,
+                              static_argnums=(0, 1, 2, 3),
+                              out_shardings={"ep": sh, "zh_inv": sh,
+                                             "shifted_inv": sh})
             hit = fn(m, n, FR_GENERATOR, group_gen)
             with self._cache_lock:
                 self._domain_tabs[key] = hit

@@ -63,7 +63,10 @@ class MeshMsmContext:
     # same knob semantics as MsmContext's chunking
     _CALL_ADDS = int(os.environ.get("DPT_MSM_CALL_ADDS", "8000000"))
 
-    def __init__(self, mesh, bases):
+    def __init__(self, mesh, bases, count=None):
+        # count(name, by): where `mesh_msm_chunks` and
+        # `mesh_all_gather_bytes` go (MeshBackend._count), if anywhere
+        self._count = count or (lambda name, by=1: None)
         self.mesh = mesh
         self.d = d = mesh.devices.size
         n = len(bases)
@@ -115,7 +118,9 @@ class MeshMsmContext:
             with FJ.pallas_disabled():
                 return CJ.proj_add(tuple(a), tuple(b))
 
-        self._merge_fn = jax.jit(_merge)
+        # program names (field_jax.named_jit): mesh_msm_digits, _chunk (the
+        # shard_map'd scan with the all_gather + fold), _merge, _finish
+        self._merge_fn = FJ.named_jit("mesh_msm_merge", _merge)
 
     # --- digit extraction ----------------------------------------------------
 
@@ -149,7 +154,8 @@ class MeshMsmContext:
                         outs.append(dg.reshape(W, d, loc))
                     return jnp.stack(outs)
 
-            fn = jax.jit(build, out_shardings=self._digits_sh)
+            fn = FJ.named_jit("mesh_msm_digits", build,
+                              out_shardings=self._digits_sh)
             self._digits_fns[key] = fn
         return fn(list(hs))
 
@@ -184,11 +190,13 @@ class MeshMsmContext:
             # check_vma=False: the all_gather+fold makes the outputs
             # replicated in value, which the varying-axes checker cannot
             # infer statically
-            self._chunk_fns[key] = jax.jit(jax.shard_map(
-                body, mesh=self.mesh,
-                in_specs=(P(None, SHARD_AXIS, None), P(None, SHARD_AXIS, None),
-                          P(SHARD_AXIS, None), P(None, None, SHARD_AXIS, None)),
-                out_specs=(P(None, None, None),) * 3, check_vma=False))
+            self._chunk_fns[key] = FJ.named_jit(
+                "mesh_msm_chunk", jax.shard_map(
+                    body, mesh=self.mesh,
+                    in_specs=(P(None, SHARD_AXIS, None),
+                              P(None, SHARD_AXIS, None), P(SHARD_AXIS, None),
+                              P(None, None, SHARD_AXIS, None)),
+                    out_specs=(P(None, None, None),) * 3, check_vma=False))
         return self._chunk_fns[key]
 
     def _finish_fn(self, batch):
@@ -197,7 +205,8 @@ class MeshMsmContext:
                 with pallas_guard(self.mesh):
                     return finish_batch(ax, ay, az, batch=batch,
                                         signed=self.signed)
-            self._finish_fns[batch] = jax.jit(_finish)
+            self._finish_fns[batch] = FJ.named_jit("mesh_msm_finish",
+                                                   _finish)
         return self._finish_fns[batch]
 
     def _exec(self, digits):
@@ -214,6 +223,11 @@ class MeshMsmContext:
             fn = self._chunk_fn(jc, g, B)
             part = fn(ax[:, :, j0:j0 + jc], ay[:, :, j0:j0 + jc],
                       ainf[:, j0:j0 + jc], digits[:, :, :, j0:j0 + jc])
+            self._count("mesh_msm_chunks")
+            # the all_gather: each of d chips takes the other d-1 chips'
+            # bucket planes, which have the shape of the folded `part`
+            self._count("mesh_all_gather_bytes",
+                        self.d * (self.d - 1) * sum(p.nbytes for p in part))
             if acc is None:
                 acc = part
             else:

@@ -184,7 +184,6 @@ class MeshNttPlan:
 
         lane_sh = jax.sharding.NamedSharding(self.mesh, P(None, SHARD_AXIS))
 
-        @jax.jit
         def fn(x, cs):
             # pallas only if the MESH devices are TPUs (a cpu mesh can be
             # traced in a tpu-default process — mesh.pallas_guard); the
@@ -213,6 +212,12 @@ class MeshNttPlan:
                         x = FJ.from_mont(FR, x)
                 return x
 
+        # named after the mode (mesh_ntt_inv_coset, mesh_ntt_fwd_plain,
+        # ...), as ntt_jax names its fused programs: a device trace tells
+        # the all-to-all program from the MSM's all-gather program
+        name = "_".join(["mesh_ntt", "inv" if inverse else "fwd"]
+                        + ["coset"] * coset + ["plain"] * plain)
+        fn = FJ.named_jit(name, fn)
         self._fns[key] = (fn, consts)
         return lambda v: fn(v, consts)
 
@@ -226,5 +231,6 @@ class MeshNttPlan:
             # it to a replicated layout (DCN all-gather) so every process
             # can read the full vector
             rep = jax.sharding.NamedSharding(self.mesh, P(None, None))
-            out = jax.jit(lambda x: x, out_shardings=rep)(out)
+            out = FJ.named_jit("mesh_ntt_gather", lambda x: x,
+                               out_shardings=rep)(out)
         return limbs_to_ints(np.asarray(out))

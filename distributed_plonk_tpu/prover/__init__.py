@@ -178,10 +178,11 @@ class _ProveCtx:
         self.stream_poly = getattr(backend, "quotient_poly_streamed", None)
         self.commit_async = getattr(backend, "commit_many_async", None)
         self.eval_async = getattr(backend, "eval_many_async", None)
-        # the device's completion-stamp ledger (trace.DeviceLedger), on
-        # backends that dispatch asynchronously to one device
-        self.ledger = (getattr(backend, "device_ledger", None)
-                       if self.commit_async is not None else None)
+        # the device's completion-stamp ledger (trace.DeviceLedger) of a
+        # backend that has one: an async backend's rounds are stamped by
+        # the ledger's watcher, a sync one's (the mesh) when the commit or
+        # the evaluations are back on the host (`_fetched`)
+        self.ledger = getattr(backend, "device_ledger", None)
 
     def round_work(self, no):
         """Model (flops, data_bytes) of what round `no` puts on the
@@ -250,6 +251,20 @@ def _watched(cx, mb, dev):
     return no, rnd, cx.round_work(no)
 
 
+def _fetched(cx, mb):
+    """A sync backend has the round's last result on the host: close the
+    round on the ledger at once and record its `device/round<N>` event,
+    as `_KernelPending.force` does for a watched one."""
+    if mb.dev is None:
+        return
+    no, rnd = mb.dev
+    mb.dev = None
+    cx.ledger.close(rnd)
+    flops, data_bytes = cx.round_work(no)
+    mb.tr.add_event("device/round%d" % no, ts=mb.tr.wall(rnd.start),
+                    dur_s=rnd.charge, flops=flops, data_bytes=data_bytes)
+
+
 def _kspan(cx, mb, name, **attrs):
     """A kernel span. On a backend with async dispatch the span times the
     enqueue, so it carries no flops/bytes attribution (the round's
@@ -305,7 +320,9 @@ def _dispatch_commit(cx, mb, hs, name, span_attrs):
         return _KernelPending(dev.force, mb.tr, name,
                               device_round=_watched(cx, mb, dev), **attrs)
     with mb.tr.span(name, **span_attrs):
-        return _Ready(cx.backend.commit_many_h(cx.ck, hs))
+        comms = cx.backend.commit_many_h(cx.ck, hs)
+    _fetched(cx, mb)
+    return _Ready(comms)
 
 
 def _dispatch_evals(cx, mb, pairs):
@@ -314,7 +331,9 @@ def _dispatch_evals(cx, mb, pairs):
         dev = cx.eval_async(pairs)
         return _KernelPending(dev.force, mb.tr, "eval_many",
                               device_round=_watched(cx, mb, dev))
-    return _Ready(cx.backend.eval_many_h(pairs))
+    evals = cx.backend.eval_many_h(pairs)
+    _fetched(cx, mb)
+    return _Ready(evals)
 
 
 # -- the five round stages ----------------------------------------------------

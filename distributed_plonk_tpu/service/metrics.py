@@ -92,6 +92,18 @@ Placement + cross-job batched proving (service/placement.py, pool.py):
                                                      opportunistic batch
                                                      leases)
     submesh_devices_free (gauge)                     unleased devices
+    mesh_ntt_calls / mesh_ntt_sharded                NTTs of mesh-placed
+                                                     jobs, and those that
+                                                     took the 4-step plan
+                                                     (the rest fell back to
+                                                     the replicated kernel)
+    mesh_msm_chunks                                  shard-mapped MSM chunk
+                                                     launches
+    mesh_all_to_all_bytes / mesh_all_gather_bytes    bytes the NTT's
+                                                     all_to_all and the
+                                                     MSM's all_gather move
+                                                     between chips, by the
+                                                     shapes of the calls
     bucket_hits / bucket_misses / bucket_disk_hits   key-cache tiers
     bucket_peer_hits                                 keys fetched from a
                                                      warm STORE_FETCH peer
@@ -123,7 +135,9 @@ Round-pipelined proving (prover.PipelinedProver via pool._run_pipeline):
 Device ledger, the fed/unfed account of the chip (trace.DeviceLedger, owned
 by the backend, read at every snapshot through Metrics.add_source and
 charged up to that instant; float seconds, cumulative since the backend was
-made; absent on a backend with no ledger, the host oracle and the mesh):
+made; absent on a backend with no ledger, the host oracle. A leased mesh
+backend opens its rounds on the pool backend's ledger, parallel/
+mesh_backend.py::MeshBackend.attach, so there is one account a service):
     phase_clock_s                            all time on the ledger's clock
     device_unfed_s                           seconds in which no round of
                                              ours was outstanding on the

@@ -469,7 +469,13 @@ class WorkerPool:
         if isinstance(item, _Group) and item.backend is not None:
             # leased-submesh sharded prove: the historical non-pipelined
             # paths on the override backend — the lease is per-unit, so
-            # these units never coalesce with queue neighbors
+            # these units never coalesce with queue neighbors. ONE fed/unfed
+            # account per service: the leased backend opens its rounds on
+            # the ledger this worker reports its idle/busy and its jobs'
+            # phases to, and counts in the service's metrics
+            attach = getattr(item.backend, "attach", None)
+            if attach is not None:
+                attach(worker.ledger, self.metrics)
             try:
                 if len(item.jobs) == 1:
                     return self._run_one(worker, item.backend,

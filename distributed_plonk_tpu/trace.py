@@ -29,7 +29,8 @@ the same Tracer, and none of them is a second tracing system:
   of a pipelined or batched prove that is in none of its own spans is in
   `pipeline_wait`: `Tracer.park()` / `unpark()` record it from explicit
   stamps, never as a remainder.
-- `DeviceLedger`: one per device, owned by the backend. It stamps when a
+- `DeviceLedger`: one per device, owned by the backend (a service has one:
+  a leased mesh backend is handed the pool backend's). It stamps when a
   round's device work was first dispatched and — from a watcher thread
   that blocks on the round's last device arrays, never on the worker's
   thread and never with a fence in the device queue — when it was done,

@@ -307,6 +307,67 @@ def test_a_launch_that_raises_leaves_no_round_open(proven):
     be.device_ledger.close_threads()
 
 
+# --- a sync backend (the mesh): rounds closed at the fetch --------------------
+
+def _sync_with_ledger(clock, ledger, fail_at=None):
+    """The host oracle with a device ledger and no async hook, which is
+    what MeshBackend is to the prover; every commit and every evaluation
+    moves the fake clock one second on, and call `fail_at` raises."""
+    from distributed_plonk_tpu.backend.python_backend import PythonBackend
+
+    class Sync(PythonBackend):
+        device_ledger = ledger
+        calls = 0
+
+        def _tick(self):
+            self.calls += 1
+            if self.calls == fail_at:
+                raise RuntimeError("commit failed")
+            clock.t += 1.0
+
+        def commit_many_h(self, ck, hs):
+            self._tick()
+            return super().commit_many_h(ck, hs)
+
+        def eval_many_h(self, pairs):
+            self._tick()
+            return super().eval_many_h(pairs)
+
+    return Sync()
+
+
+def test_a_sync_prove_closes_each_round_at_its_fetch(proven):
+    ckt, pk, _vk, want = proven
+    clock, led = _ledger()
+    tr = T.Tracer(ledger=led, worker=0)
+    got = prover.prove(random.Random(1), ckt, pk,
+                       _sync_with_ledger(clock, led), tracer=tr)
+    assert got.opening_proof == want.opening_proof
+    # five rounds, each charged the second its commit (or, in round 4, its
+    # evaluation) took on the ledger's clock, with the round's work model
+    assert tr.family("device") == {"round%d" % i: 1.0 for i in range(1, 6)}
+    events = {ev["span"]: ev for ev in tr.events}
+    assert events["device/round1"]["flops"] > 0
+    assert events["device/round4"]["flops"] == 0
+    # sync kernel spans time the compute and keep their attribution
+    assert events["round1/commit_wires"]["flops"] > 0
+    assert led._open == 0
+    # fed for exactly those five seconds: nothing else moved the clock
+    c = led.counters()
+    assert c["phase_clock_s"] == 5.0 and c["device_unfed_s"] == 0.0
+
+
+def test_a_sync_commit_that_raises_leaves_no_round_open(proven):
+    ckt, pk, _vk, _want = proven
+    clock, led = _ledger()
+    with pytest.raises(RuntimeError, match="commit failed"):
+        prover.prove(random.Random(1), ckt, pk,
+                     _sync_with_ledger(clock, led, fail_at=2),
+                     tracer=T.Tracer(ledger=led, worker=0))
+    assert led._open == 0
+    assert led.counters()["phase_clock_s"] == 1.0
+
+
 # --- the beacon ----------------------------------------------------------------
 
 def _names_in_session(seconds, around):

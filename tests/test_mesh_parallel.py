@@ -151,3 +151,50 @@ def test_mesh_msm_matches_oracle(mesh8):
     # short scalar vector (zero-padded on device)
     short = [RNG.randrange(R_MOD) for _ in range(40)]
     assert ctx.msm(short) == C.g1_msm(bases[:40], short)
+
+
+@pytest.mark.parametrize("inverse, coset, name", [
+    (False, False, "mesh_ntt_fwd_plain"),
+    (True, False, "mesh_ntt_inv_plain"),
+    (False, True, "mesh_ntt_fwd_coset_plain"),
+    (True, True, "mesh_ntt_inv_coset_plain"),
+])
+def test_mesh_ntt_programs_carry_their_mode_in_their_name(plan256, inverse,
+                                                          coset, name):
+    """field_jax.named_jit: a device trace reads `jit_mesh_ntt_<mode>`, not
+    four programs all called `jit_fn`."""
+    from distributed_plonk_tpu.backend import autotune
+    plan256.kernel(inverse, coset, boundary="plain")
+    fn, consts = plan256._fns[autotune.cache_key(inverse, coset, "plain", 4,
+                                                 "xla")]
+    assert fn.__name__ == name
+    x = jax.ShapeDtypeStruct((16, plan256.n), "uint32")
+    assert "jit_" + name in fn.lower(x, consts).as_text()[:200]
+    # the Montgomery boundary the prover runs has no suffix
+    plan256.kernel(inverse, coset, boundary="mont")
+    fn, _ = plan256._fns[autotune.cache_key(inverse, coset, "mont", 4, "xla")]
+    assert fn.__name__ == name[:-len("_plain")]
+
+
+def test_mesh_msm_programs_are_named_and_its_collective_is_counted(mesh8):
+    n = 64
+    bases = [C.g1_mul(C.G1_GEN, RNG.randrange(1, R_MOD)) for _ in range(n)]
+    counted = {}
+
+    def count(name, by=1):
+        counted[name] = counted.get(name, 0) + by
+
+    ctx = MeshMsmContext(mesh8, bases, count=count)
+    scalars = [RNG.randrange(R_MOD) for _ in range(n)]
+    assert ctx.msm(scalars) == C.g1_msm(bases, scalars)
+    assert ctx._merge_fn.__name__ == "mesh_msm_merge"
+    assert {fn.__name__ for fn in ctx._chunk_fns.values()} == {
+        "mesh_msm_chunk"}
+    assert {fn.__name__ for fn in ctx._finish_fns.values()} == {
+        "mesh_msm_finish"}
+    # one chunk covers a local slice of 16 points; its all_gather hands each
+    # of 8 chips the other 7 chips' three (24, windows * buckets) planes
+    assert counted["mesh_msm_chunks"] == 1
+    planes = counted["mesh_all_gather_bytes"] // (8 * 7)
+    assert planes * 8 * 7 == counted["mesh_all_gather_bytes"]
+    assert planes % (3 * 24 * 4 * ctx.windows) == 0
