@@ -3,7 +3,9 @@
 Replaces the role of `ark-ec`/`ark-bls12-381` in the reference
 (/root/reference/Cargo.toml:31-37, used at src/worker.rs:122 for MSM and in
 jf-plonk's verifier). The TPU G1 kernels are tested bit-identical against
-these ops; the pairing is only used host-side by the verifier.
+these ops; the pairing is only used host-side by the verifier. It is the
+optimal ate pairing in the curve's parameter (constants.BLS_X, negative:
+BLS_X_IS_NEG), up to a cube: see final_exponentiation.
 
 Point formats:
   G1 affine:   (x, y) ints, or None for the point at infinity.
@@ -14,24 +16,28 @@ Point formats:
 from .constants import (
     Q_MOD,
     R_MOD,
+    BLS_X,
+    BLS_X_IS_NEG,
     G1_GEN_X,
     G1_GEN_Y,
     G2_GEN_X,
     G2_GEN_Y,
 )
-from . import fields as F
 from .fields import (
     fq_inv,
     fq2_add,
     fq2_sub,
     fq2_mul,
     fq2_sq,
+    fq2_scalar,
     fq2_inv,
     fq2_neg,
     fq12_mul,
+    fq12_mul_sparse,
     fq12_sq,
     fq12_inv,
-    fq12_pow,
+    fq12_conj,
+    fq12_frobenius,
     FQ12_ONE,
 )
 
@@ -156,15 +162,22 @@ def g1_mul(p, k, reduce=True):
 
 
 def g1_msm(points, scalars):
-    """Reference variable-base MSM (Pippenger, window=8).
+    """Reference variable-base MSM (Pippenger).
 
     Oracle for the device MSM (reference behavior: src/worker.rs:159-185).
     Accepts affine points (None = infinity, as produced by the reference's
     zero-padding of the SRS at src/dispatcher2.rs:208).
+
+    The window follows the length: a window's bucket sums cost 2^(c+1)
+    additions whatever the length and filling the buckets one a point, so
+    the verifier's thirty points want c = 3 where a commit key wants 8.
+    From 256 points up it stays 8, as it always was: those are the key
+    builds and the oracle's commitments. The value does not depend on it.
     """
     assert len(points) == len(scalars)
     scalars = [s % R_MOD for s in scalars]
-    c = 8
+    n = len(points)
+    c = 8 if n >= 256 else max(1, n.bit_length() - 3)
     num_windows = (R_MOD.bit_length() + c - 1) // c
     window_sums = []
     for w in range(num_windows):
@@ -237,99 +250,92 @@ def g2_mul(p, k, reduce=True):
     return acc
 
 
-# --- Pairing (Tate, with denominators eliminated by the final exponentiation)
+# --- Pairing (optimal ate) ----------------------------------------------------
+#
+# e(P, Q) = f_{x,Q}(P)^((q^12 - 1)/r), P in G1, Q in G2, x the curve's
+# parameter (constants.BLS_X its absolute value, BLS_X_IS_NEG its sign): the
+# Miller loop runs over the 64 bits of |x|, not the 255 of r, its running
+# point stays on the twist E'/Fq2 (y^2 = x^3 + 4 xi, the M-twist: (x, y) ->
+# (x / w^2, y / w^3) lands on E(Fq12) since w^6 = xi), and each line comes
+# out with three Fq2 coefficients of twelve. The final exponentiation is
+# split by the structure of q^12 - 1 and its hard part runs in x.
 
-def _fq12_from_fq(a):
-    return (((a, 0), (0, 0), (0, 0)), ((0, 0), (0, 0), (0, 0)))
-
-
-def _fq12_scalar_fq(a, k):
-    """Multiply a generic Fq12 element by k in Fq."""
-    c0, c1 = a
-    return (
-        tuple((x[0] * k % Q_MOD, x[1] * k % Q_MOD) for x in c0),
-        tuple((x[0] * k % Q_MOD, x[1] * k % Q_MOD) for x in c1),
-    )
+_ATE_BITS = [int(b) for b in bin(BLS_X)[3:]]  # below the leading one
 
 
-def _fq12_sub(a, b):
-    return (F.fq6_sub(a[0], b[0]), F.fq6_sub(a[1], b[1]))
+def _line_step(t, q, neg_xp, yp):
+    """(the line through T and Q, the tangent where Q is T, at P; T + Q),
+    by g2_add's slopes. The line's value is taken times w^3, a factor in
+    Fq4 that the final exponentiation sends to 1:
+    (lam x_T - y_T) - lam x_P v + y_P v w, three coefficients of twelve."""
+    (x1, y1), (x2, y2) = t, q
+    if t == q:
+        lam = fq2_mul(fq2_scalar(fq2_sq(x1), 3), fq2_inv(fq2_add(y1, y1)))
+    else:
+        lam = fq2_mul(fq2_sub(y2, y1), fq2_inv(fq2_sub(x2, x1)))
+    x3 = fq2_sub(fq2_sub(fq2_sq(lam), x1), x2)
+    y3 = fq2_sub(fq2_mul(lam, fq2_sub(x1, x3)), y1)
+    line = (fq2_sub(fq2_mul(lam, x1), y1), fq2_scalar(lam, neg_xp), yp)
+    return line, (x3, y3)
 
 
-_W = (F.FQ6_ZERO, F.FQ6_ONE)  # w, with w^2 = v, w^6 = xi = u + 1
-_W2_INV = fq12_inv(fq12_sq(_W))
-_W3_INV = fq12_inv(fq12_mul(fq12_sq(_W), _W))
+def miller_loop(pairs):
+    """prod_i f_{x,Q_i}(P_i) over [(P_i, Q_i)], before the final
+    exponentiation: ONE accumulator, squared once a bit for all the pairs.
 
-
-def _untwist(q):
-    """Map a G2 point on the twist E'/Fq2 into E(Fq12).
-
-    BLS12-381 uses the M-twist y^2 = x^3 + 4(u+1); psi(x, y) =
-    (x * w^-2, y * w^-3) lands on y^2 = x^3 + 4 since w^6 = u + 1.
+    P_i is a G1 affine point, Q_i a G2 affine point of order r (a verifying
+    key's are; a point of small order ends in a vertical line, whose slope
+    raises ZeroDivisionError). A pair with a point at infinity
+    contributes 1.
     """
-    x, y = q
-    return (fq12_mul(_embed_fq2(x), _W2_INV), fq12_mul(_embed_fq2(y), _W3_INV))
-
-
-def _embed_fq2(a):
-    return ((a, F.FQ2_ZERO, F.FQ2_ZERO), F.FQ6_ZERO)
-
-
-FINAL_EXP = (Q_MOD ** 12 - 1) // R_MOD
-
-
-def miller_loop(p, q_untwisted):
-    """f_{r,P}(Q) with vertical lines dropped (killed by the final exp).
-
-    P is a G1 affine point (coords in Fq); Q is an untwisted G2 point with
-    coordinates in Fq12. Line arithmetic stays in Fq; only the evaluation
-    accumulator lives in Fq12.
-    """
-    xq, yq = q_untwisted
+    live = [(-p[0] % Q_MOD, (p[1], 0), q) for p, q in pairs
+            if p is not None and q is not None]
+    running = [q for _, _, q in live]
     f = FQ12_ONE
-    tx, ty = p  # T = P, affine in Fq
+    for bit in _ATE_BITS:
+        f = fq12_sq(f)
+        for i, (neg_xp, yp, q) in enumerate(live):
+            t = running[i]
+            line, t = _line_step(t, t, neg_xp, yp)
+            f = fq12_mul_sparse(f, *line)
+            if bit:
+                line, t = _line_step(t, q, neg_xp, yp)
+                f = fq12_mul_sparse(f, *line)
+            running[i] = t
+    # f_{-|x|,Q} = 1 / f_{|x|,Q} up to a vertical line, and 1/f is conj(f)
+    # up to a factor the final exponentiation kills
+    return fq12_conj(f) if BLS_X_IS_NEG else f
 
-    def line_eval(lam, x0, y0):
-        # l(Q) = (y_Q - y0) - lam * (x_Q - x0)
-        t1 = _fq12_sub(yq, _fq12_from_fq(y0))
-        t2 = _fq12_scalar_fq(_fq12_sub(xq, _fq12_from_fq(x0)), lam)
-        return _fq12_sub(t1, t2)
 
-    bits = bin(R_MOD)[3:]  # skip leading 1
-    T_inf = False
-    for b in bits:
-        if not T_inf:
-            # doubling step
-            if ty == 0:
-                T_inf = True
-            else:
-                lam = 3 * tx * tx % Q_MOD * fq_inv(2 * ty % Q_MOD) % Q_MOD
-                f = fq12_mul(fq12_sq(f), line_eval(lam, tx, ty))
-                nx = (lam * lam - 2 * tx) % Q_MOD
-                ny = (lam * (tx - nx) - ty) % Q_MOD
-                tx, ty = nx, ny
-        else:
-            f = fq12_sq(f)
-        if b == "1" and not T_inf:
-            # addition step T += P
-            px, py = p
-            if tx == px:
-                if (ty + py) % Q_MOD == 0:
-                    # vertical line, dropped; T becomes infinity
-                    T_inf = True
-                else:
-                    lam = 3 * tx * tx % Q_MOD * fq_inv(2 * ty % Q_MOD) % Q_MOD
-                    f = fq12_mul(f, line_eval(lam, tx, ty))
-                    nx = (lam * lam - 2 * tx) % Q_MOD
-                    ny = (lam * (tx - nx) - ty) % Q_MOD
-                    tx, ty = nx, ny
-            else:
-                lam = (py - ty) * fq_inv((px - tx) % Q_MOD) % Q_MOD
-                f = fq12_mul(f, line_eval(lam, tx, ty))
-                nx = (lam * lam - tx - px) % Q_MOD
-                ny = (lam * (tx - nx) - ty) % Q_MOD
-                tx, ty = nx, ny
-    return f
+def _pow_x(a):
+    """a^x for a in the cyclotomic subgroup (where 1/a = conj(a))."""
+    result = a
+    for bit in _ATE_BITS:
+        result = fq12_sq(result)
+        if bit:
+            result = fq12_mul(result, a)
+    return fq12_conj(result) if BLS_X_IS_NEG else result
+
+
+def final_exponentiation(f):
+    """f^(3 (q^12 - 1)/r): the CUBE of the textbook reduced value, so 1
+    exactly when that is 1 (3 does not divide r) and as bilinear.
+
+    (q^12 - 1)/r = (q^6 - 1)(q^2 + 1) * (q^4 - q^2 + 1)/r. The easy part is
+    a conjugate, an inverse and a Frobenius. It lands in the cyclotomic
+    subgroup, where an inverse is a conjugate, and the hard part goes by
+        3 (q^4 - q^2 + 1)/r = (x - 1)^2 (x + q)(x^2 + q^2 - 1) + 3
+    (Hayashida, Hayasaka, Teruya, eprint 2020/875): five powers by x.
+    """
+    m = fq12_mul(fq12_conj(f), fq12_inv(f))                    # ^(q^6 - 1)
+    m = fq12_mul(fq12_frobenius(fq12_frobenius(m)), m)         # ^(q^2 + 1)
+    a = fq12_mul(_pow_x(m), fq12_conj(m))                      # m^(x - 1)
+    a = fq12_mul(_pow_x(a), fq12_conj(a))                      # ^(x - 1)
+    a = fq12_mul(_pow_x(a), fq12_frobenius(a))                 # ^(x + q)
+    a = fq12_mul(fq12_mul(_pow_x(_pow_x(a)),                   # ^(x^2+q^2-1)
+                          fq12_frobenius(fq12_frobenius(a))),
+                 fq12_conj(a))
+    return fq12_mul(a, fq12_mul(fq12_sq(m), m))                # * m^3
 
 
 # pairing-cost accounting: aggregation's whole value proposition is
@@ -347,22 +353,17 @@ def reset_pairing_counters():
 def pairing_check(pairs):
     """Return True iff prod e(P_i, Q_i) == 1.
 
-    Multi-pairing: one Miller loop per pair, a single shared final
-    exponentiation. This is all the verifier needs (KZG check at
-    jf-plonk's verify, reference src/dispatcher2.rs:1290-1293).
+    Multi-pairing: one Miller loop and one final exponentiation for all
+    the pairs. This is all the verifier needs (KZG check at jf-plonk's
+    verify, reference src/dispatcher2.rs:1290-1293).
     """
     PAIRING_COUNTERS["checks"] += 1
-    f = FQ12_ONE
-    for p, q in pairs:
-        if p is None or q is None:
-            continue
-        PAIRING_COUNTERS["pairs"] += 1
-        f = fq12_mul(f, miller_loop(p, _untwist(q)))
-    return fq12_pow(f, FINAL_EXP) == FQ12_ONE
+    PAIRING_COUNTERS["pairs"] += sum(
+        1 for p, q in pairs if p is not None and q is not None)
+    return final_exponentiation(miller_loop(pairs)) == FQ12_ONE
 
 
 def pairing(p, q):
-    """Full pairing value (slow; used only in tests for bilinearity)."""
-    if p is None or q is None:
-        return FQ12_ONE
-    return fq12_pow(miller_loop(p, _untwist(q)), FINAL_EXP)
+    """The pairing's value, e(P, Q)^3 (see final_exponentiation); tests
+    use it for bilinearity."""
+    return final_exponentiation(miller_loop([(p, q)]))

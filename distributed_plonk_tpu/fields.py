@@ -62,7 +62,9 @@ def fq_neg(a):
 def fq_inv(a):
     if a == 0:
         raise ZeroDivisionError("Fq inverse of zero")
-    return pow(a, Q_MOD - 2, Q_MOD)
+    # extended Euclid, not Fermat: a tenth of the time for the same value,
+    # and the affine Miller loop of curve.py inverts once a line
+    return pow(a, -1, Q_MOD)
 
 
 def batch_inverse(vals, mod):
@@ -144,6 +146,16 @@ def fq2_inv(a):
     return (a[0] * ninv % Q_MOD, (-a[1]) * ninv % Q_MOD)
 
 
+def fq2_pow(a, e):
+    result = FQ2_ONE
+    while e > 0:
+        if e & 1:
+            result = fq2_mul(result, a)
+        a = fq2_sq(a)
+        e >>= 1
+    return result
+
+
 # nonresidue xi = u + 1 (Fq6 = Fq2[v]/(v^3 - xi))
 FQ2_XI = (1, 1)
 
@@ -192,6 +204,23 @@ def fq6_mul_by_v(a):
     return (fq2_mul_by_xi(a[2]), a[0], a[1])
 
 
+def fq6_mul_by_01(a, b0, b1):
+    # (a0 + a1 v + a2 v^2)(b0 + b1 v): five Fq2 products for fq6_mul's six
+    a0, a1, a2 = a
+    t0 = fq2_mul(a0, b0)
+    t1 = fq2_mul(a1, b1)
+    c0 = fq2_add(t0, fq2_mul_by_xi(fq2_mul(a2, b1)))
+    c1 = fq2_sub(fq2_mul(fq2_add(a0, a1), fq2_add(b0, b1)), fq2_add(t0, t1))
+    c2 = fq2_add(fq2_mul(a2, b0), t1)
+    return (c0, c1, c2)
+
+
+def fq6_mul_by_1(a, b1):
+    # (a0 + a1 v + a2 v^2)(b1 v) = xi a2 b1 + a0 b1 v + a1 b1 v^2
+    return (fq2_mul_by_xi(fq2_mul(a[2], b1)), fq2_mul(a[0], b1),
+            fq2_mul(a[1], b1))
+
+
 def fq6_inv(a):
     a0, a1, a2 = a
     c0 = fq2_sub(fq2_sq(a0), fq2_mul_by_xi(fq2_mul(a1, a2)))
@@ -218,7 +247,27 @@ def fq12_mul(a, b):
 
 
 def fq12_sq(a):
-    return fq12_mul(a, a)
+    # (a0 + a1 w)^2 = (a0 + a1)(a0 + v a1) - t - v t + 2 t w, t = a0 a1:
+    # two Fq6 products for fq12_mul's three
+    a0, a1 = a
+    t = fq6_mul(a0, a1)
+    c0 = fq6_sub(fq6_sub(fq6_mul(fq6_add(a0, a1),
+                                 fq6_add(a0, fq6_mul_by_v(a1))), t),
+                 fq6_mul_by_v(t))
+    return (c0, fq6_add(t, t))
+
+
+def fq12_mul_sparse(a, b0, b1, b4):
+    """a * (b0 + b1 v + b4 v w), the b's in Fq2: the shape of a line of the
+    Miller loop (curve.py). Equal to fq12_mul by the same element written
+    out in full; thirteen Fq2 products for its eighteen."""
+    a0, a1 = a
+    t0 = fq6_mul_by_01(a0, b0, b1)
+    t1 = fq6_mul_by_1(a1, b4)
+    c0 = fq6_add(t0, fq6_mul_by_v(t1))
+    c1 = fq6_sub(fq6_sub(
+        fq6_mul_by_01(fq6_add(a0, a1), b0, fq2_add(b1, b4)), t0), t1)
+    return (c0, c1)
 
 
 def fq12_inv(a):
@@ -229,7 +278,27 @@ def fq12_inv(a):
 
 
 def fq12_conj(a):
+    """a^(q^6): w -> -w. The inverse of an element of the cyclotomic
+    subgroup (anything raised to q^6 - 1)."""
     return (a[0], fq6_neg(a[1]))
+
+
+# w^(q-1) = xi^((q-1)/6) since w^6 = xi: the q-power Frobenius conjugates
+# every Fq2 coefficient and multiplies the coefficient of w^k by the k-th
+# power of it. Worked out here from Q_MOD and xi, not pasted as a table.
+_FROBENIUS_W = [FQ2_ONE, fq2_pow(FQ2_XI, (Q_MOD - 1) // 6)]
+for _ in range(4):
+    _FROBENIUS_W.append(fq2_mul(_FROBENIUS_W[-1], _FROBENIUS_W[1]))
+
+
+def fq12_frobenius(a):
+    """a^q."""
+    (c0, c2, c4), (c1, c3, c5) = a  # a = sum c_k w^k, with v = w^2
+    g = _FROBENIUS_W
+    return ((fq2_conj(c0), fq2_mul(fq2_conj(c2), g[2]),
+             fq2_mul(fq2_conj(c4), g[4])),
+            (fq2_mul(fq2_conj(c1), g[1]), fq2_mul(fq2_conj(c3), g[3]),
+             fq2_mul(fq2_conj(c5), g[5])))
 
 
 def fq12_pow(a, e):

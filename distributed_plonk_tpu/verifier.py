@@ -215,6 +215,14 @@ def opening_terms(vk, pub_input, proof, u, domain=None):
     return points, scalars, rhs_points, rhs_scalars
 
 
+def _fold_openings(rhs_points, rhs_scalars):
+    """W1 + u W2 from opening_terms' right-hand side: one scalar
+    multiplication and one addition (a bucket method has nothing to share
+    between two points, one of them taken once)."""
+    (w1, w2), (_, u) = rhs_points, rhs_scalars
+    return C.g1_add_affine(w1, C.g1_mul(w2, u))
+
+
 def verify(vk, pub_input, proof, domain=None, rng=None):
     rng = rng or random.Random()
     u = rng.randrange(1, R_MOD)
@@ -223,7 +231,7 @@ def verify(vk, pub_input, proof, domain=None, rng=None):
         return False
     points, scalars, rhs_points, rhs_scalars = terms
     lhs = C.g1_msm(points, scalars)
-    rhs_w = C.g1_msm(rhs_points, rhs_scalars)
+    rhs_w = _fold_openings(rhs_points, rhs_scalars)
     return C.pairing_check([
         (lhs, vk.g2),
         (C.g1_neg(rhs_w), vk.tau_g2),
@@ -262,8 +270,8 @@ def verify_aggregate(members, domains=None):
         points, scalars, rpoints, rscalars = terms
         lhs_points += points
         lhs_scalars += [r * s % R_MOD for s in scalars]
-        rhs_points += rpoints
-        rhs_scalars += [r * s % R_MOD for s in rscalars]
+        rhs_points.append(_fold_openings(rpoints, rscalars))
+        rhs_scalars.append(r)
     lhs = C.g1_msm(lhs_points, lhs_scalars)
     rhs_w = C.g1_msm(rhs_points, rhs_scalars)
     return C.pairing_check([
