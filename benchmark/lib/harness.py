@@ -74,7 +74,13 @@ class TraceStretches:
     sample of the whole window and not of one phase of a proof that
     somebody chose; a stretch that cannot begin at its offset, because the
     one before is still being stopped, begins when it can, and if all of
-    them found the device empty more are taken. The session is
+    them found the device empty more are taken. A stretch is the sleep
+    inside the host annotation `tracered.MARK`, opened once the profiler
+    runs and closed before it is stopped: the trace holds more device time
+    than the sleep, and the mark is what `tracered` cuts it to, so a
+    stretch's busy time and its length are read on the trace's one clock.
+    The host clock's reading of the sleep only places the stretch in the
+    window (`began_s`) and goes into the log beside the mark's. The session is
     the profiler's own (`ProfilerSession.stop()` returns the trace;
     `jax.profiler.stop_trace` would also export it for TensorBoard, which
     more than doubles the stop). `run()` is called on the thread that
@@ -111,7 +117,8 @@ class TraceStretches:
         opts.host_tracer_level = 2
         session = _profiler.ProfilerSession(opts)
         t0 = self.clock()
-        time.sleep(self.stretch_s)
+        with jax.profiler.TraceAnnotation(tracered.MARK):
+            time.sleep(self.stretch_s)
         t1 = self.clock()
         self.taken.append((t0, t1, session.stop()))
 
@@ -125,12 +132,8 @@ class TraceStretches:
                 and tracered.busy(tracered.read_xspace(xspace).events) is None)
 
     def read(self):
-        """[(events, operations counted, seconds)], one for each stretch."""
-        out = []
-        for t0, t1, xspace in self.taken:
-            trace = tracered.read_xspace(xspace)
-            out.append((trace.events, trace.op_events, t1 - t0))
-        return out
+        """The `tracered.Trace` of each stretch, cut to its mark."""
+        return [tracered.read_xspace(xspace) for _t0, _t1, xspace in self.taken]
 
 
 def look_for_chip(cell, require_tpu):
@@ -378,7 +381,10 @@ def run_cell(root, workload, seed, seconds, trace, t_start, **session_kw):
                 for m in cell.end_to_end
                 if values.get(m["name"]) is not None}
         else:
-            traced = stretches.read()
+            t_read = ses.clock()
+            traces = stretches.read()
+            read_s = ses.clock() - t_read
+            traced = [(tr.events, tr.op_events, tr.window_s) for tr in traces]
             ev = readers.Evidence(
                 statuses=[r.status for r in good],
                 metrics_open=metrics_open, metrics_close=metrics_close,
@@ -396,13 +402,19 @@ def run_cell(root, workload, seed, seconds, trace, t_start, **session_kw):
                 "idle_gaps": tracered.top(tracered.summed(
                     tracered.idle_gaps(e, HOST_SPAN_RE)
                     for e, _ops, _s in traced))}
-            say(phase="trace",
+            # the two clocks of a stretch, side by side: the sleep by this
+            # process's clock and by the trace's mark, the device's busy
+            # time inside the mark and in all the profiler caught
+            say(phase="trace", read_s=read_s,
                 stretches=[{"began_s": t0 - win.t_open, "seconds": t1 - t0,
-                            "xspace_bytes": len(x), "op_events": ops,
-                            "busy_s": (tracered.busy(e, stretch_s=sec)
-                                       or {}).get("busy_s")}
-                           for (t0, t1, x), (e, ops, sec)
-                           in zip(stretches.taken, traced)])
+                            "mark_s": (tr.window_s if tracered.find_mark(
+                                tr.events) else None),
+                            "xspace_bytes": len(x), "op_events": tr.op_events,
+                            "busy_s": (tracered.busy(tr.events)
+                                       or {}).get("busy_s"),
+                            "uncut_busy_s": tr.uncut_busy_s}
+                           for (t0, t1, x), tr
+                           in zip(stretches.taken, traces)])
         result["device"] = device_out
         result["checks"] = {k: {"value": c["value"], "limit": c["limit"]}
                             for k, c in checks.items()}

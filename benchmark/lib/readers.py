@@ -21,8 +21,9 @@ class Evidence:
         self.metrics_close = metrics_close or {}
         self.monitoring = list(monitoring)      # [(t, event name)]
         self.t_open, self.t_close = t_open, t_close
-        # the traced stretches, [(events, operations counted, seconds)], or
-        # None where nothing was traced
+        # the traced stretches, [(events cut to the stretch's mark, operations
+        # counted inside it, the mark's seconds)], or None where nothing was
+        # traced
         self.stretches = stretches
         self.stretch_proofs = stretch_proofs    # proofs done in the stretches
         self.memory_stats = list(memory_stats)  # one dict per device
@@ -126,7 +127,9 @@ def _busy(spec, ev):
 
 def read_trace_idle(spec, ev):
     """The share of the traced stretches, together, in which no program
-    ran on the device; raw, so a reading under 0 is possible."""
+    ran on the device. Busy time and length are both on the trace's clock,
+    the device's events cut to each stretch's mark, so it reads 0 to 100:
+    0 for a device busy from edge to edge of every stretch, never less."""
     b = _busy(spec, ev)
     return None if b is None else 100.0 * b["idle_share"]
 

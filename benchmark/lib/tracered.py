@@ -1,6 +1,14 @@
 """From a profiler trace to numbers: device busy union, idle share, time by
 program family, the operations that took most time, the longest idle gaps.
 
+A traced stretch is on ONE clock, the trace's own. The harness sleeps inside
+a host annotation named `MARK`; where the trace is read, the device's events
+are cut to that mark's interval, once (`cut`, in `reduce_profile`), and
+every number of the stretch comes from the cut events, its length from the
+mark. The profiler runs a little longer than the mark on both sides, so
+without the cut a saturated device would read busier than the stretch is
+long (PERF.md sec. 6, PR 33).
+
 The reduction works on plain event tuples so that it is tested on a tiny
 synthetic list; `read_xspace` alone touches jax.
 """
@@ -22,24 +30,80 @@ MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 SKIPPED_LINES = (OPS_LINE, "Async XLA Ops")
 
-Trace = namedtuple("Trace", "events op_events")
+# the host annotation a traced stretch is taken inside (harness.py::
+# TraceStretches._take): its interval IS the stretch
+MARK = "bench/stretch"
+
+# events: cut to the mark; op_events: operation events that start inside it;
+# window_s: the stretch's length (`window`: the mark's); uncut_busy_s: `busy`
+# of the events as the profiler gave them, for the log
+Trace = namedtuple("Trace", "events op_events window_s uncut_busy_s")
+
+
+def find_mark(events):
+    """(start_ns, end_ns) of the stretch's mark, the event named MARK
+    outside the device planes (a profiler session holds one), or None."""
+    for e in events:
+        if e.name == MARK and not DEVICE_PLANE_RE.match(e.plane):
+            return e.start_ns, e.start_ns + e.dur_ns
+    return None
+
+
+def window(events):
+    """(start_ns, end_ns) of the stretch these events are of: the mark's
+    interval, or without a mark the first event's start to the last one's
+    end over ALL planes, host threads included. None without events."""
+    if not events:
+        return None
+    return find_mark(events) or (min(e.start_ns for e in events),
+                                 max(e.start_ns + e.dur_ns for e in events))
+
+
+def cut(events):
+    """The events cut to their mark: a device-plane event outside the
+    mark's interval is dropped and one that straddles an edge is cut at
+    it; host events stay whole (they only name gaps). Without a mark the
+    events come back as they are."""
+    mark = find_mark(events)
+    if mark is None:
+        return events
+    lo, hi = mark
+    device = set(device_planes(events))
+    out = []
+    for e in events:
+        if e.plane in device:
+            start = max(e.start_ns, lo)
+            end = min(e.start_ns + e.dur_ns, hi)
+            if end < start or (end == start and e.dur_ns):
+                continue
+            if end - start != e.dur_ns:
+                e = e._replace(start_ns=start, dur_ns=end - start)
+        out.append(e)
+    return out
 
 
 def reduce_profile(data):
     """A jax.profiler.ProfileData -> Trace: every event but those of the
-    operation-level device lines, and how many operation events there
-    were on the busiest device plane."""
-    events, op_events = [], 0
+    operation-level device lines, the device's cut to the mark, and how
+    many operation events began inside the mark on the busiest device
+    plane."""
+    events, op_lines = [], []
     for plane in data.planes:
         for line in plane.lines:
             if line.name in SKIPPED_LINES:
                 if line.name == OPS_LINE and DEVICE_PLANE_RE.match(plane.name):
-                    op_events = max(op_events, sum(1 for _ in line.events))
+                    op_lines.append(line)
                 continue
             for ev in line.events:
                 events.append(Event(plane.name, line.name, ev.name,
                                     int(ev.start_ns), int(ev.duration_ns)))
-    return Trace(events, op_events)
+    # without a mark (no real run: the tests' lists) every operation counts
+    lo, hi = find_mark(events) or (float("-inf"), float("inf"))
+    op_events = max((sum(1 for ev in line.events if lo <= ev.start_ns <= hi)
+                     for line in op_lines), default=0)
+    w = window(events)
+    return Trace(cut(events), op_events, (w[1] - w[0]) / 1e9 if w else 0.0,
+                 (busy(events) or {}).get("busy_s"))
 
 
 def read_xspace(serialized):
@@ -69,17 +133,15 @@ def _busy_intervals(events, plane, line):
                   if e.plane == plane and e.line == line and e.dur_ns > 0])
 
 
-def busy(events, line=MODULES_LINE, stretch_s=None):
+def busy(events, line=MODULES_LINE):
     """{"busy_s", "window_s", "idle_share", "planes"}: seconds in which a
     program ran on the device, averaged over the device planes that ran
-    any, and the traced stretch they are a share of. `stretch_s` is the
-    stretch's length by the clock that started and stopped the profiler;
-    without it the stretch is the first event's start to the last one's end
-    over ALL planes, host threads included. Either way a stretch that
-    begins or ends with the device empty counts that time as idle, and a
-    busy time above the stretch's length (the two clocks differ by the
-    profiler's start and stop) shows as it is, as a negative idle share:
-    nothing is cut off at 100%. None without a device event."""
+    any, and the stretch they are a share of (`window`), both on the
+    trace's clock. A stretch that begins or ends with the device empty
+    counts that time as idle. Handed events that are cut to their mark, or
+    that have none, every plane's busy intervals lie inside the stretch:
+    0 <= busy_s <= window_s, and a device busy from edge to edge reads
+    idle 0, never less. None without a device event."""
     planes = device_planes(events)
     per_plane = {p: _busy_intervals(events, p, line) for p in planes}
     per_plane = {p: iv for p, iv in per_plane.items() if iv}
@@ -87,25 +149,24 @@ def busy(events, line=MODULES_LINE, stretch_s=None):
         return None
     busy_ns = sum(sum(e - s for s, e in iv) for iv in per_plane.values())
     busy_s = busy_ns / len(per_plane) / 1e9
-    if stretch_s is None:
-        start = min(e.start_ns for e in events)
-        end = max(e.start_ns + e.dur_ns for e in events)
-        stretch_s = (end - start) / 1e9
-    return {"busy_s": busy_s, "window_s": stretch_s,
-            "idle_share": 1.0 - busy_s / stretch_s if stretch_s > 0 else None,
+    start, end = window(events)
+    window_s = (end - start) / 1e9
+    return {"busy_s": busy_s, "window_s": window_s,
+            "idle_share": 1.0 - busy_s / window_s if window_s > 0 else None,
             "planes": len(per_plane)}
 
 
 def busy_over(stretches, line=MODULES_LINE):
-    """`busy` over several traced stretches [(events, stretch_s)]: busy
-    seconds and lengths summed. A stretch in which nothing ran on the
+    """`busy` over several traced stretches [(events, seconds)], the
+    seconds a stretch's length on its trace's clock (`Trace.window_s`):
+    busy seconds and lengths summed. A stretch in which nothing ran on the
     device (its trace may hold no device plane at all) is idle for its
     whole length. None when no stretch has a device event."""
     busy_s = total_s = 0.0
     seen = False
-    for events, stretch_s in stretches:
-        b = busy(events, line, stretch_s)
-        total_s += stretch_s
+    for events, seconds in stretches:
+        b = busy(events, line)
+        total_s += seconds
         if b is not None:
             busy_s += b["busy_s"]
             seen = True

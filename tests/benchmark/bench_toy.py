@@ -170,6 +170,28 @@ def hold_the_phase_metrics(root):
                                    else "program_span")
 
 
+def trace_line(out):
+    """The `"phase": "trace"` line of a traced run's standard output."""
+    lines = [json.loads(l) for l in out.splitlines()
+             if l.startswith('{"phase": "trace"')]
+    assert len(lines) == 1
+    return lines[0]
+
+
+def hold_the_stretches_to_their_marks(line):
+    """Every stretch of a traced run's log line was found marked in its own
+    trace, and carries the mark's seconds beside the host clock's. On the
+    CPU there is no device plane, so no busy time to hold under them."""
+    assert line["stretches"] and line["read_s"] >= 0
+    for st in line["stretches"]:
+        assert set(st) == {"began_s", "seconds", "mark_s", "xspace_bytes",
+                           "op_events", "busy_s", "uncut_busy_s"}
+        assert st["mark_s"] is not None and st["mark_s"] > 0
+        assert st["seconds"] > 0 and st["xspace_bytes"] > 0
+        assert st["busy_s"] is None and st["uncut_busy_s"] is None
+        assert st["op_events"] == 0
+
+
 @pytest.fixture
 def toy_root(tmp_path):
     """A toy root; and the environment as it was, afterwards: a run sets the

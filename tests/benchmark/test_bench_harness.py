@@ -12,7 +12,9 @@ import time
 
 import pytest
 
-from bench_toy import REPO, make_toy_root, toy_root  # noqa: F401 - fixture
+from bench_toy import (  # noqa: F401 - toy_root is a fixture
+    REPO, hold_the_stretches_to_their_marks, make_toy_root, toy_root,
+    trace_line)
 from benchmark.lib import faults, harness, manifest as M
 
 SEED = 2 ** 31 + 77
@@ -116,9 +118,34 @@ def test_an_answer_that_never_comes_is_not_correct(toy_root):
         ses.close()
 
 
-def test_traced_run_reports_the_per_layer_metrics_it_can_read(toy_root):
+def test_the_mark_comes_back_in_every_stretch_of_a_real_trace():
+    """A real ProfilerSession around the harness's own `_take`: the host
+    annotation reaches the trace, once, outside any device plane."""
+    from benchmark.lib import tracered
+    st = harness.TraceStretches(time.monotonic, SEED, 0.3, stretch_s=0.01)
+    for _ in st.offsets:
+        st._take()
+    traces = st.read()
+    assert len(traces) == len(st.taken) == 3
+    for (t0, t1, xspace), tr in zip(st.taken, traces):
+        assert t1 > t0 and len(xspace) > 0
+        marks = [e for e in tr.events if e.name == tracered.MARK]
+        assert len(marks) == 1
+        assert not tracered.DEVICE_PLANE_RE.match(marks[0].plane)
+        assert tr.window_s == marks[0].dur_ns / 1e9 > 0
+        assert tracered.window(tr.events) == (
+            marks[0].start_ns, marks[0].start_ns + marks[0].dur_ns)
+        # nothing ran on a device here: the stretch is idle for its length
+        assert tracered.busy(tr.events) is None
+        assert harness.TraceStretches.nothing_ran(xspace) is True
+    assert tracered.busy_over([(tr.events, tr.window_s)
+                               for tr in traces]) is None
+
+
+def test_traced_run_reports_the_per_layer_metrics_it_can_read(toy_root, capfd):
     res = _run(toy_root, trace=1)
     assert res["correct"] is True
+    hold_the_stretches_to_their_marks(trace_line(capfd.readouterr().out))
     cell = M.Cell(M.load(toy_root), toy_root, "toy.loop")
     names = {s["name"] for s in cell.per_layer}
     assert set(res["metrics"]) <= names

@@ -2,6 +2,7 @@
 tiny synthetic event list, and the generic readers."""
 
 import os
+from types import SimpleNamespace as NS
 
 import pytest
 
@@ -81,11 +82,148 @@ def test_busy_union_and_idle_share():
     assert b["window_s"] == pytest.approx(0.100)
     assert b["idle_share"] == pytest.approx(0.60)
     assert T.busy([e for e in _events() if e.plane == HOST]) is None
-    # nothing is cut off at 100%: a device busier than the stretch is long
-    # by the host's clock reads as it is
-    over = T.busy(_events(), stretch_s=0.032)
-    assert over["window_s"] == 0.032
-    assert over["idle_share"] == pytest.approx(1 - 0.040 / 0.032)
+    # a share is taken on one clock: with the stretch marked in the trace
+    # (12..44 ms) the 40 ms of programs the profiler caught around it read
+    # as the 18 ms of them inside it, and never as more than its 32 ms
+    marked = T.cut(_events() + [_mark(12, 32)])
+    inside = T.busy(marked)
+    assert inside["window_s"] == pytest.approx(0.032)
+    assert inside["busy_s"] == pytest.approx(0.018)
+    assert 0 <= inside["idle_share"] == pytest.approx(1 - 0.018 / 0.032)
+
+
+def _mark(start_ms, dur_ms):
+    """The harness's annotation around a stretch's sleep, on a host thread."""
+    return T.Event(HOST, "main", T.MARK, start_ms * 1_000_000,
+                   dur_ms * 1_000_000)
+
+
+def _saturated(lead_ns, mark_ns, tail_ns, programs=40):
+    """A stretch whose device never rests: back-to-back programs from
+    `lead_ns` before the mark to `tail_ns` after it, as the profiler, which
+    starts before the sleep and stops after it, hands them over."""
+    total = lead_ns + mark_ns + tail_ns
+    step = total // programs
+    dev = [T.Event(DEV, T.MODULES_LINE, f"jit_msm_bucket_scan({i})",
+                   i * step, step if i < programs - 1 else total - i * step)
+           for i in range(programs)]
+    return dev + [T.Event(HOST, "main", T.MARK, lead_ns, mark_ns),
+                  T.Event(HOST, "worker", "round1", 0, total)]
+
+
+def test_device_events_are_cut_to_the_mark_and_host_events_are_not():
+    ms = 1_000_000
+    events = _events() + [_mark(25, 30)]          # the stretch is 25..55 ms
+    assert T.find_mark(events) == (25 * ms, 55 * ms)
+    assert T.window(events) == (25 * ms, 55 * ms)
+    got = T.cut(events)
+    dev = [(e.name, e.start_ns, e.dur_ns) for e in got if e.plane == DEV]
+    # the msm began before the mark (10..30 -> 25..30), the first ntt ends
+    # after it (50..60 -> 50..55), the second (58..70) is outside and goes
+    assert dev == [("jit_bucket_planes(12)", 25 * ms, 5 * ms),
+                   ("jit_ntt_core(7)", 50 * ms, 5 * ms)]
+    assert [e for e in got if e.plane == HOST] == \
+        [e for e in events if e.plane == HOST]
+    b = T.busy(got)
+    assert b["busy_s"] == pytest.approx(0.010)
+    assert b["window_s"] == pytest.approx(0.030)
+    # the breakdown and the gaps read the same cut events
+    assert T.time_by_name(got) == {"jit_bucket_planes": pytest.approx(0.005),
+                                   "jit_ntt_core": pytest.approx(0.005)}
+    assert T.idle_gaps(got, r"^round\d") == {"round1": pytest.approx(0.020)}
+    assert T.cut(got) == got                      # cut once or twice: the same
+
+
+def test_a_list_without_a_mark_reads_by_its_own_span():
+    ms = 1_000_000
+    events = _events()
+    assert T.find_mark(events) is None
+    assert T.cut(events) is events
+    assert T.window(events) == (0, 100 * ms)
+    assert T.window([]) is None
+    # a device event of the mark's name is no mark
+    named = events + [T.Event(DEV, T.MODULES_LINE, T.MARK, 0, 5 * ms)]
+    assert T.find_mark(named) is None
+
+
+@pytest.mark.parametrize("lead_ns, mark_ns, tail_ns", [
+    (0, 400_000_000, 0),                    # the profiler caught no more
+    (1_000_000, 400_000_000, 1_000_000),
+    # PR 33's refused stretch: 0.411753 s of device intervals in a trace
+    # whose sleep was 0.400256 s long
+    (5_000_000, 400_256_000, 6_497_000),
+])
+def test_a_stretch_busy_from_edge_to_edge_reads_its_length_and_no_more(
+        lead_ns, mark_ns, tail_ns):
+    events = _saturated(lead_ns, mark_ns, tail_ns)
+    uncut = T.busy(events)["busy_s"]
+    assert uncut == pytest.approx((lead_ns + mark_ns + tail_ns) / 1e9)
+    b = T.busy(T.cut(events))
+    assert b["window_s"] == mark_ns / 1e9
+    assert b["busy_s"] == b["window_s"]           # to the nanosecond
+    assert b["idle_share"] == 0.0
+    by_name = T.time_by_name(T.cut(events))
+    assert sum(by_name.values()) == pytest.approx(b["window_s"])
+    assert T.idle_gaps(T.cut(events), r"^round\d") == {}
+
+
+def test_three_saturated_stretches_are_no_busier_than_they_are_long():
+    cuts = [T.cut(_saturated(lead, mark, tail)) for lead, mark, tail in
+            [(1_559_000, 400_605_000, 0), (11_497_000, 400_256_000, 0),
+             (700_000, 400_478_000, 805_000)]]
+    stretches = [(ev, T.busy(ev)["window_s"]) for ev in cuts]
+    b = T.busy_over(stretches)
+    assert b["window_s"] == pytest.approx(1.201339)
+    assert 0 < b["busy_s"] <= b["window_s"]
+    assert b["idle_share"] == pytest.approx(0.0, abs=1e-12)
+    assert b["idle_share"] >= 0
+    # two planes, one busy throughout and one half of the time: the mean
+    half = [e._replace(plane="/device:TPU:1", dur_ns=e.dur_ns // 2)
+            for e in cuts[0] if e.plane == DEV]
+    two = T.busy(cuts[0] + half)
+    assert two["planes"] == 2
+    assert two["busy_s"] == pytest.approx(0.75 * two["window_s"], rel=1e-6)
+
+
+def _line(name, events):
+    # ProfileData hands the times over as floats
+    return NS(name=name, events=[NS(name=n, start_ns=float(s),
+                                    duration_ns=float(d))
+                                 for n, s, d in events])
+
+
+def _profile(mark):
+    ms = 1_000_000
+    ops = [("fusion", t * ms, ms // 2) for t in range(0, 100, 2)]   # 50 ops
+    host = [("round1", 0, 100 * ms)] + ([(T.MARK, *mark)] if mark else [])
+    return NS(planes=[
+        # the device plane comes first, as it does in a real trace
+        NS(name=DEV, lines=[
+            _line(T.OPS_LINE, ops), _line("Async XLA Ops", ops[:7]),
+            _line(T.MODULES_LINE, [("jit_ntt_core(7)", 0, 100 * ms)])]),
+        NS(name="/device:TPU:1", lines=[_line(T.OPS_LINE, ops[:20])]),
+        # a host line of that name is not the device's
+        NS(name=HOST, lines=[_line("main", host), _line(T.OPS_LINE, ops)])])
+
+
+def test_operations_outside_the_mark_are_not_counted():
+    ms = 1_000_000
+    whole = T.reduce_profile(_profile(None))
+    assert whole.op_events == 50 and T.find_mark(whole.events) is None
+    assert whole.window_s == pytest.approx(0.100)
+    assert whole.uncut_busy_s == pytest.approx(0.100)
+    tr = T.reduce_profile(_profile((30 * ms, 40 * ms)))       # 30..70 ms
+    # those that BEGIN inside count: 30, 32, .. 70 on the busiest plane
+    assert tr.op_events == 21 and T.find_mark(tr.events) == (30 * ms, 70 * ms)
+    assert tr.window_s == pytest.approx(0.040)
+    assert tr.uncut_busy_s == pytest.approx(0.100)
+    b = T.busy(tr.events)
+    assert b["busy_s"] == b["window_s"] == tr.window_s
+    assert all(isinstance(e.start_ns, int) for e in tr.events)
+    # nothing in it at all: no length, no busy time, and no error
+    empty = T.reduce_profile(NS(planes=[]))
+    assert empty == T.Trace([], 0, 0.0, None)
+    assert T.busy_over([(empty.events, empty.window_s)]) is None
 
 
 def test_several_stretches_add_up():

@@ -11,7 +11,9 @@ import time
 import pytest
 
 from bench_toy import (DEVICE, LEDGER, NEW, PHASE, REPO,
-                       hold_the_phase_metrics, make_toy_root)
+                       hold_the_phase_metrics,
+                       hold_the_stretches_to_their_marks, make_toy_root,
+                       trace_line)
 from benchmark.lib import harness, manifest as M, readers
 
 SEED = 2 ** 31 + 2601
@@ -87,9 +89,11 @@ def _traced_toy_run(tmp_path, backend):
         os.environ.update(before)
 
 
-def test_on_the_jax_backend_every_new_metric_reads_a_number(tmp_path):
+def test_on_the_jax_backend_every_new_metric_reads_a_number(tmp_path, capfd):
     res = _traced_toy_run(tmp_path, "jax")
     assert res["correct"] is True
+    # XLA:CPU's threads fill these traces, and the mark is found in each
+    hold_the_stretches_to_their_marks(trace_line(capfd.readouterr().out))
     for name in NEW:
         assert name in res["metrics"], name    # None would leave it out
         assert res["metrics"][name]["value"] >= 0
