@@ -131,30 +131,6 @@ def _ntt_stage_breakdown(plan, radix, reps=5):
         out["radix2_stages"] = plan.log_n
     out["output_perm_s"] = timed(
         jax.jit(lambda a, p: a[:, :, p]), v, jnp.asarray(plan.perm))
-    # fused-stage variant (ntt_pallas): the whole multi-group pipeline —
-    # every butterfly stage, pre-permutation — as its pallas_call
-    # sequence. On TPU this runs at the plan's full width; off-TPU the
-    # interpret-mode kernel is timed at a reduced width and the entry
-    # says so (the PR 5 degraded-basis convention).
-    try:
-        from distributed_plonk_tpu.backend import ntt_pallas as NP
-
-        if jax.default_backend() == "tpu":
-            fplan, fv = plan, v
-            out["fused_basis"] = "tpu-full-size"
-        else:
-            nn = min(plan.n, 1 << 10)
-            fplan = NJ.get_plan(nn)
-            fv = v[:, :, :nn]
-            out["fused_basis"] = f"degraded: interpret mode at n={nn}"
-        sched = NP.plan_schedule(fplan.log_n)
-        consts = {kk: jnp.asarray(a) for kk, a in
-                  fplan.core_consts(False, kernel="pallas").items()}
-        out["fused_groups"] = [1 << r for _, r in sched]
-        out["fused_groups_s"] = timed(
-            jax.jit(lambda a, c: NP.run_groups(a, c)), fv, consts)
-    except Exception as e:  # diagnostic only
-        out["fused_stage_error"] = repr(e)
     return out
 
 
@@ -218,42 +194,6 @@ def device_ntt_seconds():
         meta["ntt_radix4_speedup_vs_radix2"] = round(r2 / r4, 2)
     except Exception as e:  # diagnostic only; never fail the bench line
         meta["ntt_ab_error"] = repr(e)
-    try:
-        # in-run A/B of the fused multi-stage Pallas kernel
-        # (DPT_NTT_KERNEL=pallas, VMEM-resident stage groups) vs the
-        # radix-4 XLA core, same arrays — mirrors
-        # msm_pallas_speedup_vs_onehot. TPU: full size; CPU: the
-        # interpret-mode kernel at a reduced width, recorded as a
-        # degraded basis (CPU is mul-bound, the HBM win cannot show —
-        # the >=1.5x target is a chip-validation ROADMAP item).
-        import jax
-
-        meta["ntt_kernel"] = ntt_jax._active_kernel()
-        if jax.default_backend() == "tpu":
-            ab_plan, ab_v = plan, v
-            meta["ntt_ab_basis"] = "tpu-full-size"
-        else:
-            nn = min(N, 1 << 10)
-            ab_plan = ntt_jax.get_plan(nn)
-            ab_v = v[:, :nn]
-            meta["ntt_ab_basis"] = ("degraded: no TPU — interpret-mode "
-                                    f"kernel at n={nn}, not a chip "
-                                    "measurement")
-        times = {}
-        for mode in ("xla", "pallas"):
-            km = ab_plan.kernel(kernel=mode)
-            sync(km(ab_v))  # compile + warm
-            t0 = time.perf_counter()
-            for _ in range(diag_reps):
-                out = km(ab_v)
-            sync(out)
-            times[mode] = (time.perf_counter() - t0) / diag_reps
-        meta["ntt_ab_xla_radix4_s"] = round(times["xla"], 5)
-        meta["ntt_ab_pallas_s"] = round(times["pallas"], 5)
-        meta["ntt_pallas_speedup_vs_radix4"] = round(
-            times["xla"] / times["pallas"], 2)
-    except Exception as e:
-        meta["ntt_pallas_ab_error"] = repr(e)
     try:
         meta["ntt_stage_breakdown"] = _ntt_stage_breakdown(
             plan, radix, reps=diag_reps)
@@ -495,59 +435,9 @@ def host_prove_seconds():
     return None, "no host baseline available"
 
 
-def _measure_autotune():
-    """Kernel-calibration pickup + in-run A/B (ISSUE 14): every bench
-    line records where the kernel plan came from (fresh|store|off|none),
-    what the pickup cost, and what the plan is worth vs the knob-free
-    defaults at the bench shape (mont-boundary NTT kernel A/B, both
-    sides measured this run). The plan persists in a bench-local store
-    (bench_artifacts/autotune_store), so the first line on a platform
-    records source=fresh and every later line source=store — the
-    trajectory shows both the calibration cost and its amortization.
-    DPT_AUTOTUNE=off skips everything (pre-autotune dispatch exactly)."""
-    mode = os.environ.get("DPT_AUTOTUNE", "run").strip().lower()
-    out = {"autotune_plan_source": "off", "autotune_s": 0.0}
-    if mode == "off":
-        return out
-    t0 = time.perf_counter()
-    try:
-        from distributed_plonk_tpu.backend import autotune as AT
-        from distributed_plonk_tpu.store import ArtifactStore, calibration
-        store = ArtifactStore(os.environ.get(
-            "DPT_AUTOTUNE_STORE",
-            os.path.join(REPO, "bench_artifacts", "autotune_store")))
-        budget = float(os.environ.get("DPT_AUTOTUNE_BUDGET_S", "180"))
-        rep = calibration.load_or_run(store, mode=mode, shapes=[N],
-                                      budget_s=budget, aot=False)
-        out["autotune_plan_source"] = rep.get("source", "none")
-        plan = AT.active_plan()
-        if plan is not None:
-            tuner = AT.Autotuner([N], budget_s=budget)
-            _, dt_plan, _ = tuner._run_ntt(N)
-            AT.set_active_plan(None)
-            try:
-                _, dt_def, _ = tuner._run_ntt(N)
-            finally:
-                AT.set_active_plan(plan)
-            if dt_plan > 0:
-                out["autotune_speedup_vs_defaults"] = round(
-                    dt_def / dt_plan, 3)
-                out["autotune_ab_basis"] = (
-                    "mont-boundary NTT kernel at the bench shape "
-                    f"2^{LOG_N}: calibrated plan vs knob-free defaults, "
-                    "both measured this run")
-    except Exception as e:  # noqa: BLE001 - calibration is diagnostic;
-        # never fail the bench line
-        out["autotune_error"] = repr(e)
-    out["autotune_s"] = round(time.perf_counter() - t0, 3)
-    return out
-
-
 def inner_main():
     """The actual measurement (runs in a budgeted subprocess)."""
     extra = {}
-    extra.update(_measure_autotune())
-    _partial_put(extra)
     ntt_dev, ntt_batch, nb, ntt_meta = device_ntt_seconds()
     extra.update(ntt_meta)
     extra[f"ntt_2p{LOG_N}_elements_per_s"] = round(N / ntt_dev)
@@ -1443,10 +1333,6 @@ def _degraded(reason, extra=None):
         "vs_baseline": None,
         "degraded": True,
         "degraded_reason": reason,
-        # every line carries the autotune keys; a partial inner run's
-        # real values (restored below) override these placeholders
-        "autotune_plan_source": "off",
-        "autotune_s": 0.0,
         "baseline_basis": ("no TPU at capture time; value is null, "
                            "cpu_* keys are live"),
     }
@@ -1467,10 +1353,8 @@ def _degraded(reason, extra=None):
     if cpu:
         out["cpu_ntt_2p14_device_s"] = cpu.get("ntt_2p14_device_s")
         out["cpu_ntt_2p14_elements_per_s"] = cpu.get("ntt_2p14_elements_per_s")
-        for k in ("ntt_radix", "ntt_kernel_variant", "ntt_kernel",
+        for k in ("ntt_radix", "ntt_kernel_variant",
                   "ntt_radix4_speedup_vs_radix2", "ntt_stage_breakdown",
-                  "ntt_ab_basis", "ntt_ab_xla_radix4_s", "ntt_ab_pallas_s",
-                  "ntt_pallas_speedup_vs_radix4", "ntt_pallas_ab_error",
                   "msm_kernel", "msm_stage_breakdown", "msm_ab_basis",
                   "msm_ab_xla_onehot_s", "msm_ab_pallas_s",
                   "msm_pallas_speedup_vs_onehot", "msm_ab_error",

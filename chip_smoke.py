@@ -134,17 +134,13 @@ def serve_and_check(spec, seeds, wait_s=1000.0, aot_warm=True):
 def kernel_paths(domain_size):
     """What `auto` resolved each kernel to at this prove's shapes."""
     from distributed_plonk_tpu.backend import field_jax, msm_jax, ntt_jax
-    from distributed_plonk_tpu.circuit import NUM_WIRE_TYPES
-    from distributed_plonk_tpu.poly import Domain
-    quot = Domain((NUM_WIRE_TYPES + 1) * (domain_size + 1) + 1).size
     return {
         "field_mul": "pallas" if field_jax._use_pallas((16, domain_size))
-        else "xla-" + ("f32" if field_jax._f32_active(domain_size) else "u32"),
-        "ntt": {str(n): ntt_jax._active_kernel(n=n)
-                for n in (domain_size, quot)},
-        "msm": msm_jax._kernel_mode(domain_size + 3),
+        else "xla-" + ("f32" if field_jax._f32_active() else "u32"),
+        "ntt_radix": ntt_jax._active_radix(),
+        "msm": msm_jax._kernel_mode(),
         "msm_bucket_update":
-            "onehot" if msm_jax._use_onehot_update(domain_size + 3) else "put",
+            "onehot" if msm_jax._use_onehot_update() else "put",
         "pallas_interpret": field_jax.pallas_interpret(),
     }
 

@@ -44,7 +44,7 @@ _STATE_FILE = os.path.join(_REPO, ".analysis_state.json")
 # value contracts compare against.
 _ENTRY_MODULES = {
     "field/": ("backend/field_jax.py", "backend/field_pallas.py"),
-    "ntt/": ("backend/ntt_jax.py", "backend/ntt_pallas.py",
+    "ntt/": ("backend/ntt_jax.py",
              "backend/field_jax.py", "backend/field_pallas.py",
              "poly.py"),
     "msm/": ("backend/msm_jax.py", "backend/msm_pallas.py",

@@ -151,7 +151,7 @@ def _ntt_mutant():
     # the shared plan's memo
     plan = NTT.NttPlan(32)
     fn, consts = plan.traced_kernel(False, False, boundary="mont",
-                                    radix=4, kernel="xla")
+                                    radix=4)
     bad = {k: np.asarray(v) for k, v in consts.items()}
     bad["pow"] = np.roll(bad["pow"], 1, axis=1)  # MUTANT: stale twiddles
     entry = R.Entry("ntt/mutant_swapped_twiddle_n32", fn,

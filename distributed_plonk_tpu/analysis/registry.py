@@ -433,13 +433,8 @@ def _ntt_entries():
         for inverse in (False, True):
             for coset in (False, True):
                 for boundary in ("mont", "plain"):
-                    # kernel pinned to the XLA core: these entries prove
-                    # the radix-4 stage pipeline regardless of what
-                    # DPT_NTT_KERNEL resolves to in the checking env
-                    # (the pallas program has its own entries below)
                     fn, consts = plan.traced_kernel(
-                        inverse, coset, boundary=boundary, radix=4,
-                        kernel="xla")
+                        inverse, coset, boundary=boundary, radix=4)
                     cnp = {k: np.asarray(v) for k, v in consts.items()}
                     # value obligations ride the n=32 programs: the
                     # stage pipeline is width-generic and n=64 costs
@@ -457,77 +452,37 @@ def _ntt_entries():
         # stage body is mode-independent modulo pre/post table muls,
         # which the inverse+coset variant includes)
         fn, consts = plan.traced_kernel(True, True, boundary="mont",
-                                        radix=2, kernel="xla")
+                                        radix=2)
         cnp = {k: np.asarray(v) for k, v in consts.items()}
         out.append(Entry(f"ntt/n{n}_radix2_inv1_coset1_mont", fn,
                          (limb_rows(16, n), cnp), [(0, U16)],
                          value=(_ntt_value(n, True, True, cnp)
                                 if n == 32 else None)))
         # batched kernel (the prover's round-1/round-3 launches)
-        fn, consts = plan.traced_kernel(False, True, radix=4, batch=True,
-                                        kernel="xla")
+        fn, consts = plan.traced_kernel(False, True, radix=4, batch=True)
         cnp = {k: np.asarray(v) for k, v in consts.items()}
         out.append(Entry(f"ntt/n{n}_radix4_batch3_coset", fn,
                          (limb_rows(16, 3, n), cnp), [(0, U16)],
                          value=(_ntt_value(n, False, True, cnp,
                                            batch=True)
                                 if n == 32 else None)))
-    # fused multi-stage Pallas kernel (DPT_NTT_KERNEL=pallas): the
-    # pallas_call kernel jaxprs are interpreted like the fused MSM's
-    # (bounds._p_pallas_call). Coverage: forward+coset (pre-scale fused
-    # into the first group) and inverse+coset (reordered post-scales in
-    # the last group) at odd/even log2(n); a small-rows schedule forces
-    # TWO sequential fused groups in one program (narrow VMEM budget);
-    # batch width > 1 checks the (B, tiles) grid. Fresh NttPlan
-    # instances, NOT get_plan: the forced schedules must not poison the
-    # shared plan's consts memo.
-    from ..backend import ntt_pallas as NP
-
-    def pallas_ntt(n, inverse, coset, batch, rows_cap):
-        saved = NP._ROWS_CAP
-        NP._ROWS_CAP = rows_cap
-        try:
-            plan = NTT.NttPlan(n)
-            fn, consts = plan.traced_kernel(inverse, coset, radix=4,
-                                            batch=batch, kernel="pallas")
-        finally:
-            NP._ROWS_CAP = saved
-        cnp = {k: np.asarray(v) for k, v in consts.items()}
-        shape = (16, 3, n) if batch else (16, n)
-        # the pallas programs carry value obligations at their OWN
-        # traced shape: the exact interpreter executes the grid with
-        # persistent scratch refs, so the fused-group scheduling (incl.
-        # the two-group VMEM spill path) is part of what is proven
-        return Entry(
-            f"ntt/n{n}_pallas_inv{int(inverse)}_coset{int(coset)}"
-            + ("_batch3" if batch else "") + f"_rows{rows_cap}",
-            fn, (limb_rows(*shape), cnp), [(0, U16)],
-            value=_ntt_value(n, inverse, coset, cnp, batch=batch))
-
-    out.append(pallas_ntt(64, False, True, False, 64))   # one group, R=6
-    out.append(pallas_ntt(64, True, True, False, 8))     # two groups, R=3
-    out.append(pallas_ntt(32, False, False, True, 32))   # odd log2, batch
-
     # deferred output permutation (DPT_R3_BITREV consumer-side fusion):
     # the forward batch kernel that SKIPS the bit-reversal gather — the
     # round-3 producer launches run this program, with the consuming
     # iNTT's input_perm paying the one remaining gather. Same limb
-    # bounds as the permuted variant (a gather moves lanes, not values);
-    # proved for both stage cores.
-    for kern, tag in (("xla", "radix4"), ("pallas", "pallas")):
-        plan = NTT.NttPlan(64)
-        fn, consts = plan.traced_kernel(False, True, radix=4, batch=True,
-                                        kernel=kern, defer_perm=True)
-        cnp = {k: np.asarray(v) for k, v in consts.items()}
-        # value obligation includes the output-order relation: the
-        # kernel's bit-reversed rows, re-ordered by its OWN consts
-        # permutation, must equal the natural-order oracle — a swapped
-        # or stale perm table is a value finding, not just a lane move
-        out.append(Entry(f"ntt/n64_{tag}_batch3_coset_defer_perm", fn,
-                         (limb_rows(16, 3, 64), cnp), [(0, U16)],
-                         value=_ntt_value(64, False, True, cnp,
-                                          batch=True,
-                                          perm=np.asarray(cnp["perm"]))))
+    # bounds as the permuted variant (a gather moves lanes, not values).
+    fn, consts = NTT.get_plan(64).traced_kernel(
+        False, True, radix=4, batch=True, defer_perm=True)
+    cnp = {k: np.asarray(v) for k, v in consts.items()}
+    # value obligation includes the output-order relation: the
+    # kernel's bit-reversed rows, re-ordered by its OWN consts
+    # permutation, must equal the natural-order oracle — a swapped
+    # or stale perm table is a value finding, not just a lane move
+    out.append(Entry("ntt/n64_radix4_batch3_coset_defer_perm", fn,
+                     (limb_rows(16, 3, 64), cnp), [(0, U16)],
+                     value=_ntt_value(64, False, True, cnp,
+                                      batch=True,
+                                      perm=np.asarray(cnp["perm"]))))
     return out
 
 

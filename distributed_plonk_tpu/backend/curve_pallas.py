@@ -251,8 +251,8 @@ def _add_full_kernel(x1_ref, y1_ref, z1_ref, x2_ref, y2_ref, z2_ref,
 def field_consts(spec):
     """Hashable per-field constant tuple for kernels embedding these
     primitives (jit-static; feed through consts_env inside the kernel
-    body). Width-generic: Fq for the curve/MSM kernels, Fr for the fused
-    NTT stage kernel (ntt_pallas)."""
+    body). Width-generic over the field spec; the curve/MSM kernels pass
+    Fq."""
     L = spec.n_limbs
     return (("n_limbs", L),
             ("ninv_bytes",

@@ -16,7 +16,9 @@ All functions are shape-polymorphic over the batch dims and jit-safe (static
 limb counts, no data-dependent control flow).
 """
 
+import hashlib
 import os
+import platform
 
 import numpy as np
 import jax
@@ -28,10 +30,24 @@ import jax.numpy as jnp
 # AOT entries embed host CPU features, and loading another host's entries
 # fails with "machine feature mismatch" warnings — separate subdirectories
 # make every host build/read only its own entries.
-# machine_fingerprint lives in backend/autotune.py (the calibration
-# artifact key and the compile-cache partition are ONE machine identity).
-from .autotune import machine_fingerprint
-from . import autotune
+
+
+def machine_fingerprint():
+    """Stable 12-hex id of what XLA:CPU AOT entries actually depend on:
+    the architecture + CPU feature flags of this host. Names the
+    persistent compile cache's subdirectory, so a change of its value
+    sends every run's set-up cold."""
+    cpu = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith("flags"):
+                    cpu = line
+                    break
+    except OSError:
+        pass
+    return hashlib.sha256(
+        f"{platform.machine()}|{cpu}".encode()).hexdigest()[:12]
 
 
 def configure_compile_cache(base_dir, min_compile_secs=1.0):
@@ -289,19 +305,17 @@ MUL_CHOICES = ("pallas", "f32", "u32")
 _MUL_MODE = os.environ.get("DPT_FIELD_MUL", "auto")
 
 
-def _mul_path(n=None):
-    """Resolved multiplier mode name: the explicit DPT_FIELD_MUL knob
-    (env, or a test-patched _MUL_MODE attr) wins, then the autotune
-    plan's winner ("field", "mul") near n lanes, else "auto" (platform
-    default). Read per call like msm_jax's dispatch knobs."""
-    return autotune.attr_or_plan(_MUL_MODE, "auto", "DPT_FIELD_MUL",
-                                 "field", "mul", n)
+def _mul_path():
+    """Resolved multiplier mode name: the DPT_FIELD_MUL knob (env, or a
+    test-patched _MUL_MODE attr), "auto" (platform default) when unset.
+    Read per call like msm_jax's dispatch knobs."""
+    return _MUL_MODE
 
 
-def _f32_active(n=None):
+def _f32_active():
     """Whether the XLA byte-product/MXU path (vs the u32 reference
     oracle) backs non-Pallas mont_muls under the resolved mode."""
-    return _mul_path(n) != "u32"
+    return _mul_path() != "u32"
 
 # below this many lanes the per-call overhead of a pallas kernel exceeds
 # the XLA path's cost (scalar/narrow shapes: transcript scalars, finish
@@ -347,7 +361,7 @@ def _use_pallas(shape):
     lanes = 1
     for d in shape[1:]:
         lanes *= d
-    mode = _mul_path(lanes)
+    mode = _mul_path()
     if mode in ("u32", "f32") or lanes < _PALLAS_MIN_LANES:
         return False
     if mode == "pallas":

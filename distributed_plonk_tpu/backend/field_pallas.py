@@ -50,26 +50,7 @@ LIMB_MASK = (1 << LIMB_BITS) - 1
 # VMEM — while giving the VPU full rows. DPT_PALLAS_LANE_TILE widens the
 # tile (fewer sequential grid steps at NTT widths — a 2^22-lane stage mul
 # is 8192 steps at 512 — trading VMEM for per-step overhead).
-LANE_TILE_DEFAULT = 512
-LANE_TILE = int(os.environ.get("DPT_PALLAS_LANE_TILE",
-                               str(LANE_TILE_DEFAULT)))
-
-
-def lane_tile(n=None):
-    """Per-call lane tile: the env/patched LANE_TILE attr wins, else the
-    autotune plan's winner ("field", "lane_tile") near n lanes, else the
-    built-in 512 (same precedence as ntt_pallas._vmem_mb). A plan value
-    that is not a positive power of two falls back to the default — the
-    tile divides the padded lane count and feeds BlockSpec shapes, so a
-    malformed plan (e.g. 0) must never reach the kernel math."""
-    from . import autotune
-
-    t = int(autotune.attr_or_plan(
-        LANE_TILE, LANE_TILE_DEFAULT, "DPT_PALLAS_LANE_TILE",
-        "field", "lane_tile", n, cast=int))
-    if t != LANE_TILE and (t < 1 or (t & (t - 1))):
-        return LANE_TILE_DEFAULT
-    return t
+LANE_TILE = int(os.environ.get("DPT_PALLAS_LANE_TILE", "512"))
 
 
 def _const_bytes(value, n_bytes):
@@ -422,13 +403,12 @@ def mont_mul(spec, a, b):
         lanes *= d
     af = a.reshape(L, lanes)
     bf = b.reshape(L, lanes)
-    tile = lane_tile(lanes)
-    pad = (-lanes) % tile
+    pad = (-lanes) % LANE_TILE
     if pad:
         af = jnp.pad(af, ((0, 0), (0, pad)))
         bf = jnp.pad(bf, ((0, 0), (0, pad)))
     out = _mont_mul_flat(spec.name.lower(), pallas_interpret(), _VARIANT,
-                         tile, af, bf)
+                         LANE_TILE, af, bf)
     if pad:
         out = out[:, :lanes]
     return out.reshape(shape)

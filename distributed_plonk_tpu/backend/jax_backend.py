@@ -37,7 +37,7 @@ from .limbs import ints_to_limbs
 # epilogues, and the final quotient combine into the coset iNTT as a
 # prologue (NttPlan.kernel_fused) — the quotient pipeline loses its
 # standalone O(m) passes. 0 restores the separate jitted step programs
-# (the value-identical reference path, kept like DPT_NTT_KERNEL=xla).
+# (the value-identical reference path).
 _R3_FUSE = os.environ.get("DPT_R3_FUSE", "1") != "0"
 
 # Bit-reversal deferral for the FUSED round 3 (DPT_R3_BITREV, default on;
@@ -48,8 +48,7 @@ _R3_FUSE = os.environ.get("DPT_R3_FUSE", "1") != "0"
 # holds in any order the operands share (the z_next roll and the domain
 # tables are re-indexed once, per-plan). The ONE place the order returns
 # to natural is the consuming coset-iNTT's input gather (kernel_fused
-# input_perm), fused into that program's first stage reads — the
-# "consumer-side fusion" follow-on noted in backend/ntt_pallas.py: ~26
+# input_perm), fused into that program's first stage reads: ~26
 # standalone O(m) bit-reversal gathers per round 3 collapse into 1.
 # 0 restores per-launch output permutation (bit-identical either way).
 _R3_BITREV = os.environ.get("DPT_R3_BITREV", "1") != "0"

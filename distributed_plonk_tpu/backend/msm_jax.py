@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..constants import FQ_MONT_R, Q_MOD, R_MOD, FR_LIMBS, FQ_LIMBS
-from . import autotune
 from . import curve_jax as CJ
 from . import field_jax as FJ
 from .field_jax import FR
@@ -50,9 +49,7 @@ from .limbs import ints_to_limbs, limbs_to_int
 
 SCALAR_BITS = 256
 
-# accepted knob values — the autotuner enumerates its candidate grid
-# from these (and from the C_CHOICES assert below), so the measured
-# space cannot drift from what the dispatch accepts
+# accepted knob values
 BUCKET_UPDATE_CHOICES = ("onehot", "put")
 KERNEL_CHOICES = ("pallas", "xla")
 C_CHOICES = (7, 8)
@@ -90,7 +87,7 @@ def _group_size(n):
     (no scatter op) per-ADD plane traffic is G-independent, so wider
     groups only amortize per-step overhead better — bounded by the fold
     work and the plane-budget cap in _group_size_batch."""
-    g = _group_max_knob(n)
+    g = int(os.environ.get("DPT_MSM_GROUP_MAX", "512"))
     if g < 1:
         g = 512
     g = 1 << (g.bit_length() - 1)  # round down to a power of two: the
@@ -118,19 +115,9 @@ _PLANE_BYTES_BUDGET = int(os.environ.get("DPT_MSM_PLANE_MB", "1536")) << 20
 _BUCKET_UPDATE = os.environ.get("DPT_BUCKET_UPDATE", "auto")
 
 
-def _group_max_knob(n=None):
-    """Per-call group cap: explicit DPT_MSM_GROUP_MAX > autotune plan
-    near n points > 512 (the shared env > plan > default resolver)."""
-    return autotune.env_or_plan("DPT_MSM_GROUP_MAX", "msm", "group_max",
-                                512, n, cast=int)
-
-
-def _use_onehot_update(n=None):
-    mode = autotune.attr_or_plan(_BUCKET_UPDATE, "auto",
-                                 "DPT_BUCKET_UPDATE", "msm",
-                                 "bucket_update", n)
-    if mode in BUCKET_UPDATE_CHOICES:
-        return mode == "onehot"
+def _use_onehot_update():
+    if _BUCKET_UPDATE in BUCKET_UPDATE_CHOICES:
+        return _BUCKET_UPDATE == "onehot"
     return jax.default_backend() == "tpu"
 
 
@@ -143,8 +130,8 @@ def _use_onehot_update(n=None):
 _PLANE_PACK = os.environ.get("DPT_PLANE_PACK", "1") != "0"
 
 
-def _use_packed_planes(n=None):
-    return _use_onehot_update(n) and _PLANE_PACK
+def _use_packed_planes():
+    return _use_onehot_update() and _PLANE_PACK
 
 
 # Bucket-accumulation kernel (DPT_MSM_KERNEL):
@@ -159,7 +146,7 @@ def _use_packed_planes(n=None):
 #   limb, but ONE shape took 387 s to compile (PR 21 chip run, libtpu
 #   0.0.34; CHANGES.md) and a cold 2^13 prove commits at three batch
 #   widths — so it is never what a device path falls into. Asking for
-#   it by name (DPT_MSM_KERNEL=pallas, or a plan cell) runs it; what
+#   it by name (DPT_MSM_KERNEL=pallas) runs it; what
 #   the compiler says then is the caller's to see. No handler
 #   substitutes the scan at run time.
 # Resolved per call (module attr, monkeypatchable) like _BUCKET_UPDATE;
@@ -169,19 +156,17 @@ def _use_packed_planes(n=None):
 _MSM_KERNEL = os.environ.get("DPT_MSM_KERNEL", "auto")
 
 
-def _use_pallas_kernel(n=None):
+def _use_pallas_kernel():
     if getattr(FJ._pallas_off, "v", False):
         return False
-    mode = autotune.attr_or_plan(_MSM_KERNEL, "auto", "DPT_MSM_KERNEL",
-                                 "msm", "kernel", n)
-    if mode not in KERNEL_CHOICES + ("auto",):
+    if _MSM_KERNEL not in KERNEL_CHOICES + ("auto",):
         raise ValueError(
-            f"DPT_MSM_KERNEL must be auto|pallas|xla, got {mode!r}")
-    return mode == "pallas"
+            f"DPT_MSM_KERNEL must be auto|pallas|xla, got {_MSM_KERNEL!r}")
+    return _MSM_KERNEL == "pallas"
 
 
-def _kernel_mode(n=None):
-    return "pallas" if _use_pallas_kernel(n) else "xla"
+def _kernel_mode():
+    return "pallas" if _use_pallas_kernel() else "xla"
 
 
 # packed-pair layout shared with field_jax (round 3's packed coset evals
@@ -246,14 +231,14 @@ def _group_size_batch(n, batch, c, signed=False, kernel=None):
     per-step overhead no longer rewards huge groups there.
 
     kernel: explicit resolved mode ('pallas'|'xla') from the caller —
-    MsmContext passes its context-width resolution so group sizing,
-    the chunk memo key, and the traced branch all agree; None resolves
-    at n (direct/mesh callers, whose traces resolve at the same n)."""
+    MsmContext passes the resolution its chunk memo key holds, so group
+    sizing, the key and the traced branch all agree; None resolves here
+    (direct/mesh callers)."""
     w = -(-SCALAR_BITS // c)  # ceil: c=7 has 37 windows, not 36
     buckets = 1 << (c - 1) if signed else 1 << c
     g = _group_size(n)
     if (kernel == "pallas") if kernel is not None \
-            else _use_pallas_kernel(n):
+            else _use_pallas_kernel():
         from . import msm_pallas
         cap = max(8, msm_pallas.plane_lanes_cap(
             buckets, _PLANE_PACK) // 8)
@@ -304,12 +289,11 @@ def _bucket_scan(ax, ay, ainf, digits, group, n_buckets, kernel=None):
     DPT_MSM_KERNEL=pallas runs the fused VMEM-resident kernel
     (msm_pallas.bucket_scan) — bit-identical planes at the same group
     width; this scan remains the parity/debug core. `kernel` pins the
-    resolved mode from the caller (MsmContext resolves at its context
-    width so the trace matches its memo key); None resolves here at the
-    local chunk width.
+    resolved mode from the caller (MsmContext's, so the trace matches
+    its memo key); None resolves here.
     """
     if (kernel == "pallas") if kernel is not None \
-            else _use_pallas_kernel(ax.shape[1]):
+            else _use_pallas_kernel():
         from . import msm_pallas
         return msm_pallas.bucket_scan(ax, ay, ainf, digits, group,
                                       n_buckets, packed=_PLANE_PACK)
@@ -359,7 +343,7 @@ def _bucket_scan_signed(ax, ay, ainf, packed, group, n_buckets=128,
     _bucket_scan.
     """
     if (kernel == "pallas") if kernel is not None \
-            else _use_pallas_kernel(ax.shape[1]):
+            else _use_pallas_kernel():
         from . import msm_pallas
         return msm_pallas.bucket_scan_signed(ax, ay, ainf, packed, group,
                                              n_buckets,
@@ -710,11 +694,9 @@ class MsmContext:
         # enough: DPT_MSM_C picks 8 (32 windows x 128 buckets, planes
         # exactly fill (8, 128) minor tiles) or 7 (37 x 64 — half the
         # plane traffic per step at +16% window-adds; A/B'd on chip,
-        # msm_c7_ab_r05.json); the autotune plan's winner applies when
-        # the knob is unset. Tiny keys keep the unsigned small-window
+        # msm_c7_ab_r05.json). Tiny keys keep the unsigned small-window
         # scan (a 16-bucket c=4 plane is layout-padded 8x otherwise).
-        self.c_batch = _c_batch_knob(self.padded_n) \
-            if self.padded_n >= 256 else self.c
+        self.c_batch = self._C_BATCH if self.padded_n >= 256 else self.c
         # wide windows run the SIGNED pipeline (half the buckets, sign
         # folded into y); both pipelines take affine bases + inf mask and
         # accumulate with complete projective adds
@@ -777,38 +759,34 @@ class MsmContext:
         f"DPT_MSM_C must be 7 or 8, got {_C_BATCH}"
 
     def _mode(self):
-        """Resolved bucket kernel for this context's width."""
-        return _kernel_mode(self.padded_n)
+        """Resolved bucket kernel."""
+        return _kernel_mode()
 
     def _chunk_key(self, nc, group):
-        """Chunk-fn/call memo key: resolved mode + the autotune plan
-        revision (autotune.cache_key) — the pallas/xla branch is taken
-        at TRACE time inside the jit, so neither an env/attr flip
-        (bench A/B, tests) nor a mid-process plan reload may reuse the
-        other configuration's executable."""
-        return autotune.cache_key(nc, group, self._mode())
+        """Chunk-fn/call memo key, resolved mode included — the
+        pallas/xla branch is taken at TRACE time inside the jit, so an
+        env/attr flip (bench A/B, tests) must not reuse the other
+        configuration's executable."""
+        return (nc, group, self._mode())
 
     def _chunk_fn(self, nc, group):
         key = self._chunk_key(nc, group)
         if key not in self._chunk_fns:
             fn = bucket_planes_batch_signed if self.signed \
                 else bucket_planes_batch
-            # kernel pinned to the CONTEXT-width resolution (the memo
-            # key above): a plan whose nearest cell at the chunk width
-            # disagrees must not make the traced branch diverge from
-            # the key, the seeded rate, and the AOT-compiled variant
+            # kernel pinned to the memo key's resolution, so the traced
+            # branch cannot diverge from the key
             self._chunk_fns[key] = FJ.named_jit(
                 "msm_bucket_scan",
                 partial(fn, group=group, kernel=self._mode()))
         return self._chunk_fns[key]
 
     def _finish_fn(self, batch):
-        key = autotune.cache_key(batch)
-        if key not in self._finish_fns:
-            self._finish_fns[key] = FJ.named_jit(
+        if batch not in self._finish_fns:
+            self._finish_fns[batch] = FJ.named_jit(
                 "msm_finish",
                 partial(finish_batch, batch=batch, signed=self.signed))
-        return self._finish_fns[key]
+        return self._finish_fns[batch]
 
     # adds/s measured from the first fenced chunk call; class-level so every
     # context on the process shares the calibration. Keyed by
@@ -821,33 +799,13 @@ class MsmContext:
 
     def _calib_key(self):
         # the fused kernel's adds/s is far from the XLA scan's: a rate
-        # latched under one kernel must not size the other's chunks —
-        # and a plan reload retires latched rates with the revision
-        return autotune.cache_key(self._platform, self.signed,
-                                  self.c_batch, self._mode())
-
-    def _plan_rate(self):
-        """The calibration plan's measured adds/s for keys near this
-        width — but only when this context actually dispatches the
-        kernel the plan measured (an env override to the other kernel
-        must not size chunks from the wrong rate). Seeding the rate
-        from the plan makes chunk shapes deterministic from the FIRST
-        call, so the AOT pass covers them and nothing recompiles at
-        serve time (the PR 3/5 chunk-shape remainder)."""
-        rate = autotune.plan_param("msm", "adds_per_s", self.padded_n)
-        if rate is None:
-            return None
-        planned = autotune.plan_param("msm", "kernel", self.padded_n)
-        if planned is not None and planned != self._mode():
-            return None
-        return float(rate)
+        # latched under one kernel must not size the other's chunks
+        return (self._platform, self.signed, self.c_batch, self._mode())
 
     def _chunk_lanes(self, B, W):
         """Current per-call point budget (1024-aligned)."""
         budget = self._CALL_ADDS
         rate = MsmContext._measured_adds_per_s.get(self._calib_key())
-        if rate is None:
-            rate = self._plan_rate()
         if rate is not None:
             budget = min(self._CALL_ADDS_MAX, int(rate * self._CALL_TARGET_S))
         return max(1024, (budget // (B * W)) & ~1023)
@@ -868,13 +826,10 @@ class MsmContext:
             fn = self._chunk_fn(nc, g)
             # calibrate once, on a WARM shape only: a first call's
             # wall-clock is dominated by XLA compilation and would wildly
-            # under-read the device rate. A plan-provided rate makes the
-            # fence unnecessary (and keeps chunk shapes pinned to what
-            # the AOT pass compiled).
+            # under-read the device rate.
             warm = self._chunk_calls.get(self._chunk_key(nc, g), 0) > 0
             calibrate = (self._calib_key() not in
                          MsmContext._measured_adds_per_s
-                         and self._plan_rate() is None
                          and nc >= 8192 and warm)
             if calibrate:
                 if acc is not None:  # drain queued async work first, or
@@ -972,7 +927,7 @@ class MsmContext:
                 lanes = pairs * g * B * W
                 if FJ._use_pallas((FQ_LIMBS, lanes)):
                     from . import field_pallas as FP
-                    tile = FP.lane_tile(lanes)
+                    tile = FP.LANE_TILE
                     mul_widths.add((lanes + (-lanes) % tile, tile))
         for Nw, tile in sorted(mul_widths):
             from . import field_pallas as FP
@@ -1095,24 +1050,6 @@ class MsmContext:
             make = lambda s: jnp.asarray(
                 digits_of_scalars(s, self.padded_n, self.c_batch))
         return self._run_batches(scalar_lists, make)
-
-
-def _c_batch_knob(n=None):
-    """Resolved batch window width: explicit DPT_MSM_C (latched into
-    MsmContext._C_BATCH, which its import-time assert already validated
-    against C_CHOICES) > autotune plan near an n-point key > 7. A plan
-    value outside C_CHOICES falls back to the default — a malformed
-    plan must never break dispatch (only explicit knobs may raise)."""
-    if "DPT_MSM_C" in os.environ or MsmContext._C_BATCH != 7:
-        # env-set, or test/harness-patched away from the built-in
-        # default: explicit wins over the plan (attr_or_plan semantics)
-        return MsmContext._C_BATCH
-    p = autotune.plan_param("msm", "c", n)
-    try:
-        c = int(p)
-    except (TypeError, ValueError):
-        return MsmContext._C_BATCH
-    return c if c in C_CHOICES else MsmContext._C_BATCH
 
 
 def _decode_totals(B, totals):

@@ -54,7 +54,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from ..constants import FQ_LIMBS
-from . import autotune
 from .curve_pallas import add_mixed_val, consts_env, fq_consts, _mod_sub
 from .field_jax import pallas_interpret, unpack_limb_pairs
 
@@ -66,17 +65,7 @@ _SKIP_BIT = 9
 
 # peak VMEM the resident bucket planes may occupy (3 coords x rows x
 # B x lanes x 4 B); the lane tile shrinks to fit
-_VMEM_MB_DEFAULT = 6
-_VMEM_MB = int(os.environ.get("DPT_MSM_PALLAS_VMEM_MB",
-                              str(_VMEM_MB_DEFAULT)))
-
-
-def _vmem_mb():
-    """Per-call plane budget: env/patched attr > autotune plan winner
-    > default (same precedence as ntt_pallas._vmem_mb)."""
-    return int(autotune.attr_or_plan(
-        _VMEM_MB, _VMEM_MB_DEFAULT, "DPT_MSM_PALLAS_VMEM_MB",
-        "msm", "vmem_mb", None, cast=int))
+_VMEM_MB = int(os.environ.get("DPT_MSM_PALLAS_VMEM_MB", "6"))
 
 
 def plane_lanes_cap(n_buckets, packed):
@@ -91,7 +80,7 @@ def plane_lanes_cap(n_buckets, packed):
     per_lane = (6 * rows * n_buckets * 4   # planes: scratch + out window
                 + 4 * FQ_LIMBS * 6 * 4     # mul scratch t_ref
                 + 4)                       # op words
-    cap = (_vmem_mb() << 20) // per_lane
+    cap = (_VMEM_MB << 20) // per_lane
     return max(8, 1 << max(3, cap.bit_length() - 1))
 
 

@@ -55,96 +55,23 @@ from jax import lax
 
 from ..constants import R_MOD, FR_GENERATOR, FR_LIMBS, FR_MONT_R
 from ..fields import fr_inv, fr_root_of_unity
-from . import autotune
 from . import field_jax as FJ
 from .field_jax import FR
 from .limbs import ints_to_limbs, limbs_to_ints
 
-# the values the resolvers below accept — the autotuner enumerates its
-# candidate grid from these, so the measured space cannot drift from
-# what the kernels dispatch on
 RADIX_CHOICES = (2, 4)
-KERNEL_CHOICES = ("pallas", "xla")
 
 
-def _active_radix(radix=None, n=None):
+def _active_radix(radix=None):
     """Resolve the stage radix: explicit argument > DPT_NTT_RADIX (2|4)
-    > the active autotune plan's winner near domain size n > 4. Read
-    per call — not latched at import — so the radix-2 path stays
-    selectable for parity debugging without rebuilding plans (mirrors
-    msm_jax's DPT_BUCKET_UPDATE knob)."""
+    > 4. Read per call — not latched at import — so the radix-2 path
+    stays selectable for parity debugging without rebuilding plans
+    (mirrors msm_jax's DPT_BUCKET_UPDATE knob)."""
     if radix is None:
-        env = os.environ.get("DPT_NTT_RADIX")
-        if env is not None:
-            radix = int(env)
-        else:
-            p = autotune.plan_param("ntt", "radix", n)
-            try:
-                radix = int(p)
-            except (TypeError, ValueError):
-                radix = 4
-            if radix not in RADIX_CHOICES:
-                # a malformed plan value falls back to the default —
-                # only explicit knobs (arg/env, below) may raise
-                radix = 4
+        radix = int(os.environ.get("DPT_NTT_RADIX", "4"))
     if radix not in RADIX_CHOICES:
         raise ValueError(f"NTT radix must be 2 or 4, got {radix!r}")
     return radix
-
-
-# Stage-core kernel (DPT_NTT_KERNEL), mirroring DPT_MSM_KERNEL:
-#   pallas: the fused multi-stage VMEM-resident kernel (ntt_pallas) —
-#     log2(rows) butterfly stages per HBM round trip instead of the
-#     radix-4 scan's two; coset pre-scale and inverse post-scales fused
-#     into the first/last group.
-#   xla: the radix-4/radix-2 lax.scan cores — the path every chip run to
-#     date has proved with.
-#   auto (default): xla on every platform. The fused kernel has never
-#     compiled on the v5e (PR 21: Mosaic refuses it, and a variant that
-#     got past the refusal did not finish compiling — ntt_pallas
-#     docstring, CHANGES.md), so it is never what a device path falls
-#     into: asking for it by name (DPT_NTT_KERNEL=pallas, the `kernel`
-#     argument, or a plan cell) runs it and raises what the compiler
-#     says. No handler substitutes the XLA core at run time. It goes back
-#     into `auto` only by a platform or shape rule with a measured cell
-#     on each side (ROADMAP).
-# field_jax.pallas_disabled() / mesh.pallas_guard override even a forced
-# "pallas" — a pallas_call has no GSPMD partitioning rule, so sharded
-# operands outside shard_map must never meet one.
-_NTT_KERNEL = os.environ.get("DPT_NTT_KERNEL", "auto")
-
-
-def _use_pallas_kernel(n=None):
-    if getattr(FJ._pallas_off, "v", False):
-        return False
-    mode = _NTT_KERNEL
-    if mode == "auto":
-        # a plan winner resolves the auto default; an explicit (env or
-        # test-patched) DPT_NTT_KERNEL above stays the override
-        p = autotune.plan_param("ntt", "kernel", n)
-        if p in KERNEL_CHOICES:
-            mode = p
-    if mode in KERNEL_CHOICES:
-        return mode == "pallas"
-    if mode != "auto":
-        raise ValueError(
-            f"DPT_NTT_KERNEL must be auto|pallas|xla, got {_NTT_KERNEL!r}")
-    return False
-
-
-def _active_kernel(kernel=None, n=None):
-    """Resolve the stage-core kernel: explicit argument > DPT_NTT_KERNEL
-    > the active autotune plan near domain size n > platform default.
-    Read per call like _active_radix; the pallas_disabled guard wins
-    even over an explicit 'pallas' (same invariant as msm_jax)."""
-    if kernel is not None:
-        if kernel not in KERNEL_CHOICES:
-            raise ValueError(
-                f"NTT kernel must be 'pallas' or 'xla', got {kernel!r}")
-        if kernel == "pallas" and getattr(FJ._pallas_off, "v", False):
-            return "xla"
-        return kernel
-    return "pallas" if _use_pallas_kernel(n) else "xla"
 
 
 def _mont_table(xs):
@@ -343,20 +270,12 @@ def batched_butterflies(v, perm, exps, pow_tab):
 
 def run_stages(v, consts):
     """Shared stage core: (16, B, n) natural-order Montgomery rows ->
-    (i)NTT in natural order (1/n scaling NOT included). The kernel and
-    radix are carried by the table set (`NttPlan.core_consts`): pallas
-    tables hold "pg{g}s{t}" fused-stage twiddle blocks, radix-4 tables
+    (i)NTT in natural order (1/n scaling NOT included). The radix is
+    carried by the table set (`NttPlan.core_consts`): radix-4 tables
     hold "exps4" (+ "fix_exps" for odd log2(n)), radix-2 tables hold
     "exps". Single-device kernels, the mesh 4-step NTT stages, and the
     fleet panel kernels all run their butterflies through this entry
-    point, so one DPT_NTT_KERNEL / DPT_NTT_RADIX flip covers every path.
-    The pallas dispatch re-checks the guard at trace time: inside
-    pallas_disabled()/pallas_guard the XLA tables (always present) run
-    instead — bit-identical either way."""
-    if _use_pallas_kernel(v.shape[2]) and any(k.startswith("pg")
-                                              for k in consts):
-        from . import ntt_pallas
-        return ntt_pallas.run_groups(v, consts)[:, :, consts["perm"]]
+    point, so one DPT_NTT_RADIX flip covers every path."""
     if "exps4" in consts:
         return _radix4_core(v, consts)[:, :, consts["perm"]]
     return batched_butterflies(v, consts["perm"], consts["exps"],
@@ -401,44 +320,18 @@ class NttPlan:
         self.inv_coset_tab = _mont_table(_powers(fr_inv(g), n, start=n_inv))
         self.n_inv_tab = _mont_table([n_inv])
         self._fns = {}
-        self._pallas_tabs = {}
 
     def _effective_radix(self, radix=None):
         """Active radix for this plan: n <= 2 has no radix-4 stage, so the
         radix-2 body covers it (bit-identical either way)."""
-        radix = _active_radix(radix, n=self.n)
+        radix = _active_radix(radix)
         return radix if self.exps4 is not None else 2
 
-    def _effective_kernel(self, kernel=None):
-        """Active stage-core kernel for this plan: n <= 2 has no fused
-        group schedule, so the XLA body covers it (like radix)."""
-        if self.log_n < 2:
-            return "xla"
-        return _active_kernel(kernel, n=self.n)
-
-    def _pallas_consts(self, inverse):
-        """Fused-group twiddle VALUE tables (host numpy, cached per
-        schedule — the schedule moves with the VMEM/group-cap knobs)."""
-        from . import ntt_pallas
-
-        schedule = ntt_pallas.plan_schedule(self.log_n)
-        # revision-keyed like _fns: a plan reload may move the schedule
-        # knobs, and stale twiddle blocks must not outlive it
-        key = autotune.cache_key(inverse, schedule)
-        if key not in self._pallas_tabs:
-            pow_tab = self.pow_inv if inverse else self.pow_fwd
-            self._pallas_tabs[key] = ntt_pallas.group_tables(
-                self.log_n, self.exps, pow_tab, schedule)
-        return self._pallas_tabs[key]
-
-    def core_consts(self, inverse=False, radix=None, kernel=None):
-        """HOST (numpy) table set for `run_stages` at the active radix
-        and kernel. Callers (mesh shard_map consts, fleet panel kernels)
-        place these on device / build PartitionSpecs per entry; every
-        entry is replicated-safe (O(n) tables, no per-shard content).
-        Under the pallas kernel the fused-stage twiddle blocks ride
-        ALONGSIDE the XLA tables — run_stages falls back to the XLA body
-        whenever the guard disables pallas at trace time."""
+    def core_consts(self, inverse=False, radix=None):
+        """HOST (numpy) table set for `run_stages` at the active radix.
+        Callers (mesh shard_map consts, fleet panel kernels) place these
+        on device / build PartitionSpecs per entry; every entry is
+        replicated-safe (O(n) tables, no per-shard content)."""
         pow_tab = self.pow_inv if inverse else self.pow_fwd
         if self._effective_radix(radix) == 4:
             out = {"perm": self.perm, "exps4": self.exps4, "pow": pow_tab}
@@ -446,55 +339,26 @@ class NttPlan:
                 out["fix_exps"] = self.fix_exps
         else:
             out = {"perm": self.perm, "exps": self.exps, "pow": pow_tab}
-        if self._effective_kernel(kernel) == "pallas":
-            out.update(self._pallas_consts(inverse))
         return out
 
-    def _pallas_post_tab(self, coset):
-        """Inverse scales reordered for pre-permutation application in
-        the LAST fused group: s = post[perm] (bit reversal is an
-        involution), laid out (16, rows_last, M_last) to match the
-        kernel's in-VMEM block orientation."""
-        from . import ntt_pallas
-
-        schedule = ntt_pallas.plan_schedule(self.log_n)
-        rows = 1 << schedule[-1][1]
-        m_cols = self.n // rows
-        post = (self.inv_coset_tab if coset
-                else np.broadcast_to(self.n_inv_tab, (FR_LIMBS, self.n)))
-        s = post[:, self.perm]
-        return np.ascontiguousarray(
-            s.reshape(FR_LIMBS, m_cols, rows).swapaxes(1, 2))
-
-    def _kernel_consts(self, inverse, coset, radix, kernel="xla"):
+    def _kernel_consts(self, inverse, coset, radix):
         """Traced-argument tables for one compiled kernel variant."""
         consts = {k: jnp.asarray(v)
-                  for k, v in self.core_consts(inverse, radix,
-                                               kernel=kernel).items()}
+                  for k, v in self.core_consts(inverse, radix).items()}
         if coset and not inverse:
             consts["pre"] = jnp.asarray(self.coset_tab)
-            if kernel == "pallas":
-                # the pallas first group consumes the SAME coset table,
-                # viewed (16, rows, M) — a reshape, not a new precompute
-                consts["ppre"] = consts["pre"]
         if inverse:
             consts["post"] = jnp.asarray(
                 self.inv_coset_tab if coset else self.n_inv_tab)
-            if kernel == "pallas":
-                consts["ppost"] = jnp.asarray(self._pallas_post_tab(coset))
         return consts
 
-    def _apply_batched(self, v, consts, radix, kernel="xla",
-                       defer_perm=False):
+    def _apply_batched(self, v, consts, radix, defer_perm=False):
         """(16, B, n) Montgomery rows -> full (i)(coset)NTT: butterflies +
-        output permutation + fused scales, radix/kernel-selected. The
-        pallas path runs the fused multi-stage groups (coset pre-scale in
-        the first group, inverse scales in the last) and finishes with
-        the bit-reversal gather; the radix-4 path peels the first/last
-        stages so the coset tables ride the first butterfly and the perm
-        gather + inverse scales fuse with the last one; the radix-2 path
-        keeps the historical standalone pre/post table multiplies
-        (parity/debug reference).
+        output permutation + fused scales, radix-selected. The radix-4
+        path peels the first/last stages so the coset tables ride the
+        first butterfly and the perm gather + inverse scales fuse with
+        the last one; the radix-2 path keeps the historical standalone
+        pre/post table multiplies (parity/debug reference).
 
         defer_perm=True (forward launches only) SKIPS the output
         bit-reversal gather: the result stays in constant-geometry
@@ -503,10 +367,6 @@ class NttPlan:
         and pays one gather at the consuming iNTT's input instead of one
         standalone O(n) pass per FFT launch (DPT_R3_BITREV)."""
         n = self.n
-        if kernel == "pallas" and _active_kernel("pallas") == "pallas":
-            from . import ntt_pallas
-            v = ntt_pallas.run_groups(v, consts)
-            return v if defer_perm else v[:, :, consts["perm"]]
         if radix == 4:
             v = _radix4_core(v, consts, coset_pre="pre" in consts)
         else:
@@ -523,35 +383,30 @@ class NttPlan:
             v = FJ.mont_mul(FR, v, post[:, None, :])
         return v
 
-    def kernel(self, inverse=False, coset=False, boundary="mont", radix=None,
-               kernel=None):
+    def kernel(self, inverse=False, coset=False, boundary="mont", radix=None):
         """Jitted (16, n) -> (16, n) kernel.
 
         boundary="mont": input/output in Montgomery form (device-resident
         pipelines). boundary="plain": canonical-form input/output (host
         round-trips); conversion is fused into the same XLA program.
 
-        The O(n) tables (permutation, exponents, power table, coset scales,
-        fused-stage twiddle blocks) are passed as traced arguments, not
-        baked-in constants, so compiled programs and persistent-cache
-        entries stay small. `kernel` overrides DPT_NTT_KERNEL like `radix`
-        overrides DPT_NTT_RADIX; the memo is keyed on the resolved mode
-        plus the autotune plan revision (autotune.cache_key), so a
-        mid-process plan reload can never serve a stale compiled
-        variant.
+        The O(n) tables (permutation, exponents, power table, coset
+        scales) are passed as traced arguments, not baked-in constants, so
+        compiled programs and persistent-cache entries stay small. `radix`
+        overrides DPT_NTT_RADIX; the memo is keyed on the resolved radix,
+        so an env flip never reuses the other radix's executable.
         """
         radix = self._effective_radix(radix)
-        kmode = self._effective_kernel(kernel)
-        key = autotune.cache_key(inverse, coset, boundary, radix, kmode)
+        key = (inverse, coset, boundary, radix)
         if key not in self._fns:
             plain = boundary == "plain"
-            consts = self._kernel_consts(inverse, coset, radix, kmode)
+            consts = self._kernel_consts(inverse, coset, radix)
 
             def fn(v, consts):
                 if plain:
                     v = FJ.to_mont(FR, v)
-                v = self._apply_batched(v[:, None, :], consts, radix,
-                                        kmode)[:, 0, :]
+                v = self._apply_batched(v[:, None, :], consts,
+                                        radix)[:, 0, :]
                 if plain:
                     v = FJ.from_mont(FR, v)
                 return v
@@ -561,26 +416,24 @@ class NttPlan:
         return lambda v: fn(v, consts)
 
     def kernel_batch(self, inverse=False, coset=False, radix=None,
-                     kernel=None, defer_perm=False):
+                     defer_perm=False):
         """Jitted (16, B, n) -> (16, B, n) Montgomery-boundary kernel: B
         polynomials in ONE launch (the prover's round-1/round-3 NTT batches;
         the reference fans these out as concurrent RPCs,
         dispatcher2.rs:294-321,382-414 — on device they are one program).
-        Compiled once per (mode, radix, kernel, B). defer_perm=True emits
+        Compiled once per (mode, radix, B). defer_perm=True emits
         the result in bit-reversed order (forward only — the consumer
         absorbs the permutation; see _apply_batched)."""
         radix = self._effective_radix(radix)
-        kmode = self._effective_kernel(kernel)
         if defer_perm and inverse:
             raise ValueError("defer_perm is forward-only")
-        key = autotune.cache_key(
-            inverse, coset, "batch_noperm" if defer_perm else "batch",
-            radix, kmode)
+        key = (inverse, coset, "batch_noperm" if defer_perm else "batch",
+               radix)
         if key not in self._fns:
-            consts = self._kernel_consts(inverse, coset, radix, kmode)
+            consts = self._kernel_consts(inverse, coset, radix)
 
             def fn(v, consts):
-                return self._apply_batched(v, consts, radix, kmode,
+                return self._apply_batched(v, consts, radix,
                                            defer_perm=defer_perm)
 
             self._fns[key] = (FJ.named_jit("ntt_batch", fn), consts)
@@ -588,7 +441,7 @@ class NttPlan:
         return lambda v: fn(v, consts)
 
     def kernel_fused(self, inverse=False, coset=False, *, key,
-                     prologue=None, epilogue=None, radix=None, kernel=None,
+                     prologue=None, epilogue=None, radix=None,
                      input_perm=False, defer_perm=False):
         """Jitted Montgomery-boundary batch kernel with caller-supplied
         pointwise stages fused into the SAME program:
@@ -617,18 +470,16 @@ class NttPlan:
         iNTT program's first stage reads instead of a standalone pass
         per producer launch."""
         radix = self._effective_radix(radix)
-        kmode = self._effective_kernel(kernel)
-        ck = autotune.cache_key("fused", key, inverse, coset, radix, kmode,
-                                input_perm, defer_perm)
+        ck = ("fused", key, inverse, coset, radix, input_perm, defer_perm)
         if ck not in self._fns:
-            consts = self._kernel_consts(inverse, coset, radix, kmode)
+            consts = self._kernel_consts(inverse, coset, radix)
 
             def fn(pro_args, epi_args, consts):
                 v = prologue(*pro_args) if prologue is not None \
                     else pro_args[0]
                 if input_perm:
                     v = v[:, :, consts["perm"]]
-                v = self._apply_batched(v, consts, radix, kmode,
+                v = self._apply_batched(v, consts, radix,
                                         defer_perm=defer_perm)
                 if epilogue is not None:
                     return epilogue(v, *epi_args)
@@ -650,53 +501,44 @@ class NttPlan:
                                                 tuple(epi_args), consts)
 
     def traced_kernel(self, inverse=False, coset=False, boundary="mont",
-                      radix=None, batch=False, kernel=None,
-                      defer_perm=False):
+                      radix=None, batch=False, defer_perm=False):
         """(jitted fn, consts dict) for one kernel variant — the raw
         pair behind `kernel`/`kernel_batch`'s memo. The static verifier
         (analysis/registry.py) traces `fn(v, consts)` through
-        jax.make_jaxpr to interval-check the whole stage pipeline
-        (including the pallas_call kernel jaxprs under kernel="pallas");
-        AOT tooling can reuse it for explicit lower()/compile() too."""
+        jax.make_jaxpr to interval-check the whole stage pipeline; AOT
+        tooling can reuse it for explicit lower()/compile() too."""
         radix = self._effective_radix(radix)
-        kmode = self._effective_kernel(kernel)
         if batch:
             if boundary != "mont":
                 raise ValueError(
                     "batch kernels are Montgomery-boundary only")
-            self.kernel_batch(inverse, coset, radix=radix, kernel=kmode,
+            self.kernel_batch(inverse, coset, radix=radix,
                               defer_perm=defer_perm)
-            key = autotune.cache_key(
-                inverse, coset, "batch_noperm" if defer_perm else "batch",
-                radix, kmode)
+            key = (inverse, coset,
+                   "batch_noperm" if defer_perm else "batch", radix)
         elif defer_perm:
             raise ValueError("defer_perm needs batch=True")
         else:
-            self.kernel(inverse, coset, boundary=boundary, radix=radix,
-                        kernel=kmode)
-            key = autotune.cache_key(inverse, coset, boundary, radix, kmode)
+            self.kernel(inverse, coset, boundary=boundary, radix=radix)
+            key = (inverse, coset, boundary, radix)
         return self._fns[key]
 
     def aot_compile(self, batch_sizes=(), boundaries=("mont", "plain"),
-                    radix=None, kernel=None):
+                    radix=None):
         """Ahead-of-time lower + compile every (inverse, coset) kernel
-        variant for this domain at the ACTIVE radix and kernel mode, plus
-        `kernel_batch` at the given batch widths, WITHOUT running anything
-        — `jit.lower(shapes).compile()` on ShapeDtypeStructs.
+        variant for this domain at the ACTIVE radix, plus `kernel_batch`
+        at the given batch widths, WITHOUT running anything —
+        `jit.lower(shapes).compile()` on ShapeDtypeStructs.
 
         The executables land in the persistent compilation cache
         (field_jax.configure_compile_cache), which is the point: a warmup
         process can pre-bake a store-owned cache so every later server
-        start compiles nothing for this shape. Mode-aware like
-        MsmContext.aot_compile: under DPT_NTT_KERNEL=pallas the lowered
-        programs ARE the fused multi-stage Mosaic kernels, so
-        `warm_stages` / `scripts/warmup.py --aot` pre-bake those too.
-        Returns {"compiled": k, "failed": j, "errors": [...], "radix": r,
-        "kernel": mode}; `errors` holds what the compiler said for every
-        variant counted in `failed`.
+        start compiles nothing for this shape.
+        Returns {"compiled": k, "failed": j, "errors": [...], "radix": r};
+        `errors` holds what the compiler said for every variant counted
+        in `failed`.
         """
         radix = self._effective_radix(radix)
-        kmode = self._effective_kernel(kernel)
         compiled = 0
         errors = []
         v_spec = jax.ShapeDtypeStruct((FR_LIMBS, self.n), jnp.uint32)
@@ -714,31 +556,25 @@ class NttPlan:
         for inverse in (False, True):
             for coset in (False, True):
                 for boundary in boundaries:
-                    self.kernel(inverse, coset, boundary=boundary,
-                                radix=radix, kernel=kmode)
-                    fn, consts = self._fns[autotune.cache_key(
-                        inverse, coset, boundary, radix, kmode)]
+                    fn, consts = self.traced_kernel(
+                        inverse, coset, boundary=boundary, radix=radix)
                     aot(fn, consts, v_spec)
                 for b in batch_sizes:
-                    self.kernel_batch(inverse, coset, radix=radix,
-                                      kernel=kmode)
-                    fn, consts = self._fns[autotune.cache_key(
-                        inverse, coset, "batch", radix, kmode)]
+                    fn, consts = self.traced_kernel(
+                        inverse, coset, radix=radix, batch=True)
                     aot(fn, consts,
                         jax.ShapeDtypeStruct((FR_LIMBS, b, self.n),
                                              jnp.uint32))
         return {"compiled": compiled, "failed": len(errors),
-                "errors": errors, "radix": radix, "kernel": kmode}
+                "errors": errors, "radix": radix}
 
     # --- host-boundary convenience (int lists, zero-padded to n) -------------
 
-    def run_ints(self, values, inverse=False, coset=False, radix=None,
-                 kernel=None):
+    def run_ints(self, values, inverse=False, coset=False, radix=None):
         assert len(values) <= self.n
         padded = list(values) + [0] * (self.n - len(values))
         v = jnp.asarray(ints_to_limbs(padded, FR_LIMBS))
-        out = self.kernel(inverse, coset, boundary="plain", radix=radix,
-                          kernel=kernel)(v)
+        out = self.kernel(inverse, coset, boundary="plain", radix=radix)(v)
         return limbs_to_ints(np.asarray(out))
 
 

@@ -21,11 +21,8 @@ Kernel dispatch and device tuning (backend/, parallel/):
     DPT_MUL_MXU               pallas mul: use the MXU matmul core (0)
     DPT_MUL_LAZY              pallas mul: lazy-carry accumulation (1)
     DPT_CURVE_ADD             curve add kernel: xla|pallas (xla)
-    DPT_NTT_KERNEL            NTT kernel: auto|xla|pallas (auto)
-    DPT_NTT_RADIX             force the NTT radix (unset = auto)
+    DPT_NTT_RADIX             NTT stage radix: 2|4 (4)
     DPT_NTT_BATCH             NTT batch width for *_many paths (8)
-    DPT_NTT_PALLAS_VMEM_MB    pallas NTT VMEM budget in MB
-    DPT_NTT_PALLAS_ROWS       pallas NTT rows per grid step
     DPT_R3_FUSE               fuse the round-3 quotient pipeline (1)
     DPT_R3_BITREV             consumer-side bit-reversal fusion (1)
     DPT_QUOT_SLICE            round-3 quotient eval slice length (2^20)
@@ -36,7 +33,7 @@ Kernel dispatch and device tuning (backend/, parallel/):
     DPT_MSM_C                 MSM window bits (7)
     DPT_MSM_BATCH             MSM scalar batch width (8)
     DPT_MSM_JOB_BATCH         MSM jobs folded per device dispatch (16)
-    DPT_MSM_GROUP_MAX         max MSM group size (autotune-plan override)
+    DPT_MSM_GROUP_MAX         max MSM group size (512)
     DPT_MSM_PLANE_MB          bucket-plane HBM budget in MB (1536)
     DPT_MSM_PALLAS_VMEM_MB    pallas MSM VMEM budget in MB
     DPT_MSM_CALL_ADDS         target bucket adds per device call (8e6)
@@ -47,10 +44,6 @@ Kernel dispatch and device tuning (backend/, parallel/):
     DPT_FIXED_BASE_CHUNK      fixed-base table build chunk size
     DPT_MESH_MIN_LOCAL        min per-device rows before mesh sharding (1024)
     DPT_MESH_LEASE            lease mesh backends to the pool (0)
-    DPT_AUTOTUNE              calibration plan mode: load|run|off (load)
-    DPT_AUTOTUNE_BUDGET_S     autotune sweep wall-clock budget (120)
-    DPT_AUTOTUNE_SHAPES       comma list of shapes to calibrate
-    DPT_AUTOTUNE_INTERPRET    allow pallas interpret-mode candidates
     DPT_PALLAS_INTERPRET      run Pallas kernels interpreted: tests only (0)
     DPT_JAX_CACHE_DIR         fleet worker's compile-cache dir (--store)
     DPT_JAX_TRACE             jax.profiler span annotations on hot paths

@@ -163,12 +163,8 @@ class MeshMsmContext:
 
     def _chunk_fn(self, jc, group, B):
         """shard_map'd program: per-device bucket planes on a jc-wide local
-        chunk, then cross-device all_gather + fold -> replicated planes.
-        Key carries the autotune plan revision (the traced scan resolves
-        the kernel branch per call): a mid-process plan reload must not
-        serve a program traced under the previous plan."""
-        from ..backend import autotune
-        key = autotune.cache_key(jc, group, B)
+        chunk, then cross-device all_gather + fold -> replicated planes."""
+        key = (jc, group, B)
         if key not in self._chunk_fns:
             scan = (bucket_planes_batch_signed if self.signed
                     else bucket_planes_batch)

@@ -33,7 +33,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..constants import R_MOD, FR_GENERATOR, FR_LIMBS
 from ..fields import fr_inv, fr_root_of_unity
-from ..backend import autotune
 from ..backend import field_jax as FJ
 from ..backend.field_jax import FR
 from ..backend import ntt_jax
@@ -99,26 +98,20 @@ class MeshNttPlan:
 
     def kernel(self, inverse=False, coset=False, boundary="mont"):
         """Compiled (16, n) -> (16, n) mesh program for one mode (at the
-        active DPT_NTT_RADIX and DPT_NTT_KERNEL — part of the cache key,
-        like the single-device kernels; under the pallas kernel the
-        per-shard run_stages calls pick up the fused multi-stage kernel
-        unchanged, and pallas_guard falls them back to the XLA tables on
-        a non-TPU mesh at trace time)."""
-        key = autotune.cache_key(
-            inverse, coset, boundary, ntt_jax._active_radix(n=self.n),
-            ntt_jax._active_kernel(n=self.n))
-        # can the TRACED body contain a pallas_call — the fused NTT
-        # kernel, or the fused multiplier the XLA stage cores dispatch
-        # for wide shapes on a TPU? Resolve under the same guard the
-        # trace runs under (pallas_guard disables both for a non-TPU
-        # mesh), so check_vma below is only relaxed for programs that
-        # can genuinely contain one
-        with pallas_guard(self.mesh):
-            pallas_active = (ntt_jax._active_kernel() == "pallas"
-                             or FJ.pallas_mul_possible())
+        active DPT_NTT_RADIX — part of the cache key, like the
+        single-device kernels)."""
+        key = (inverse, coset, boundary, ntt_jax._active_radix())
         if key in self._fns:
             fn, consts = self._fns[key]
             return lambda v: fn(v, consts)
+        # can the TRACED body contain a pallas_call — the fused
+        # multiplier the stage cores dispatch for wide shapes on a TPU?
+        # Resolve under the same guard the trace runs under
+        # (pallas_guard disables it for a non-TPU mesh), so check_vma
+        # below is only relaxed for programs that can genuinely contain
+        # one
+        with pallas_guard(self.mesh):
+            pallas_active = FJ.pallas_mul_possible()
 
         n, r, c = self.n, self.r, self.c
         d = self.mesh.devices.size

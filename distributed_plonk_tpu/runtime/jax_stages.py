@@ -17,7 +17,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..backend import autotune
 from ..backend import ntt_jax
 from ..backend import field_jax as FJ
 from ..backend.field_jax import FR
@@ -52,13 +51,9 @@ class StageKernels:
         return FJ.from_mont(FR, v)
 
     def _plan_consts(self, size, inverse):
-        # keyed on the active radix AND kernel (DPT_NTT_KERNEL): pallas
-        # table sets carry the fused-stage twiddle blocks alongside the
-        # XLA tables, so the fleet panels follow the same dispatch knob
-        # as the single-device and mesh paths
-        key = autotune.cache_key(
-            "plan", size, inverse, ntt_jax._active_radix(n=size),
-            ntt_jax._active_kernel(n=size))
+        # keyed on the active radix: the fleet panels follow the same
+        # DPT_NTT_RADIX knob as the single-device and mesh paths
+        key = ("plan", size, inverse, ntt_jax._active_radix())
         if key not in self._tables:
             plan = ntt_jax.get_plan(size)
             self._tables[key] = {

@@ -794,24 +794,6 @@ def _make_store(store_dir):
     return ArtifactStore(store_dir)
 
 
-def _load_calibration(store, mode=None):
-    """Adopt the store's kernel-calibration plan for this machine
-    (store/calibration.py, DPT_AUTOTUNE=load|run|off; default load).
-    Import-light and jax-free on the load path, so python-backend
-    workers pay nothing. Never fatal: a worker without a plan (or with a
-    broken one) serves with the built-in kernel defaults. `mode`
-    overrides DPT_AUTOTUNE (serve_joined pins its pre-sync probe to
-    'load'). Returns the pickup report (or None)."""
-    if store is None:
-        return None
-    from ..store import calibration
-    try:
-        return calibration.load_or_run(store, mode=mode)
-    except Exception:  # noqa: BLE001 - calibration is an accelerator,
-        # never a startup gate
-        return None
-
-
 def _run_server(listener, state, ready_event=None):
     """Accept loop until a SHUTDOWN frame lands."""
     if ready_event is not None:
@@ -845,7 +827,6 @@ def serve(index, config, backend_name="python", ready_event=None,
     # the backend first would configure the cache elsewhere and leave
     # this worker with zero jaxcache:* entries to serve warm-rejoiners
     store = _make_store(store_dir)
-    _load_calibration(store)
     olog.configure_from_env(proc=f"worker/{index}")
     state = WorkerState(_make_backend(backend_name), config=config, me=index,
                         store=store)
@@ -870,14 +851,7 @@ def serve_joined(join_addr, listen_addr=("127.0.0.1", 0),
     port = port or native.listener_port(listener)
     reply = membership.join_fleet(join_addr[0], join_addr[1], host, port,
                                   store=store_dir is not None)
-    # adopt a locally present plan immediately — but LOAD only: under
-    # DPT_AUTOTUNE=run a fresh joiner must not burn its startup on a
-    # full local measure pass when the warm sync below may pull this
-    # fingerprint's plan from a roster peer for free (the post-sync
-    # pickup keeps the configured mode, so a genuinely plan-less fleet
-    # still calibrates)
     store = _make_store(store_dir)
-    _load_calibration(store, mode="load")
     olog.configure_from_env(proc=f"worker/{reply['index']}")
     state = WorkerState(_make_backend(backend_name),
                         config=NetworkConfig(reply["workers"]),
@@ -896,13 +870,6 @@ def serve_joined(join_addr, listen_addr=("127.0.0.1", 0),
         if store is not None and peers:
             stats = store_remote.warm_sync(
                 store, [(h, int(p)) for h, p in peers])
-            # the sync may have just pulled this fingerprint's autotune:
-            # plan from a roster peer (WARM_SYNC_PREFIXES) — adopt it so
-            # a replacement worker dispatches the calibrated kernels
-            # without ever measuring locally
-            from ..backend import autotune as _autotune
-            if _autotune.active_plan() is None:
-                _load_calibration(store)
         state.warm = stats
         olog.emit("worker", "warm_rejoin", worker=state.me, **{
             k: v for k, v in stats.items()

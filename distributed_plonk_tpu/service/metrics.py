@@ -267,31 +267,6 @@ runtime/health.py, service/pool.py — the SDC defense):
                                              journal/client by a failed
                                              self-verify (job re-proved)
 
-Kernel-autotune vocabulary (backend/autotune.py, store/calibration.py —
-the measured kernel-dispatch plan, ISSUE 14):
-    autotune_runs                            calibration measure passes
-                                             started (mode=run on a
-                                             plan-less store)
-    autotune_cells                           (kind, domain-size) cells
-                                             decided by a pass
-    autotune_measure_runs                    candidate configurations
-                                             measured (incl. the parity
-                                             reference per cell)
-    autotune_candidate_errors                candidates that failed to
-                                             build/trace/run (skipped)
-    autotune_parity_rejects                  fast-but-WRONG candidates
-                                             rejected by the bit-identity
-                                             gate (never adopted)
-    autotune_run_s (histogram)               wall-clock per measure pass
-    autotune_plan_stores / autotune_plan_loads  plan artifacts persisted
-                                             to / adopted from the store
-    autotune_plan_source (gauge)             off|none|store|fresh — where
-                                             this process's plan came from
-    autotune_plan_cells (gauge)              cells in the active plan
-    autotune_plan_revision (gauge)           process-wide plan revision
-                                             (bumps on every reload; memo
-                                             caches key on it)
-
 Tracing vocabulary (trace.py, service/pool.py, server.py --obs-port):
     trace_spans_recorded                     spans folded into finished
                                              jobs' merged timelines

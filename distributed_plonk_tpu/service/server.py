@@ -134,10 +134,8 @@ class ProofService:
             self.queue, self.pool, self.metrics, buckets=self.buckets,
             max_batch=max_batch, devices=devices,
             mesh_backend_factory=mesh_backend_factory)
-        # kernel-calibration pickup report (store/calibration.py), filled
-        # by start(): {"source": off|none|store|fresh, ...}. Without a
-        # store (or DPT_AUTOTUNE=off) no plan is loaded and every kernel
-        # path keeps the built-in defaults.
+        # constant: benchmark/lib/harness.py reads svc.autotune for its
+        # "service" line (debt: ROADMAP Queue 3)
         self.autotune = {"source": "off"}
         # fleet observability (obs/fleet.py): attach_fleet() arms it —
         # the scraper aggregates every roster member's METRICS_FETCH
@@ -634,24 +632,7 @@ class ProofService:
 
     def start(self):
         """Start scheduler + listener threads; returns self. With port=0
-        an ephemeral port is chosen and published as `self.port`.
-
-        Kernel-calibration pickup runs FIRST (store/calibration.py,
-        DPT_AUTOTUNE=load|run|off): a calibrated store's plan is adopted
-        before any job can trace a kernel, so a second service start
-        reaches its first proof with zero measurement runs and zero
-        kernel compiles at the calibrated shapes (the plan pins the
-        dispatch, the store-synced persistent compile cache holds the
-        winners' executables)."""
-        if self.store is not None:
-            from ..store import calibration
-            try:
-                self.autotune = calibration.load_or_run(
-                    self.store, metrics=self.metrics)
-            except Exception as e:  # noqa: BLE001 - calibration is an
-                # accelerator: a broken plan/measure pass must never
-                # stop the service from serving with defaults
-                self.autotune = {"source": "error", "error": repr(e)}
+        an ephemeral port is chosen and published as `self.port`."""
         self._recover()
         self.scheduler.start()
         self._listener = native.Listener(self.host, self.port)

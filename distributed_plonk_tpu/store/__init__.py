@@ -11,14 +11,10 @@ the dominant serving cost at scale):
                     element for element)
     warmstart.py    store-owned JAX persistent-compile-cache dir + AOT
                     stage precompilation per shape bucket
-    calibration.py  kernel-autotune plan artifacts (backend/autotune.py
-                    winners keyed by machine fingerprint): load_or_run
-                    is the service/worker startup entry point
 
 Consumers: service.scheduler.BucketCache (memory -> disk -> build tiers),
-the WARMUP wire tag (service/server.py), scripts/warmup.py +
-scripts/autotune.py, bench.py's cold-vs-warm service round trip,
-tests/test_store.py + tests/test_autotune.py.
+the WARMUP wire tag (service/server.py), scripts/warmup.py,
+bench.py's cold-vs-warm service round trip, tests/test_store.py.
 """
 
 from .artifacts import ArtifactStore
@@ -30,8 +26,6 @@ from .keycache import (bucket_store_key, serialize_bucket,
 from .warmstart import (set_jax_cache_env, aot_errors, aot_warmup,
                         warm_spec)
 from .remote import FetchError, fetch_blob, fetch_into
-from .calibration import (plan_store_key, store_plan, load_plan,
-                          load_or_run, parse_shapes)
 
 __all__ = [
     "ArtifactStore", "bucket_store_key", "serialize_bucket",
@@ -41,6 +35,4 @@ __all__ = [
     "profile_store_key", "store_profile", "load_profile",
     "set_jax_cache_env", "aot_errors", "aot_warmup", "warm_spec",
     "FetchError", "fetch_blob", "fetch_into",
-    "plan_store_key", "store_plan", "load_plan", "load_or_run",
-    "parse_shapes",
 ]

@@ -189,16 +189,12 @@ def sync_jax_cache(store, host, port, timeout_ms=30000, keys=None):
 
 
 # artifact-key prefixes a joining worker pulls from roster peers: bucket
-# keys carry the SRS + proving/verifying keys (keycache.py layout) and
-# autotune: keys the per-fingerprint kernel calibration plans
-# (store/calibration.py) — the expensive-to-rebuild/-remeasure state.
-# Checkpoints/proofs stay fetch-on-demand (they are job-scoped, not
-# shape-scoped). A synced plan only activates on a host whose
-# fingerprint matches (load_plan rejects foreign plans), so pulling
-# every fingerprint's plan is cheap insurance, never a wrong config.
+# keys carry the SRS + proving/verifying keys (keycache.py layout) — the
+# expensive-to-rebuild state. Checkpoints/proofs stay fetch-on-demand
+# (they are job-scoped, not shape-scoped).
 WARM_SYNC_PREFIXES = tuple(
     p for p in os.environ.get(
-        "DPT_WARM_SYNC_PREFIXES", "bucket:,autotune:").split(",") if p)
+        "DPT_WARM_SYNC_PREFIXES", "bucket:").split(",") if p)
 
 
 def warm_sync(store, peers, prefixes=None, timeout_ms=10000):

@@ -23,12 +23,6 @@
 #                          tracing + exact host evaluation, nothing runs on
 #                          a device; `analyze --changed-only` skips
 #                          unchanged kernel families)
-#   scripts/ci.sh autotune kernel-autotuner smoke tier (ISSUE 14): plan
-#                          store round-trip, fingerprint-mismatch rebuild,
-#                          parity gate vs a lying candidate, env-override
-#                          precedence, DPT_AUTOTUNE=off parity, service +
-#                          fleet-worker plan pickup — tiny shapes,
-#                          interpret-safe budget (XLA:CPU only)
 #   scripts/ci.sh benchcheck  perf-regression smoke (ISSUE 15): gate the
 #                          COMMITTED bench trajectory (BENCH_r*.json +
 #                          bench_artifacts/trajectory.jsonl) through
@@ -112,11 +106,6 @@ if [ "$1" = "chaos" ]; then
     tests/test_circuits.py tests/test_aggregate.py \
     -q -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
 fi
-if [ "$1" = "autotune" ]; then
-  exec env JAX_PLATFORMS=cpu DPT_TIER2=1 python -m pytest \
-    tests/test_autotune.py \
-    -q -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-fi
 if [ "$1" = "fast" ]; then
   # the AST lints cost <1 s and catch the jit-cache/promotion/lock bug
   # classes before any compile starts; bounds stay in `analyze` (tracing
@@ -126,12 +115,8 @@ if [ "$1" = "fast" ]; then
   # the chaos subset rides along: it is jax-free (no compiles) and pins
   # the fault-domain acceptance surface before kernel-parity compiles start
   bash scripts/ci.sh chaos || exit 1
-  # the autotune smoke tier rides along too: tiny shapes on XLA:CPU, and
-  # it pins the "off/plan-less = byte-identical dispatch" invariant the
-  # kernel-parity tests below now implicitly rely on
-  bash scripts/ci.sh autotune || exit 1
   exec env JAX_PLATFORMS=cpu DPT_TIER2=1 python -m pytest \
-    tests/test_ntt_jax.py tests/test_ntt_pallas.py \
+    tests/test_ntt_jax.py \
     tests/test_curve_msm_jax.py \
     tests/test_msm_update_paths.py tests/test_msm_pallas.py \
     tests/test_poly.py \

@@ -197,7 +197,6 @@ def main():
                       "workers": args.workers, "chaos": args.chaos,
                       "store": args.store_dir, "journal": journal_dir,
                       "log_file": log_path,
-                      "autotune": svc.autotune,
                       "autoscale": autoscaler.mode if autoscaler else "0"}),
           flush=True)
     svc.serve_forever()

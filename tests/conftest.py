@@ -44,7 +44,7 @@ _HOST_TIER = {
 }
 
 
-# tier2 tests run in scripts/ci.sh chaos / fast / autotune / tier2, which
+# tier2 tests run in scripts/ci.sh chaos / fast / tier2, which
 # set DPT_TIER2=1. Anywhere else they count as slow, so the tier-1 command's
 # fixed `-m 'not slow'` leaves them out of its 870 s wall (pytest.ini).
 _TIER2_ON = os.environ.get("DPT_TIER2") == "1"

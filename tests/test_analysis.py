@@ -331,7 +331,7 @@ def test_repo_lints_clean():
     ("ntt/n32_radix4_inv0_coset1_mont", "ntt/n32_radix2"),
     ("msm/digits_signed_c7_L66", "msm/bucket_scan_signed_onehot_packed"),
     ("msm/bucket_pallas_signed_c7_packed",),
-    ("ntt/n32_pallas", "field/fr_mont_mul_pallas_lazy"),
+    ("ntt/n32_radix4_batch3_coset", "field/fr_mont_mul_pallas_lazy"),
     ("curve/proj_add",),
 ])
 def test_registry_subset_clean(subset):

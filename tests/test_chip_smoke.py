@@ -103,7 +103,7 @@ def test_auto_ntt_lowers_for_tpu(monkeypatch, n, batch, inverse, coset):
 
     _as_on_tpu(monkeypatch)
     # the radix-4 XLA core, whose wide multiplies are the Pallas kernel
-    assert ntt_jax._active_kernel(n=n) == "xla"
+    assert ntt_jax._active_radix() == 4
     fn, consts = ntt_jax.NttPlan(n).traced_kernel(inverse, coset, batch=True)
     cspec = {k: jax.ShapeDtypeStruct(a.shape, a.dtype)
              for k, a in consts.items()}
@@ -122,12 +122,12 @@ def test_auto_msm_lowers_for_tpu(monkeypatch):
 
     _as_on_tpu(monkeypatch)
     n, batch, windows = 8224, 5, msm_jax.W7
-    mode = msm_jax._kernel_mode(n)
+    mode = msm_jax._kernel_mode()
     assert mode == "xla"   # the one-hot scan; its wide multiplies are Pallas
     # a mistyped kernel name is an error, not a quiet "xla"
     monkeypatch.setattr(msm_jax, "_MSM_KERNEL", "palas")
     with pytest.raises(ValueError, match="DPT_MSM_KERNEL"):
-        msm_jax._kernel_mode(n)
+        msm_jax._kernel_mode()
     monkeypatch.setattr(msm_jax, "_MSM_KERNEL", "auto")
     group = msm_jax._group_size_batch(n, batch, 7, signed=True, kernel=mode)
     u32 = jnp.uint32

@@ -57,23 +57,6 @@ def test_jax_prove_msm_pallas_byte_identical(proven, monkeypatch):
 
 
 @pytest.mark.slow
-def test_jax_prove_ntt_pallas_byte_identical(proven, monkeypatch):
-    """DPT_NTT_KERNEL=pallas (the fused multi-stage VMEM-resident NTT)
-    produces the SAME proof bytes as the host oracle — every forward /
-    inverse / coset NTT of all 5 rounds goes through the fused groups.
-    Slow tier: each distinct (mode, domain) NTT program recompiles
-    through the interpret-mode emulation."""
-    from distributed_plonk_tpu import proof_io
-    from distributed_plonk_tpu.backend import ntt_jax
-
-    ckt, pk, vk, proof_host = proven
-    monkeypatch.setattr(ntt_jax, "_NTT_KERNEL", "pallas")
-    proof_pl = prove(random.Random(1), ckt, pk, JaxBackend())
-    assert (proof_io.serialize_proof(proof_pl)
-            == proof_io.serialize_proof(proof_host))
-
-
-@pytest.mark.slow
 def test_jax_prove_r3_unfused_byte_identical(proven, monkeypatch):
     """DPT_R3_FUSE=0 (the standalone gate/sigma/combine step programs)
     produces the SAME proof bytes as the default fused round 3 — the

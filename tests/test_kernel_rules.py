@@ -1,0 +1,384 @@
+"""Which kernel runs is a rule in the code: each resolver reads its own
+module attribute (latched from its DPT_* variable at import, patchable
+here) and, where the answer depends on the platform,
+jax.default_backend(). This file is the table of those rules, the
+precedence of an explicit knob over them, the memo keys that follow the
+resolved mode, the MSM chunk budget and its latch, the compile cache's
+partition, and a start of the service and of a joined worker on a store
+that still holds an older run's `autotune:<fp>` artifact."""
+
+import hashlib
+import os
+import platform
+import random
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from distributed_plonk_tpu.backend import field_jax as FJ
+from distributed_plonk_tpu.backend import field_pallas as FP
+from distributed_plonk_tpu.backend import msm_jax as MJ
+from distributed_plonk_tpu.backend import ntt_jax as NJ
+
+_KNOB_ENV = ("DPT_NTT_RADIX", "DPT_MSM_GROUP_MAX")
+
+
+@pytest.fixture
+def knob_free(monkeypatch):
+    """No DPT_* kernel knob set: the module attributes hold what an
+    import without the variables latches."""
+    for k in _KNOB_ENV:
+        monkeypatch.delenv(k, raising=False)
+    monkeypatch.setattr(FJ, "_MUL_MODE", "auto")
+    monkeypatch.setattr(MJ, "_BUCKET_UPDATE", "auto")
+    monkeypatch.setattr(MJ, "_MSM_KERNEL", "auto")
+    monkeypatch.setattr(MJ, "_PLANE_PACK", True)
+    monkeypatch.setattr(MJ.MsmContext, "_C_BATCH", 7)
+    return monkeypatch
+
+
+# --- the rule, by platform ----------------------------------------------------
+
+_WIDE = (16, 1 << 13)  # past _PALLAS_MIN_LANES: what a prove multiplies at
+
+_RULE = {
+    "ntt_radix": (lambda: NJ._active_radix(), {"tpu": 4, "cpu": 4}),
+    "msm_kernel": (lambda: MJ._kernel_mode(), {"tpu": "xla", "cpu": "xla"}),
+    "bucket_update": (lambda: MJ._use_onehot_update(),
+                      {"tpu": True, "cpu": False}),   # onehot | put
+    "packed_planes": (lambda: MJ._use_packed_planes(),
+                      {"tpu": True, "cpu": False}),   # only with onehot
+    # `auto` on both; what auto means is the fused Pallas multiply for a
+    # wide shape on a TPU and the XLA f32 path everywhere else
+    "field_mul": (lambda: (FJ._mul_path(), FJ._use_pallas(_WIDE),
+                           FJ.pallas_mul_possible(), FJ._f32_active()),
+                  {"tpu": ("auto", True, True, True),
+                   "cpu": ("auto", False, False, True)}),
+}
+
+
+@pytest.mark.parametrize("decision", sorted(_RULE))
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_auto_rule(knob_free, backend, decision):
+    knob_free.setattr(jax, "default_backend", lambda: backend)
+    resolve, want = _RULE[decision]
+    assert resolve() == want[backend]
+
+
+# --- an explicit knob wins over the rule --------------------------------------
+
+def _inner_mul_width(lanes):
+    """Padded lane count the fused multiplier's pallas program sees."""
+    arg = jax.ShapeDtypeStruct((16, lanes), jnp.uint32)
+    jaxpr = jax.make_jaxpr(lambda a, b: FP.mont_mul(FJ.FR, a, b))(arg, arg)
+    (inner,) = [e for e in jaxpr.eqns
+                if e.params.get("name") == "_mont_mul_flat"]
+    return inner.invars[0].aval.shape[1]
+
+
+def _knob_mul_mode(mp):
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    mp.setattr(FJ, "_MUL_MODE", "u32")
+    assert FJ._mul_path() == "u32" and not FJ._f32_active()
+    assert not FJ._use_pallas(_WIDE) and not FJ.pallas_mul_possible()
+    mp.setattr(FJ, "_MUL_MODE", "pallas")
+    mp.setattr(jax, "default_backend", lambda: "cpu")
+    assert FJ._use_pallas(_WIDE) and FJ.pallas_mul_possible()
+    with FJ.pallas_disabled():     # the guard wins even over the knob
+        assert not FJ._use_pallas(_WIDE) and not FJ.pallas_mul_possible()
+
+
+def _knob_bucket_update(mp):
+    mp.setattr(jax, "default_backend", lambda: "cpu")
+    mp.setattr(MJ, "_BUCKET_UPDATE", "onehot")
+    assert MJ._use_onehot_update() and MJ._use_packed_planes()
+    mp.setattr(MJ, "_PLANE_PACK", False)
+    assert not MJ._use_packed_planes()
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    mp.setattr(MJ, "_BUCKET_UPDATE", "put")
+    assert not MJ._use_onehot_update()
+
+
+def _knob_msm_kernel(mp):
+    mp.setattr(MJ, "_MSM_KERNEL", "pallas")
+    assert MJ._kernel_mode() == "pallas"
+    assert MJ.MsmContext([(1, 2)] * 8)._mode() == "pallas"
+    with FJ.pallas_disabled():
+        assert MJ._kernel_mode() == "xla"
+
+
+def _knob_group_max(mp):
+    assert MJ._group_size(1 << 20) == 512
+    mp.setenv("DPT_MSM_GROUP_MAX", "64")     # lowers the cap too
+    assert MJ._group_size(1 << 20) == 64
+    assert MJ._group_size_batch(1 << 20, 1, 7, signed=True) == 64
+
+
+def _knob_msm_c(mp):
+    assert MJ.MsmContext([(1, 2)] * 300).c_batch == 7
+    mp.setattr(MJ.MsmContext, "_C_BATCH", 8)
+    ctx = MJ.MsmContext([(1, 2)] * 300)
+    assert ctx.c_batch == 8 and ctx.signed and ctx._calib_key()[2] == 8
+    # a tiny key keeps the unsigned small-window scan whatever the knob
+    assert MJ.MsmContext([(1, 2)] * 8).c_batch == MJ.window_bits(8)
+
+
+def _knob_lane_tile(mp):
+    assert _inner_mul_width(20) == FP.LANE_TILE
+    mp.setattr(FP, "LANE_TILE", 8)
+    assert _inner_mul_width(20) == 24
+
+
+@pytest.mark.parametrize("knob", [
+    _knob_mul_mode, _knob_bucket_update, _knob_msm_kernel, _knob_group_max,
+    _knob_msm_c, _knob_lane_tile], ids=lambda f: f.__name__[len("_knob_"):])
+def test_explicit_knob_wins(knob_free, knob):
+    knob(knob_free)
+
+
+# --- memo keys follow the resolved mode ---------------------------------------
+
+def _lowered(fn, consts, n):
+    return fn.lower(jax.ShapeDtypeStruct((16, n), jnp.uint32),
+                    consts).as_text()
+
+
+def _site_ntt_plan(mp):
+    plan = NJ.NttPlan(16)
+    mp.setenv("DPT_NTT_RADIX", "2")
+    fn2, c2 = plan.traced_kernel()
+    mp.setenv("DPT_NTT_RADIX", "4")
+    fn4, c4 = plan.traced_kernel()
+    assert set(plan._fns) == {(False, False, "mont", 2),
+                              (False, False, "mont", 4)}
+    assert "exps" in c2 and "exps4" in c4
+    assert _lowered(fn2, c2, 16) != _lowered(fn4, c4, 16)
+
+
+def _site_mesh_ntt_plan(mp):
+    from distributed_plonk_tpu.parallel.mesh import make_mesh
+    from distributed_plonk_tpu.parallel.ntt_mesh import MeshNttPlan
+
+    plan = MeshNttPlan(make_mesh(2, platform="cpu"), 16)
+    mp.setenv("DPT_NTT_RADIX", "2")
+    plan.kernel()
+    mp.setenv("DPT_NTT_RADIX", "4")
+    plan.kernel()
+    k2, k4 = (False, False, "mont", 2), (False, False, "mont", 4)
+    assert set(plan._fns) == {k2, k4}
+    (fn2, c2), (fn4, c4) = plan._fns[k2], plan._fns[k4]
+    assert "exps" in c2["core_r"] and "exps4" in c4["core_r"]
+    assert _lowered(fn2, c2, 16) != _lowered(fn4, c4, 16)
+
+
+def _site_stage_kernels(mp):
+    from distributed_plonk_tpu.runtime.jax_stages import StageKernels
+
+    sk = StageKernels()
+    mp.setenv("DPT_NTT_RADIX", "2")
+    t2 = sk._plan_consts(16, False)
+    mp.setenv("DPT_NTT_RADIX", "4")
+    t4 = sk._plan_consts(16, False)
+    assert set(sk._tables) == {("plan", 16, False, 2), ("plan", 16, False, 4)}
+    assert "exps" in t2 and "exps4" in t4
+    assert sk._plan_consts(16, False) is t4
+
+
+def _site_msm_chunk(mp):
+    ctx = MJ.MsmContext([(1, 2)] * 8)
+    assert ctx._chunk_key(8, 4) == (8, 4, "xla")
+    fx = ctx._chunk_fn(8, 4)
+    mp.setattr(MJ, "_MSM_KERNEL", "pallas")
+    assert ctx._chunk_key(8, 4) == (8, 4, "pallas")
+    fp = ctx._chunk_fn(8, 4)
+    assert fp is not fx and len(ctx._chunk_fns) == 2
+    mp.setattr(MJ, "_MSM_KERNEL", "xla")
+    assert ctx._chunk_fn(8, 4) is fx
+
+
+def _site_msm_calib(mp):
+    ctx = MJ.MsmContext([(1, 2)] * 300)
+    kx = ctx._calib_key()
+    assert kx == (ctx._platform, True, 7, "xla")
+    mp.setattr(MJ, "_MSM_KERNEL", "pallas")
+    assert ctx._calib_key() == kx[:3] + ("pallas",)
+
+
+@pytest.mark.parametrize("site", [
+    _site_ntt_plan, _site_mesh_ntt_plan, _site_stage_kernels,
+    _site_msm_chunk, _site_msm_calib],
+    ids=lambda f: f.__name__[len("_site_"):])
+def test_memo_key_follows_resolved_mode(knob_free, site):
+    site(knob_free)
+
+
+# --- the MSM chunk budget and its latch ---------------------------------------
+
+B, W = 5, 37
+
+
+@pytest.fixture
+def ctx(knob_free):
+    """A wide-window context and an empty class-level latch."""
+    knob_free.setattr(MJ.MsmContext, "_measured_adds_per_s", {})
+    return MJ.MsmContext([(1, 2)] * 300)
+
+
+def _aligned(budget):
+    return max(1024, (budget // (B * W)) & ~1023)
+
+
+def test_chunk_budget_unlatched(ctx):
+    assert ctx._chunk_lanes(B, W) == _aligned(ctx._CALL_ADDS)
+    assert ctx._chunk_lanes(B, W) % 1024 == 0
+
+
+@pytest.mark.parametrize("rate", [3e6, 1e12], ids=["rate", "rate-capped"])
+def test_chunk_budget_latched(ctx, rate):
+    MJ.MsmContext._measured_adds_per_s[ctx._calib_key()] = rate
+    want = min(ctx._CALL_ADDS_MAX, int(rate * ctx._CALL_TARGET_S))
+    assert ctx._chunk_lanes(B, W) == _aligned(want)
+    assert ctx._chunk_lanes(B, W) != _aligned(ctx._CALL_ADDS)
+
+
+def test_chunk_budget_floor(ctx):
+    MJ.MsmContext._measured_adds_per_s[ctx._calib_key()] = 1.0
+    assert ctx._chunk_lanes(B, W) == 1024
+    assert ctx._chunk_lanes(64, 64) == 1024
+
+
+def test_chunk_budget_other_kernels_rate_unused(ctx):
+    other = ctx._calib_key()[:3] + ("pallas",)
+    MJ.MsmContext._measured_adds_per_s[other] = 1e12
+    assert ctx._chunk_lanes(B, W) == _aligned(ctx._CALL_ADDS)
+
+
+# --- the compile cache's partition --------------------------------------------
+
+def _fingerprint_here():
+    cpu = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            cpu = next((line for line in f if line.startswith("flags")), "")
+    except OSError:
+        pass
+    return hashlib.sha256(
+        f"{platform.machine()}|{cpu}".encode()).hexdigest()[:12]
+
+
+def test_compile_cache_partition_fingerprint():
+    fp = FJ.machine_fingerprint()
+    assert fp == _fingerprint_here()
+    assert len(fp) == 12 and int(fp, 16) >= 0
+    # what the process configured at import lives under it (or where the
+    # variable says)
+    want = os.environ.get("JAX_COMPILATION_CACHE_DIR") or fp
+    assert jax.config.jax_compilation_cache_dir.endswith(want)
+
+
+def test_compile_cache_partition_dir(tmp_path, monkeypatch):
+    before = jax.config.jax_compilation_cache_dir
+    before_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        got = FJ.configure_compile_cache(str(tmp_path))
+        assert got == str(tmp_path / _fingerprint_here())
+        assert jax.config.jax_compilation_cache_dir == got
+        # the variable places the cache from outside: nothing else is set
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        assert FJ.configure_compile_cache(str(tmp_path / "other")) \
+            == str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          before_min)
+
+
+# --- a store with an older run's plan artifact --------------------------------
+
+def _stale_store(root):
+    """A store that still holds what a calibrating run of an older tree
+    left: an ordinary blob nothing reads."""
+    from distributed_plonk_tpu.store import ArtifactStore
+
+    key = "autotune:" + FJ.machine_fingerprint()
+    ArtifactStore(root).put(
+        key, b'{"version": 1, "cells": {"ntt:64": {"params": {"radix": 2}}}}',
+        meta={"kind": "autotune_plan"})
+    return key
+
+
+def _no_autotune_series(snapshot):
+    return not [name for kind in snapshot.values() if isinstance(kind, dict)
+                for name in kind if name.startswith("autotune")]
+
+
+def _start_service(tmp_path, monkeypatch, proven):
+    from distributed_plonk_tpu.service import ProofService, ServiceClient
+
+    root = str(tmp_path / "store")
+    key = _stale_store(root)
+    svc = ProofService(port=0, prover_workers=1, store_dir=root).start()
+    try:
+        assert svc.autotune == {"source": "off"}
+        with ServiceClient("127.0.0.1", svc.port) as c:
+            jid = c.submit({"kind": "toy", "gates": 8, "seed": 3})["job_id"]
+            assert c.wait(jid, timeout_s=180)["state"] == "done"
+            assert c.result(jid)[1]
+        snap = svc.metrics.snapshot()
+        assert snap["counters"]["jobs_completed"] == 1
+        assert _no_autotune_series(snap)
+        assert key in svc.store.keys()      # untouched; the GC may evict it
+    finally:
+        svc.shutdown()
+
+
+def _start_joined_worker(tmp_path, monkeypatch, proven):
+    from distributed_plonk_tpu.prover import prove
+    from distributed_plonk_tpu.runtime import worker
+    from distributed_plonk_tpu.runtime.dispatcher import (Dispatcher,
+                                                          RemoteBackend)
+    from distributed_plonk_tpu.runtime.netconfig import NetworkConfig
+    from distributed_plonk_tpu.service.metrics import Metrics
+
+    ckt, pk, _vk, proof_host = proven
+    root = str(tmp_path / "wstore")
+    _stale_store(root)
+    # _make_store points a later jax import's cache under the store through
+    # the environment; this process has jax already, so keep it out of ours
+    monkeypatch.setenv("DPT_JAX_CACHE_DIR", str(tmp_path / "jc"))
+    metrics = Metrics()
+    d = Dispatcher(NetworkConfig([]), metrics=metrics)
+    mserver = d.enable_membership()
+    t = threading.Thread(
+        target=worker.serve_joined, args=(("127.0.0.1", mserver.port),),
+        kwargs={"backend_name": "python", "store_dir": root}, daemon=True)
+    t.start()
+    try:
+        for _ in range(600):
+            if len(d.workers) >= 1 and len(d.tracker.usable_set()) >= 1:
+                break
+            threading.Event().wait(0.05)
+        assert len(d.workers) == 1
+        proof = prove(random.Random(1), ckt, pk,
+                      RemoteBackend(d, dist_fft_min=ckt.n))
+        assert proof.opening_proof == proof_host.opening_proof
+        assert proof.wires_poly_comms == proof_host.wires_poly_comms
+        assert _no_autotune_series(metrics.snapshot())
+    finally:
+        try:
+            d.shutdown()
+        finally:
+            d.pool.shutdown(wait=False)
+        t.join(timeout=15)
+    assert not t.is_alive()
+
+
+@pytest.mark.parametrize("start", [_start_service, _start_joined_worker],
+                         ids=["service", "joined_worker"])
+def test_service_and_worker_start_without_calibration(tmp_path, monkeypatch,
+                                                      proven, start):
+    start(tmp_path, monkeypatch, proven)

@@ -61,11 +61,8 @@ def test_mesh_ntt_radix2_core_parity(mesh8, plan256, monkeypatch):
     monkeypatch.setenv("DPT_NTT_RADIX", "2")
     got = plan256.run_ints(values)
     assert got == want
-    from distributed_plonk_tpu.backend import autotune
-    assert autotune.cache_key(False, False, "plain", 2, "xla") \
-        in plan256._fns
-    assert autotune.cache_key(False, False, "plain", 4, "xla") \
-        in plan256._fns
+    assert (False, False, "plain", 2) in plan256._fns
+    assert (False, False, "plain", 4) in plan256._fns
 
 
 @pytest.mark.tier2
@@ -163,16 +160,14 @@ def test_mesh_ntt_programs_carry_their_mode_in_their_name(plan256, inverse,
                                                           coset, name):
     """field_jax.named_jit: a device trace reads `jit_mesh_ntt_<mode>`, not
     four programs all called `jit_fn`."""
-    from distributed_plonk_tpu.backend import autotune
     plan256.kernel(inverse, coset, boundary="plain")
-    fn, consts = plan256._fns[autotune.cache_key(inverse, coset, "plain", 4,
-                                                 "xla")]
+    fn, consts = plan256._fns[(inverse, coset, "plain", 4)]
     assert fn.__name__ == name
     x = jax.ShapeDtypeStruct((16, plan256.n), "uint32")
     assert "jit_" + name in fn.lower(x, consts).as_text()[:200]
     # the Montgomery boundary the prover runs has no suffix
     plan256.kernel(inverse, coset, boundary="mont")
-    fn, _ = plan256._fns[autotune.cache_key(inverse, coset, "mont", 4, "xla")]
+    fn, _ = plan256._fns[(inverse, coset, "mont", 4)]
     assert fn.__name__ == name[:-len("_plain")]
 
 
