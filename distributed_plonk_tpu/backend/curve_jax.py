@@ -13,6 +13,7 @@ the TPU-idiomatic shape for data-dependent curve edge cases.
 import os
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..constants import FQ_MONT_R, FQ_LIMBS, Q_MOD
@@ -247,61 +248,55 @@ def proj_add_mixed(p, q_affine, q_inf):
     return pt_select(q_inf, p, res)
 
 
+def _mm(a, b):
+    return FJ.mont_mul(FQ, a, b)
+
+
+@jax.jit
+def _prefix_suffix(z):
+    # single-width Hillis-Steele ladders, NOT associative_scan: the
+    # multi-width lowering wedged the remote TPU compile at SRS scale
+    # (round 4) — rationale at field_jax.cumprod_mont
+    return FJ.cumprod_mont(FQ, z), FJ.cumprod_mont(FQ, z, reverse=True)
+
+
+@jax.jit
+def _normalize(px, py, pz, pre, suf, tinv, inf):
+    one_col = jnp.asarray(_MONT_ONE).reshape(FQ_LIMBS, 1)
+    pre_im1 = jnp.concatenate([one_col, pre[:, :-1]], axis=1)
+    suf_ip1 = jnp.concatenate([suf[:, 1:], one_col], axis=1)
+    # z_i^-1 (Montgomery) = pre_{i-1} * suf_{i+1} * (T^-1 R)
+    zinv = _mm(_mm(pre_im1, suf_ip1), jnp.broadcast_to(tinv, pz.shape))
+    zinv2 = _mm(zinv, zinv)
+    zinv3 = _mm(zinv2, zinv)
+    ax = _mm(px, zinv2)
+    ay = _mm(py, zinv3)
+    zero = jnp.zeros_like(ax)
+    return (FJ.select(inf, zero, ax), FJ.select(inf, zero, ay))
+
+
 def batch_to_affine(p):
     """Jacobian (24, n) Montgomery -> (x_affine, y_affine, inf_mask), all on
     device: Montgomery batch inversion of the Z column via two log-depth
     prefix/suffix product scans and ONE field inverse, which crosses to the
     host as a single element (pow(z, q-2) there costs nothing). Used to
     normalize a device-built SRS (fixed_base output has arbitrary Z) into
-    the affine form the mixed-add bucket scan consumes."""
-    import jax
-
+    the affine form the mixed-add bucket scan consumes, and each window of
+    msm_jax's pre-weighted table: the two programs are the module's, so a
+    second call at a width traces and compiles nothing."""
     px, py, pz = p
     inf = FJ.is_zero(FQ, pz)
-    one = _mont_one_like(pz)
-    z = FJ.select(inf, one, pz)
-
-    def mm(a, b):
-        return FJ.mont_mul(FQ, a, b)
-
-    @jax.jit
-    def prefix_suffix(z):
-        # single-width Hillis-Steele ladders, NOT associative_scan: the
-        # multi-width lowering wedged the remote TPU compile at SRS scale
-        # (round 4) — rationale at field_jax.cumprod_mont
-        pre = FJ.cumprod_mont(FQ, z)
-        suf = FJ.cumprod_mont(FQ, z, reverse=True)
-        return pre, suf
-
-    pre, suf = prefix_suffix(z)
+    z = FJ.select(inf, _mont_one_like(pz), pz)
+    pre, suf = _prefix_suffix(z)
     total = np.asarray(pre[:, -1])  # ONE element to host
     total_int = 0
     for k, limb in enumerate(total):
         total_int |= int(limb) << (16 * k)
-    # total is Montgomery form of T: T*R. Its modular inverse in Montgomery
-    # form is (T^-1)*R = R^2 / (T*R) -> compute R^3 * (T*R)^-1 mod q... the
-    # clean route: inv_mont = (R^2 * modinv(total_int)) % q with
-    # modinv(T*R) = T^-1 * R^-1, so R^2 * that = T^-1 * R. QED.
+    # total is Montgomery form of T: T*R. modinv(T*R) = T^-1 * R^-1, so
+    # R^2 * that = T^-1 * R, the inverse in Montgomery form
     inv_int = (FQ_MONT_R * FQ_MONT_R % Q_MOD) * pow(total_int, Q_MOD - 2, Q_MOD) % Q_MOD
     tinv = jnp.asarray(int_to_limbs(inv_int, FQ_LIMBS)).reshape(FQ_LIMBS, 1)
-
-    @jax.jit
-    def normalize(px, py, pz, pre, suf, tinv, inf):
-        n = pz.shape[1]
-        one_col = jnp.broadcast_to(
-            jnp.asarray(_MONT_ONE).reshape(FQ_LIMBS, 1), (FQ_LIMBS, 1))
-        pre_im1 = jnp.concatenate([one_col, pre[:, :-1]], axis=1)
-        suf_ip1 = jnp.concatenate([suf[:, 1:], one_col], axis=1)
-        # z_i^-1 (Montgomery) = pre_{i-1} * suf_{i+1} * (T^-1 R)
-        zinv = mm(mm(pre_im1, suf_ip1), jnp.broadcast_to(tinv, pz.shape))
-        zinv2 = mm(zinv, zinv)
-        zinv3 = mm(zinv2, zinv)
-        ax = mm(px, zinv2)
-        ay = mm(py, zinv3)
-        zero = jnp.zeros_like(ax)
-        return (FJ.select(inf, zero, ax), FJ.select(inf, zero, ay))
-
-    ax, ay = normalize(px, py, pz, pre, suf, tinv, inf)
+    ax, ay = _normalize(px, py, pz, pre, suf, tinv, inf)
     return ax, ay, inf
 
 

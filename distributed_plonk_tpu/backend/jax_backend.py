@@ -103,6 +103,21 @@ class JaxBackend:
         # completion stamps and the fed/unfed account of THE device: one
         # ledger, shared by every pool worker that proves on this backend
         self.device_ledger = self._make_ledger()
+        # where the kernels' counters go (`_count`): the Metrics of the
+        # service this backend proves for, once a pool worker has attached
+        # it; none without one
+        self.metrics = None
+
+    def attach(self, ledger, metrics):
+        """Report to the service this backend proves for: the kernels'
+        counters count in its `metrics` (a pool worker calls this with its
+        own backend's ledger, which changes nothing else here; the mesh
+        backend also takes the ledger)."""
+        self.metrics = metrics
+
+    def _count(self, name, by=1):
+        if self.metrics is not None:
+            self.metrics.inc(name, by)
 
     def _make_ledger(self):
         from ..trace import DeviceLedger
@@ -132,8 +147,10 @@ class JaxBackend:
 
     def _make_msm_ctx(self, bases):
         """MSM context factory hook (the mesh backend overrides this to
-        build a mesh-sharded context; the caching in _ctx is shared)."""
-        return MsmContext(bases)
+        build a mesh-sharded context; the caching in _ctx is shared).
+        `count=`: the context says how many polynomials it committed and
+        how many of them from its window table."""
+        return MsmContext(bases, count=self._count)
 
     def _ctx(self, bases):
         # keyed by identity; the bases reference is retained so the id can

@@ -20,7 +20,18 @@ buckets are accumulated WITHOUT any sort or data-dependent scatter pattern:
   - the remaining O(W * B) tail (running-sum bucket aggregation,
     2^(c*w) window weighting, final window sum) runs as two more
     static-shape scans with no data-dependent indexing at all (see
-    `finish`).
+    `finish`);
+  - where the bases are a key's, fixed for its life, the weighting is not
+    owed at commit time at all: a context over a signed wide-window key
+    holds, beside each point, its 2^(c*w) multiples for every window
+    (`window_table`, built once per context on the device: W times the
+    points' bytes, 117 MB for the 16,416 points of a 2^14 key at c=7, and
+    set-up seconds PERF.md sec. 5 gives as measured on the chip); lane
+    (b, w) of the scan adds window w's copy, the planes of a polynomial's
+    windows are added (`sum_windows`, 8 steps), and the tail is the bucket
+    running sum alone (`finish_preweighted`, 65 steps), on B lanes: 73
+    sequential steps where `finish` takes 323 on W * B lanes (c=8: 136
+    for 382). `use_window_table` is the rule.
 
 Accumulators are homogeneous PROJECTIVE (X : Y : Z), identity (0 : 1 : 0);
 results decode as x = X/Z, y = Y/Z (_proj_limbs_to_affine). Large MSMs
@@ -324,7 +335,7 @@ def _bucket_scan(ax, ay, ainf, digits, group, n_buckets, kernel=None):
 
 
 def _bucket_scan_signed(ax, ay, ainf, packed, group, n_buckets=128,
-                        kernel=None):
+                        kernel=None, preweighted=False):
     """SIGNED-digit COMBINED-LANE bucket accumulation — the signed hot
     path (c=8: 128 bucket columns; c=7: 64): half the buckets of the
     unsigned scan (bucket i holds points whose |digit| == i+1; the sign
@@ -337,13 +348,25 @@ def _bucket_scan_signed(ax, ay, ainf, packed, group, n_buckets=128,
     uint32 = digit + n_buckets with digit in [-n_buckets, n_buckets-1].
     Returns ((24, group, M, n_buckets),)*3 PROJECTIVE bucket planes.
 
+    preweighted=True: ax/ay are the two halves of a window table
+    (`window_table`: n rows of 24*W, eight a tile, row i holding
+    2^(c*w) * P_i for every window w) and M = B * W in the order
+    m = b * W + w. Lane m then adds window w's copy of the step's point
+    instead of the point itself, so every window's buckets carry the same
+    weights and the planes may be added across windows (`sum_windows`).
+    A step reads its `group` rows whole: point i is step i // group of
+    group i % group, which needs no relayout of the table (the plain
+    stream deals contiguous ranges to the groups; a sum of buckets does
+    not care which).
+
     DPT_MSM_KERNEL=pallas runs the fused VMEM-resident kernel
     (msm_pallas.bucket_scan_signed) — bit-identical planes at the same
     group width; this scan remains the parity/debug core. `kernel`: see
-    _bucket_scan.
+    _bucket_scan. The fused kernel takes one point a step: no table.
     """
     if (kernel == "pallas") if kernel is not None \
             else _use_pallas_kernel():
+        assert not preweighted, "the fused kernel takes one point a step"
         from . import msm_pallas
         return msm_pallas.bucket_scan_signed(ax, ay, ainf, packed, group,
                                              n_buckets,
@@ -355,9 +378,31 @@ def _bucket_scan_signed(ax, ay, ainf, packed, group, n_buckets=128,
     skip = (mag == 0) | ainf[None, :]
     idx = jnp.maximum(mag, 1).astype(jnp.uint32) - 1  # 0..n_buckets-1
 
-    sx_all, sy_all = _scan_layout(ax, ay, group)
-    xs = (sx_all, sy_all, _to_scan_m(skip, group), _to_scan_m(neg, group),
-          _to_scan_m(idx, group))
+    if preweighted:
+        row = ax.shape[-1]
+        wins = row // FQ_LIMBS
+        steps = ainf.shape[0] // group
+        sx_all, sy_all = (a.reshape(steps, group, row) for a in (ax, ay))
+
+        def to_m(a):  # (M, n) -> (steps, group, M), dealt like the rows
+            return a.reshape(M, steps, group).transpose(1, 2, 0)
+
+        def limbs(a):  # a step's rows (G, 24*W) -> (24, G, W)
+            return a.reshape(group, FQ_LIMBS, wins).transpose(1, 0, 2)
+
+        def lanes(a):  # (24, G, W) -> (24, G, M): window w on lane b*W + w
+            return jnp.tile(a, (1, 1, M // wins))
+    else:
+        sx_all, sy_all = _scan_layout(ax, ay, group)
+        to_m = partial(_to_scan_m, group=group)
+
+        def limbs(a):  # a step's points are (24, G) as they come
+            return a
+
+        def lanes(a):  # the same point on every lane
+            return a[:, :, None]
+
+    xs = (sx_all, sy_all, to_m(skip), to_m(neg), to_m(idx))
 
     vz = ax.ravel()[0] & 0  # varying-zero, see _bucket_scan
     init = _plane_init(tuple(
@@ -365,11 +410,12 @@ def _bucket_scan_signed(ax, ay, ainf, packed, group, n_buckets=128,
 
     def step(carry, x):
         planes = carry                # plane carry (packed or limb) x3
-        sx, sy, sk, ng, dg = x        # sx/sy (24, G); sk/ng/dg (G, M)
+        sx, sy, sk, ng, dg = x        # sk/ng/dg (G, M)
         cur, ctx = _plane_gather(planes, dg)
+        sx, sy = limbs(sx), limbs(sy)
         nsy = FJ.neg(CJ.FQ, sy)       # negate once per step, select per lane
-        qy = jnp.where(ng[None], nsy[:, :, None], sy[:, :, None])
-        sxb = jnp.broadcast_to(sx[:, :, None], cur[0].shape)
+        qy = jnp.where(ng[None], lanes(nsy), lanes(sy))
+        sxb = jnp.broadcast_to(lanes(sx), cur[0].shape)
         nv = CJ.proj_add_mixed(cur, (sxb, qy), sk)
         return _plane_update(planes, nv, ctx), None
 
@@ -400,21 +446,57 @@ def fold_planes(bx, by, bz):
 
 # --- finish tail -------------------------------------------------------------
 
+def _running_sum(bx, by, bz, signed):
+    """(24, L, B) buckets on L lanes -> (24, L): lane l's sum of its bucket
+    columns, each times its weight, by the running-sum trick. A scan over
+    the columns from the heaviest down (+ one infinity flush column), the
+    carry (run_l, acc_l) stacked on a lane axis so each step is ONE
+    (24, L, 2) complete projective add — pipelined:
+    acc += run ; run += bucket[:, b]  per step. signed=True: column i
+    weighs i+1 and all B columns count (B + 1 steps); else column 0 is
+    the zero digit's and is dropped (B steps)."""
+    vz = bz.ravel()[0] & 0  # varying-zero, see _bucket_scan
+    inf_l = tuple(x + vz for x in CJ.proj_inf((bz.shape[1],)))
+
+    def col_xs(a):  # (24, L, B) -> (B, 24, L): high-weight column first
+        body = a if signed else a[:, :, 1:]
+        return body[:, :, ::-1].transpose(2, 0, 1)
+
+    xs = tuple(jnp.concatenate([col_xs(a), i[None, :, :]], axis=0)
+               for a, i in zip((bx, by, bz), inf_l))
+
+    def agg(carry, x):
+        # carry: ((24, L, 2),)*3 with lane 0 = run, lane 1 = acc
+        left = tuple(v for v in carry)
+        right = tuple(jnp.stack([xi, v[:, :, 0]], axis=2)
+                      for xi, v in zip(x, left))
+        out = CJ.proj_add(left, right)
+        return out, None
+
+    init = tuple(jnp.stack([i, i], axis=2) for i in inf_l)
+    acc2, _ = lax.scan(agg, init, xs)
+    return tuple(v[:, :, 1] for v in acc2)
+
+
 def finish(bx, by, bz, signed=False):
-    """(24, W, B) folded buckets -> total point ((24,),)*3.
+    """(24, W, B) folded buckets -> total point ((24,),)*3: the tail of a
+    commit whose bases are the points themselves. What a context without
+    a window table runs (the unsigned small-window path under 256 points,
+    a table over `_TABLE_BYTES_BUDGET`, the fused Pallas scan), and the
+    oracle `finish_preweighted` is tested against.
 
     Three phases, all static-shape scans with NO gather/scatter ops (this
     XLA:CPU build expands scatters into per-index buffer updates, which
     made an indexed-machine variant of this tail pathologically slow):
 
-      1. running-sum bucket aggregation: scan over bucket columns B-1..1
-         (+ one infinity flush column), carry (run_w, acc_w) stacked on a
-         lane axis so each step is ONE (24, W, 2) complete projective add
-         — pipelined:  acc += run ; run += bucket[:, b]  per step.
+      1. running-sum bucket aggregation with the W windows as lanes
+         (`_running_sum`): B + 1 steps (signed) of a (24, W, 2) add.
       2+3. window weighting and final sum in ONE scan of (shift, mask)
          steps on (24, W): `shift=0` steps double the masked windows
          (acc_w ends as 2^(c*w) * A_w), `shift=h` steps add acc[w+h] into
          acc[w] for w < h (pairwise tree); the total lands in lane 0.
+         c * (W - 1) + log2(W) steps: 258 at c=7, 253 at c=8, four fifths
+         of the tail — what the window table takes away.
 
     Points are PROJECTIVE with complete adds throughout, so the shift=0
     "doubling" steps and every identity lane need no special handling at
@@ -426,28 +508,7 @@ def finish(bx, by, bz, signed=False):
     c = -(-SCALAR_BITS // wins)  # ceil: c=7 gives 37 windows (not 256/37=6)
     assert buckets == (1 << (c - 1) if signed else 1 << c), (wins, buckets)
     add = CJ.proj_add
-    vz = bz.ravel()[0] & 0  # varying-zero, see _bucket_scan
-    inf_w = tuple(x + vz for x in CJ.proj_inf((wins,)))
-
-    # phase 1: bucket columns (weight order), then one infinity flush column
-    def col_xs(a):  # (24, W, B) -> (B, 24, W): high-weight column first
-        body = a if signed else a[:, :, 1:]
-        return body[:, :, ::-1].transpose(2, 0, 1)
-
-    xs = tuple(jnp.concatenate([col_xs(a), i[None, :, :]], axis=0)
-               for a, i in zip((bx, by, bz), inf_w))
-
-    def agg(carry, x):
-        # carry: ((24, W, 2),)*3 with lane 0 = run, lane 1 = acc
-        left = tuple(v for v in carry)
-        right = tuple(jnp.stack([xi, v[:, :, 0]], axis=2)
-                      for xi, v in zip(x, left))
-        out = add(left, right)
-        return out, None
-
-    init = tuple(jnp.stack([i, i], axis=2) for i in inf_w)
-    acc2, _ = lax.scan(agg, init, xs)
-    acc = tuple(v[:, :, 1] for v in acc2)  # (24, W)
+    acc = _running_sum(bx, by, bz, signed)  # (24, W)
 
     # phase 2+3: doubling ladder + pairwise tree, one (shift, mask) scan
     steps = []
@@ -474,6 +535,51 @@ def finish(bx, by, bz, signed=False):
     return tuple(v[:, 0] for v in acc)
 
 
+def finish_preweighted(bx, by, bz):
+    """((24, B, buckets),)*3 signed planes of B polynomials, their windows
+    already added (`sum_windows`) -> ((24, B),)*3 totals: the running sum
+    over the bucket columns with the polynomials as lanes, buckets + 1
+    steps, and nothing after it. The 2^(c*w) that `finish`'s ladder
+    applies is in the bases (`window_table`)."""
+    return _running_sum(bx, by, bz, signed=True)
+
+
+_WINDOW_LANES = 8
+
+
+def sum_windows(bx, by, bz, batch):
+    """((24, B*W, buckets),)*3 planes accumulated over a window table ->
+    ((24, B, buckets),)*3: each polynomial's W window planes added, which
+    the table's equal weights allow. Two scans, one add body each: the
+    windows fold eight abreast (`fold_planes` over ceil(W / 8) slices,
+    identity planes filling the last), then three cyclic roll-adds
+    (4, 2, 1) leave the total on every one of the eight lanes. 8 steps at
+    W = 37, 7 at 32, on a fifth of the lanes a masked tree over all W
+    would add at every level."""
+    wins, buckets = bx.shape[1] // batch, bx.shape[2]
+    k = -(-wins // _WINDOW_LANES)
+    vz = bz.ravel()[0] & 0  # varying-zero, see _bucket_scan
+
+    def slices(a, inf):  # (24, B*W, buckets) -> (k, 24, B*8, buckets)
+        a = a.reshape(FQ_LIMBS, batch, wins, buckets)
+        a = jnp.concatenate([a, inf + vz], axis=2)
+        return a.reshape(FQ_LIMBS, batch, k, _WINDOW_LANES, buckets) \
+            .transpose(2, 0, 1, 3, 4) \
+            .reshape(k, FQ_LIMBS, batch * _WINDOW_LANES, buckets)
+
+    inf = CJ.proj_inf((batch, k * _WINDOW_LANES - wins, buckets))
+    acc = fold_planes(*(slices(a, i) for a, i in zip((bx, by, bz), inf)))
+    acc = tuple(a.reshape(FQ_LIMBS, batch, _WINDOW_LANES, buckets)
+                for a in acc)
+
+    def roll_add(acc, shift):
+        return CJ.proj_add(
+            acc, tuple(jnp.roll(a, shift, axis=2) for a in acc)), None
+
+    acc, _ = lax.scan(roll_add, acc, jnp.asarray([4, 2, 1], jnp.int32))
+    return tuple(a[:, :, 0] for a in acc)
+
+
 def bucket_planes_batch(ax, ay, ainf, digits, group, kernel=None):
     """B-polynomial bucket accumulation over SHARED bases: affine points
     (24, nc) + inf mask (nc,) + digits (B, W, nc) -> folded planes
@@ -491,17 +597,24 @@ def bucket_planes_batch(ax, ay, ainf, digits, group, kernel=None):
     return fold_planes(*planes)
 
 
-def bucket_planes_batch_signed(ax, ay, ainf, packed, group, kernel=None):
+def bucket_planes_batch_signed(ax, ay, ainf, packed, group, kernel=None,
+                               preweighted=False):
     """Signed-digit analog of bucket_planes_batch: affine bases (24, nc) +
     inf mask (nc,) + packed digits (B, W, nc) -> ((24, B*W, 2^(c-1)),)*3.
-    The window count W determines c (32 -> c=8, 37 -> c=7)."""
+    The window count W determines c (32 -> c=8, 37 -> c=7).
+
+    preweighted=True: ax/ay are nc rows of a window table
+    (nc/8, 8, 24*W); after the group fold the W window planes of each
+    polynomial are added -> ((24, B, 2^(c-1)),)*3."""
     B, W, n = packed.shape
     c = -(-SCALAR_BITS // W)
     flat = packed.reshape(B * W, n)
     wb = _bucket_scan_signed(ax, ay, ainf, flat, group,
-                             n_buckets=1 << (c - 1), kernel=kernel)
+                             n_buckets=1 << (c - 1), kernel=kernel,
+                             preweighted=preweighted)
     planes = tuple(x.transpose(1, 0, 2, 3) for x in wb)
-    return fold_planes(*planes)
+    acc = fold_planes(*planes)
+    return sum_windows(*acc, batch=B) if preweighted else acc
 
 
 def finish_batch(acc_x, acc_y, acc_z, batch, signed=False):
@@ -666,6 +779,87 @@ def points_to_device(bases_affine, pad):
     return x, y, inf
 
 
+# --- window-weighted bases ---------------------------------------------------
+# The weight 2^(c*w) that `finish` gives window w AFTER its buckets are
+# summed can sit in the bases instead, which are fixed for the life of a
+# key: beside P_i a context holds 2^(c*w) * P_i for every window w, lane
+# (b, w) of the scan adds window w's copy, and the whole tail of a commit
+# is `sum_windows` + `finish_preweighted`: ceil(W / 8) + 3 steps to add a
+# polynomial's windows and buckets + 1 for the running sum, where the
+# ladder took c * (W - 1) more and ran both on W times the lanes (323
+# sequential steps -> 73 at c=7, 382 -> 136 at c=8; on the chip the tail
+# of a 5-polynomial commit went from 107 ms to 8, PERF.md sec. 5). The
+# price is memory, W * n * 192 bytes: 117 MB
+# for the 16,416 points of a 2^14 key at c=7, 1.86 GB at 2^18; a key whose
+# table would pass the budget keeps the ladder, under 1% of its commit.
+_TABLE_BYTES_BUDGET = 2 << 30
+
+
+def table_bytes(n, c):
+    """Bytes of an n-point window table at window width c."""
+    return -(-SCALAR_BITS // c) * n * 2 * 4 * FQ_LIMBS
+
+
+def use_window_table(signed, kernel, n, c):
+    """THE RULE for the pre-weighted tail, on what a context can observe:
+    the signed wide-window pipeline (the unsigned one is for keys under
+    256 points, where a tail is nothing), the XLA scan (the fused Pallas
+    kernel takes one point a step), and a table within the byte budget."""
+    return (signed and kernel == "xla"
+            and table_bytes(n, c) <= _TABLE_BYTES_BUDGET)
+
+
+def _next_window(c, x, y, inf):
+    """Affine points -> their 2^c multiples, Jacobian: c doublings over all
+    lanes (the identity stays the identity, and no other point of prime
+    order becomes it)."""
+    return lax.fori_loop(0, c, lambda _, p: CJ.jac_double(p),
+                         CJ.from_affine(x, y, inf))
+
+
+_next_window_fn = FJ.named_jit("msm_table_window", _next_window,
+                               static_argnums=0)
+
+
+TABLE_TILE = 8  # rows of a window table in one TPU tile; its keys are a
+# whole number of them
+
+
+def _table_pack(cols):
+    """W arrays (24, n) -> (n/8, 8, 24*W): a point's limbs of all its
+    windows in one row, the window minor. Rows, not a trailing window
+    axis of 37: a TPU tile is 8 x 128 of the two minor dimensions and
+    pads what does not fill it, 888 to 896 here and 37 to 128 there. And
+    three dimensions, eight rows a tile, because the layout a TPU gives
+    two follows their sizes (16,416 x 888 is held column-major, which a
+    scan over rows would undo with a copy of the table a call)."""
+    t = jnp.stack(cols, axis=2).transpose(1, 0, 2)
+    return t.reshape(t.shape[0] // TABLE_TILE, TABLE_TILE, -1)
+
+
+_table_pack_fn = FJ.named_jit("msm_table_pack", _table_pack)
+
+
+def window_table(ax, ay, ainf, c):
+    """Affine Montgomery points (24, n) + inf mask (n,) -> the window
+    table (tx, ty), each (n/8, 8, 24*W) uint32: row i (of the n the two
+    leading axes make) holds the 24 limbs of 2^(c*w) * P_i for
+    w = 0..W-1 at columns [k*W + w]. Identity columns (the key's padding)
+    are zeros in every window and keep the one mask.
+    Built on the device, window by window: c doublings of the last
+    window's affine points, then one batched inversion (`batch_to_affine`:
+    one field element a window crosses to the host), so nothing larger
+    than the table is ever held."""
+    xs, ys = [jnp.asarray(ax)], [jnp.asarray(ay)]
+    ainf = jnp.asarray(ainf)
+    for _ in range(-(-SCALAR_BITS // c) - 1):
+        x, y, _inf = CJ.batch_to_affine(
+            _next_window_fn(c, xs[-1], ys[-1], ainf))
+        xs.append(x)
+        ys.append(y)
+    return _table_pack_fn(xs), _table_pack_fn(ys)
+
+
 class DeviceCommitKey:
     """A commit key that lives on device as Jacobian Montgomery limb arrays
     (e.g. straight out of the fixed-base SRS generator) — no host affine
@@ -682,12 +876,25 @@ class DeviceCommitKey:
 
 class MsmContext:
     """Device-resident base set (the SRS chunk a worker holds,
-    reference src/worker.rs:42-48). Reused across commitments."""
+    reference src/worker.rs:42-48). Reused across commitments.
 
-    def __init__(self, bases):
+    What it holds on the device: the affine points `point` (x, y (24, n),
+    inf mask (n,): 192 B a point) and, where `use_window_table` says so,
+    their window table `table` (tx, ty (n/8, 8, 24*W): W times as much,
+    117 MB at the 16,416 points of a 2^14 key), built once here. A commit
+    served from the table ends in `finish_preweighted`; without one, in
+    `finish`.
+
+    count(name, by): where `msm_commit_polys` and
+    `msm_commit_polys_preweighted` go (JaxBackend._count), if anywhere."""
+
+    def __init__(self, bases, count=None):
+        self._count = count or (lambda name, by=1: None)
         n = len(bases)
         self.n = n
         pad = n % 2  # groups need >= 2 scan steps
+        if n + pad >= 256:  # a wide-window key: whole tiles of a table
+            pad = (-n) % TABLE_TILE
         self.padded_n = n + pad
         self.c = window_bits(self.padded_n)
         # batched pipelines use wide SIGNED windows once the key is big
@@ -714,9 +921,14 @@ class MsmContext:
             self.point = tuple(jax.device_put(p)
                                for p in points_to_device(bases, pad))
         self._platform = next(iter(self.point[0].devices())).platform
+        self.table = (window_table(*self.point, self.c_batch)
+                      if use_window_table(self.signed, self._mode(),
+                                          self.padded_n, self.c_batch)
+                      else None)
         # every program of the commit pipeline has a name of its own in
         # the device trace (field_jax.named_jit): msm_digits,
-        # msm_digits_many, msm_bucket_scan, msm_merge, msm_finish
+        # msm_digits_many, msm_bucket_scan, msm_merge, msm_finish (and, at
+        # the build above, msm_table_window, msm_table_pack)
         if self.c_batch == 7:
             digits = partial(signed_digits7_from_mont,
                              padded_n=self.padded_n)
@@ -762,31 +974,40 @@ class MsmContext:
         """Resolved bucket kernel."""
         return _kernel_mode()
 
+    def _preweighted(self):
+        """Whether a commit is served from the window table: the table is
+        there and the kernel resolved for this call is still the one it
+        was built for."""
+        return self.table is not None and self._mode() == "xla"
+
     def _chunk_key(self, nc, group):
         """Chunk-fn/call memo key, resolved mode included — the
         pallas/xla branch is taken at TRACE time inside the jit, so an
         env/attr flip (bench A/B, tests) must not reuse the other
-        configuration's executable."""
+        configuration's executable. (Whether the table serves the call
+        follows from the context and that mode.)"""
         return (nc, group, self._mode())
 
     def _chunk_fn(self, nc, group):
         key = self._chunk_key(nc, group)
         if key not in self._chunk_fns:
-            fn = bucket_planes_batch_signed if self.signed \
-                else bucket_planes_batch
             # kernel pinned to the memo key's resolution, so the traced
             # branch cannot diverge from the key
+            fn = (partial(bucket_planes_batch_signed,
+                          preweighted=self._preweighted())
+                  if self.signed else bucket_planes_batch)
             self._chunk_fns[key] = FJ.named_jit(
                 "msm_bucket_scan",
                 partial(fn, group=group, kernel=self._mode()))
         return self._chunk_fns[key]
 
     def _finish_fn(self, batch):
-        if batch not in self._finish_fns:
-            self._finish_fns[batch] = FJ.named_jit(
-                "msm_finish",
+        key = (batch, self._preweighted())
+        if key not in self._finish_fns:
+            self._finish_fns[key] = FJ.named_jit(
+                "msm_finish", finish_preweighted if key[1] else
                 partial(finish_batch, batch=batch, signed=self.signed))
-        return self._finish_fns[batch]
+        return self._finish_fns[key]
 
     # adds/s measured from the first fenced chunk call; class-level so every
     # context on the process shares the calibration. Keyed by
@@ -815,7 +1036,20 @@ class MsmContext:
         device calls as the per-call budget requires: per-chunk bucket
         accumulation, cheap cross-chunk plane merges, one finish tail."""
         B, W, n = digits.shape
-        ax, ay, ainf = self.point
+        ainf = self.point[2]
+        # the bases of a call: the table's rows (n/8, 8, 24*W), cut by the
+        # tile, or the points' columns (24, n)
+        if self._preweighted():
+            (ax, ay), axis, tile = self.table, 0, TABLE_TILE
+            self._count("msm_commit_polys_preweighted", B)
+        else:
+            (ax, ay), axis, tile = self.point[:2], 1, 1
+        self._count("msm_commit_polys", B)
+
+        def cut(a, i0, nc, axis=0, tile=1):
+            return a if nc == tile * a.shape[axis] else lax.slice_in_dim(
+                a, i0 // tile, (i0 + nc) // tile, axis=axis)
+
         acc = None
         i0 = 0
         while i0 < n:
@@ -835,8 +1069,8 @@ class MsmContext:
                 if acc is not None:  # drain queued async work first, or
                     np.asarray(acc[0][:1, :1, :1])  # dt covers prior chunks
                 t0 = time.perf_counter()
-            part = fn(ax[:, i0:i0 + nc], ay[:, i0:i0 + nc], ainf[i0:i0 + nc],
-                      digits[:, :, i0:i0 + nc])
+            part = fn(cut(ax, i0, nc, axis, tile), cut(ay, i0, nc, axis, tile),
+                      cut(ainf, i0, nc), cut(digits, i0, nc, 2))
             if calibrate:
                 np.asarray(part[0][:1, :1, :1])  # fence (tiny transfer)
                 # clamp: a sub-latency reading still LATCHES (at an
@@ -908,13 +1142,19 @@ class MsmContext:
             nc = min(self._chunk_lanes(B, W), self.padded_n)
             g = _group_size_batch(nc, B, c, signed=self.signed,
                                   kernel=self._mode())
+            # what a chunk takes of the bases and gives back: the table's
+            # rows and planes with the windows added, or the points'
+            # columns and a plane a window
+            base, lanes = (((nc // TABLE_TILE, TABLE_TILE, FQ_LIMBS * W), B)
+                           if self._preweighted()
+                           else ((FQ_LIMBS, nc), B * W))
             aot(self._chunk_fn(nc, g),
-                jax.ShapeDtypeStruct((FQ_LIMBS, nc), u32),
-                jax.ShapeDtypeStruct((FQ_LIMBS, nc), u32),
+                jax.ShapeDtypeStruct(base, u32),
+                jax.ShapeDtypeStruct(base, u32),
                 jax.ShapeDtypeStruct((nc,), jnp.bool_),
                 jax.ShapeDtypeStruct((B, W, nc), u32))
             planes = tuple(
-                jax.ShapeDtypeStruct((FQ_LIMBS, B * W, buckets), u32)
+                jax.ShapeDtypeStruct((FQ_LIMBS, lanes, buckets), u32)
                 for _ in range(3))
             aot(self._finish_fn(B), *planes)
             aot(self._merge_fn, planes, planes)

@@ -60,7 +60,8 @@ service Metrics), nothing of its own:
     from the all-gather program and both from the inherited round math;
   - counters, in the attached Metrics (none without one):
     `mesh_ntt_calls`, `mesh_ntt_sharded`, `mesh_all_to_all_bytes`,
-    `mesh_msm_chunks`, `mesh_all_gather_bytes`.
+    `mesh_msm_chunks`, `mesh_all_gather_bytes`, and the two every commit
+    context counts, `msm_commit_polys` / `msm_commit_polys_preweighted`.
 """
 
 import functools
@@ -117,7 +118,6 @@ class MeshBackend(JaxBackend):
         self.mesh = mesh
         self.d = mesh.devices.size
         self._mesh_plans = {}
-        self.metrics = None
 
     def attach(self, ledger, metrics):
         """Report to the service this backend is leased to: the mesh
@@ -125,14 +125,10 @@ class MeshBackend(JaxBackend):
         (trace.DeviceLedger; one fed/unfed account per service). A pool
         whose own backend has no ledger passes None, and this backend
         keeps its own."""
-        self.metrics = metrics
+        super().attach(ledger, metrics)
         if ledger is not None and ledger is not self.device_ledger:
             self.device_ledger.close_threads()   # its own: beacon, watcher
             self.device_ledger = ledger
-
-    def _count(self, name, by=1):
-        if self.metrics is not None:
-            self.metrics.inc(name, by)
 
     # --- placement hooks ----------------------------------------------------
 

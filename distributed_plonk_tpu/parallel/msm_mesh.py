@@ -29,6 +29,18 @@ like the single-device MsmContext, the mesh context
 Data layout: points live as (24, D, local) arrays sharded on the device
 axis — device d owns the contiguous base range [d*local, (d+1)*local) —
 so chunk slices along the LOCAL axis never reshard.
+
+What a context holds on the device besides: where msm_jax's
+`use_window_table` says so (the signed pipeline, a table within the byte
+budget), the window table of its points, 2^(c*w) * P_i for each of the W
+windows (msm_jax.window_table), built on ONE device over the whole padded
+key before anything is sharded and then placed as the points are, (D,
+local/8, 8, 24*W) sharded on the device axis: W * 192 B a point, 101 MB
+over the four chips for the 16,448 padded points of a 2^14 key at c = 8.
+A chip then adds its windows' planes before the all_gather, which moves
+B x 128 bucket rows a chip and not B x 32 x 128, and the finish on the
+one chip is the bucket running sum alone (msm_jax.finish_preweighted:
+129 steps on B lanes where the ladder path takes 382 on 32 x B).
 """
 
 import os
@@ -47,8 +59,9 @@ from ..backend import field_jax as FJ
 from ..backend.msm_jax import (
     SCALAR_BITS, DeviceCommitKey, window_bits, _group_size_batch,
     bucket_planes_batch, bucket_planes_batch_signed, fold_planes,
-    finish_batch, digits_of_scalars, signed_digits_of_scalars,
-    digits_from_mont, signed_digits_from_mont, points_to_device,
+    finish_batch, finish_preweighted, digits_of_scalars,
+    signed_digits_of_scalars, digits_from_mont, signed_digits_from_mont,
+    points_to_device, use_window_table, window_table, TABLE_TILE,
     _proj_limbs_to_affine,
 )
 from .mesh import SHARD_AXIS, pallas_guard
@@ -64,8 +77,9 @@ class MeshMsmContext:
     _CALL_ADDS = int(os.environ.get("DPT_MSM_CALL_ADDS", "8000000"))
 
     def __init__(self, mesh, bases, count=None):
-        # count(name, by): where `mesh_msm_chunks` and
-        # `mesh_all_gather_bytes` go (MeshBackend._count), if anywhere
+        # count(name, by): where `mesh_msm_chunks`, `mesh_all_gather_bytes`,
+        # `msm_commit_polys` and `msm_commit_polys_preweighted` go
+        # (MeshBackend._count), if anywhere
         self._count = count or (lambda name, by=1: None)
         self.mesh = mesh
         self.d = d = mesh.devices.size
@@ -103,6 +117,20 @@ class MeshMsmContext:
             jax.device_put(resh(ainf, (d, self.local_n)), inf_sh),
         )
 
+        # the window table: built over the whole key on one device, then
+        # dealt like the points (the window axis is in the rows, unsharded)
+        self.table = None
+        if use_window_table(self.signed, msm_jax._kernel_mode(),
+                            self.padded_n, self.c):
+            tab_sh = NamedSharding(mesh, P(SHARD_AXIS, None, None, None))
+            with jax.default_device(self._local_device()):
+                table = window_table(ax, ay, ainf, self.c)
+            self.table = tuple(
+                jax.device_put(
+                    t.reshape(d, self.local_n // TABLE_TILE, TABLE_TILE, -1),
+                    tab_sh)
+                for t in table)
+
         self._digits_sh = NamedSharding(mesh, P(None, None, SHARD_AXIS, None))
         self._digits_fns = {}
         self._chunk_fns = {}
@@ -121,6 +149,13 @@ class MeshMsmContext:
         # program names (field_jax.named_jit): mesh_msm_digits, _chunk (the
         # shard_map'd scan with the all_gather + fold), _merge, _finish
         self._merge_fn = FJ.named_jit("mesh_msm_merge", _merge)
+
+    def _local_device(self):
+        """The mesh device this process does one-device work on (the table
+        build, the finish tail): its own first, under multi-controller."""
+        return next((dv for dv in self.mesh.devices.ravel()
+                     if dv.process_index == jax.process_index()),
+                    self.mesh.devices.ravel()[0])
 
     # --- digit extraction ----------------------------------------------------
 
@@ -166,15 +201,25 @@ class MeshMsmContext:
         chunk, then cross-device all_gather + fold -> replicated planes."""
         key = (jc, group, B)
         if key not in self._chunk_fns:
-            scan = (bucket_planes_batch_signed if self.signed
+            table = self.table is not None
+            # a table was built for the XLA scan and is served by it
+            scan = (partial(bucket_planes_batch_signed, preweighted=True,
+                            kernel="xla") if table else
+                    bucket_planes_batch_signed if self.signed
                     else bucket_planes_batch)
+            # the bases' device axis: leading in the table, second in the
+            # points
+            base_spec, local = ((P(SHARD_AXIS, None, None, None),
+                                 lambda a: a[0]) if table else
+                                (P(None, SHARD_AXIS, None),
+                                 lambda a: a[:, 0]))
 
             def body(ax, ay, ainf, digits):
                 # pallas only if the mesh devices are TPUs (mesh.pallas_guard)
                 with pallas_guard(self.mesh):
-                    # local block: ax/ay (24, 1, jc), ainf (1, jc),
-                    # digits (B, W, 1, jc)
-                    acc = scan(ax[:, 0], ay[:, 0], ainf[0],
+                    # local block: ax/ay (24, 1, jc) or the table's
+                    # (1, jc/8, 8, 24*W), ainf (1, jc), digits (B, W, 1, jc)
+                    acc = scan(local(ax), local(ay), ainf[0],
                                digits[:, :, 0], group=group)
                     # fold bucket planes across the mesh on device (the
                     # reference folds partial totals on the dispatcher host,
@@ -189,8 +234,7 @@ class MeshMsmContext:
             self._chunk_fns[key] = FJ.named_jit(
                 "mesh_msm_chunk", jax.shard_map(
                     body, mesh=self.mesh,
-                    in_specs=(P(None, SHARD_AXIS, None),
-                              P(None, SHARD_AXIS, None), P(SHARD_AXIS, None),
+                    in_specs=(base_spec, base_spec, P(SHARD_AXIS, None),
                               P(None, None, SHARD_AXIS, None)),
                     out_specs=(P(None, None, None),) * 3, check_vma=False))
         return self._chunk_fns[key]
@@ -199,6 +243,8 @@ class MeshMsmContext:
         if batch not in self._finish_fns:
             def _finish(ax, ay, az):
                 with pallas_guard(self.mesh):
+                    if self.table is not None:
+                        return finish_preweighted(ax, ay, az)
                     return finish_batch(ax, ay, az, batch=batch,
                                         signed=self.signed)
             self._finish_fns[batch] = FJ.named_jit("mesh_msm_finish",
@@ -210,6 +256,9 @@ class MeshMsmContext:
         B = digits.shape[0]
         W = self.windows
         ax, ay, ainf = self.point
+        self._count("msm_commit_polys", B)
+        if self.table is not None:
+            self._count("msm_commit_polys_preweighted", B)
         chunk = max(16, (self._CALL_ADDS // (B * W)) & ~15)
         acc = None
         j0 = 0
@@ -217,8 +266,14 @@ class MeshMsmContext:
             jc = min(chunk, self.local_n - j0)
             g = _group_size_batch(jc, B, self.c, signed=self.signed)
             fn = self._chunk_fn(jc, g, B)
-            part = fn(ax[:, :, j0:j0 + jc], ay[:, :, j0:j0 + jc],
-                      ainf[:, j0:j0 + jc], digits[:, :, :, j0:j0 + jc])
+            if self.table is not None:
+                t0, t1 = j0 // TABLE_TILE, (j0 + jc) // TABLE_TILE
+                bases = tuple(t if jc == self.local_n else t[:, t0:t1]
+                              for t in self.table)
+            else:
+                bases = ax[:, :, j0:j0 + jc], ay[:, :, j0:j0 + jc]
+            part = fn(*bases, ainf[:, j0:j0 + jc],
+                      digits[:, :, :, j0:j0 + jc])
             self._count("mesh_msm_chunks")
             # the all_gather: each of d chips takes the other d-1 chips'
             # bucket planes, which have the shape of the folded `part`
@@ -235,9 +290,7 @@ class MeshMsmContext:
         # the whole tail. Under multi-controller the global array is not
         # fully addressable, so each process pulls its LOCAL replica
         # (identical by construction).
-        dev = next((dv for dv in self.mesh.devices.ravel()
-                    if dv.process_index == jax.process_index()),
-                   self.mesh.devices.ravel()[0])
+        dev = self._local_device()
         acc = tuple(jax.device_put(a.addressable_data(0), dev) for a in acc)
         tx, ty, tz = self._finish_fn(B)(*acc)
         tx, ty, tz = np.asarray(tx), np.asarray(ty), np.asarray(tz)

@@ -415,6 +415,11 @@ class WorkerPool:
             # the fed/unfed account of the device is read, charged to the
             # instant, with every METRICS snapshot
             self.metrics.add_source(ledger)
+        attach = getattr(backend, "attach", None)
+        if attach is not None:
+            # the kernels' counters (`msm_commit_polys*`) count in the
+            # service's registry, as a leased backend's do (_run_item)
+            attach(ledger, self.metrics)
         while True:
             if ledger is not None:
                 ledger.idle(worker.index)

@@ -64,6 +64,27 @@ def pytest_collection_modifyitems(items):
     items.sort(key=lambda it: it.module.__name__ not in _HOST_TIER)
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _benchmark_modules_start_without_compiled_programs(request):
+    """A module under tests/benchmark starts with no compiled program alive
+    in its process. The JAX profiler writes the HLO of every live
+    executable into each trace it takes (megabytes for one NTT program),
+    and the harness reads an idle stretch as idle only while its trace is
+    under a megabyte: a worker that had run a kernel module first made
+    test_bench_harness's real-trace test fail (seen under `--dist
+    loadfile`, where the order of modules on a worker follows their sizes
+    and the clock; on the parent commit too). Nothing a module compiles
+    for itself is touched."""
+    if "benchmark" in request.module.__name__.split(".") \
+            or os.sep + "benchmark" + os.sep in str(request.path):
+        import gc
+        import sys
+        if "jax" in sys.modules:
+            sys.modules["jax"].clear_caches()
+            gc.collect()
+    yield
+
+
 def free_port_block(n, port_base):
     """First port of `n` consecutive loopback ports that are free right
     now. The search starts where the fleet tests always took their block

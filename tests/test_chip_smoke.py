@@ -37,6 +37,11 @@ def test_served_flow_jax_backend_matches_oracle(aot_warm):
     assert job["placement"] == "pool" and job["oracle_equal"] is True
     assert len(job["proof"]) == chip_smoke.PROOF_BYTES
     assert metrics["counters"]["jobs_completed"] == 1
+    # a proof commits 13 polynomials (5 wires, the permutation product, 5
+    # quotient splits, 2 openings), counted by the commit context of the
+    # job's key; a toy key is under 256 points and holds no window table
+    assert metrics["counters"]["msm_commit_polys"] == 13
+    assert "msm_commit_polys_preweighted" not in metrics["counters"]
     assert runtime["backend"] == "jax" and runtime["platform"] == "cpu"
     assert runtime["domain_size"] == 16
     assert mesh_backends == []

@@ -233,3 +233,184 @@ def test_msm_c7_matches_oracle(monkeypatch):
     dev = np.asarray(msm_jax.signed_digits7_from_mont(h, ctx.padded_n))
     host = msm_jax.signed_digits7_of_scalars(scalars, ctx.padded_n)
     assert np.array_equal(dev, host)
+
+
+# --- window-weighted bases (PR 36) --------------------------------------------
+# A context over a signed wide-window key holds 2^(c*w) * P_i for every
+# window w beside P_i; its commits end in the bucket running sum alone.
+
+_TABLE_N = 256
+_DISTINCT = 15
+
+
+@pytest.fixture(scope="module")
+def table_bases():
+    """254 points of 15 distinct ones (duplicates force P == Q inside the
+    scan) and two identities, like an SRS's padding."""
+    distinct = _rand_points(_DISTINCT)
+    return (distinct * 17)[:_TABLE_N - 2] + [None, None]
+
+
+@pytest.fixture(scope="module")
+def table_ctxs(table_bases):
+    """{c: (context with the table, context made to keep the ladder)}."""
+    mp = pytest.MonkeyPatch()
+    out = {}
+    try:
+        for c in msm_jax.C_CHOICES:
+            mp.setattr(msm_jax.MsmContext, "_C_BATCH", c)
+            with_table = msm_jax.MsmContext(table_bases)
+            mp.setattr(msm_jax, "_TABLE_BYTES_BUDGET", 0)
+            ladder = msm_jax.MsmContext(table_bases)
+            mp.setattr(msm_jax, "_TABLE_BYTES_BUDGET", 2 << 30)
+            assert with_table.table is not None and ladder.table is None
+            assert with_table.c_batch == ladder.c_batch == c
+            out[c] = (with_table, ladder)
+    finally:
+        mp.undo()
+    return out
+
+
+def _table_rows(t, wins):
+    """A table half (n/8, 8, 24*W) -> [[int of window w] * W] * n, out of
+    Montgomery form."""
+    import numpy as np
+    from distributed_plonk_tpu.constants import Q_MOD
+    from distributed_plonk_tpu.backend.limbs import limbs_to_ints
+
+    t = np.asarray(t)
+    n = t.shape[0] * t.shape[1]
+    limbs = t.reshape(n, 24, wins).transpose(1, 0, 2).reshape(24, n * wins)
+    ints = [v * CJ._MONT_R_INV % Q_MOD for v in limbs_to_ints(limbs)]
+    return [ints[i * wins:(i + 1) * wins] for i in range(n)]
+
+
+@pytest.mark.parametrize("c", msm_jax.C_CHOICES)
+def test_window_table_is_the_host_oracles_multiples(table_bases, table_ctxs,
+                                                    c):
+    """T[w][i] = 2^(c*w) * P_i by curve.py's doubling, for every window and
+    every point; the padding's identities stay identities (zeros under the
+    one mask) in every window."""
+    import numpy as np
+
+    ctx = table_ctxs[c][0]
+    wins = -(-256 // c)
+    assert ctx.padded_n == _TABLE_N
+    assert ctx.table[0].shape == (_TABLE_N // 8, 8, 24 * wins)
+    assert ctx.table[0].nbytes + ctx.table[1].nbytes \
+        == msm_jax.table_bytes(_TABLE_N, c)
+    want = {}
+    for p in table_bases[:_DISTINCT]:
+        col, q = [], p
+        for _ in range(wins):
+            col.append(q)
+            for _ in range(c):
+                q = C.g1_add_affine(q, q)
+        want[p] = col
+    xs, ys = _table_rows(ctx.table[0], wins), _table_rows(ctx.table[1], wins)
+    inf = np.asarray(ctx.point[2])
+    for i, p in enumerate(table_bases):
+        if p is None:
+            assert inf[i] and not any(xs[i]) and not any(ys[i])
+        else:
+            assert not inf[i]
+            assert list(zip(xs[i], ys[i])) == want[p], i
+
+
+def _edge_polys(c, batch, n):
+    """`batch` scalar lists over an n-point key: random full-length ones,
+    then (as the batch allows) one shorter than the key that carries the
+    extreme digits, and one of zeros."""
+    half = 1 << (c - 1)
+    edge = [half, half - 1, half << c, (half - 1) << c, 0, 1, R_MOD - 1,
+            R_MOD - half]
+    polys = [[RNG.randrange(R_MOD) for _ in range(n)] for _ in range(batch)]
+    if batch >= 2:
+        polys[1] = edge + [RNG.randrange(R_MOD) for _ in range(n // 3)]
+    if batch >= 3:
+        polys[2] = [0] * n
+    return polys
+
+
+def test_extreme_digits_are_in_the_edge_polynomial():
+    import numpy as np
+
+    for c, recode in ((7, msm_jax.signed_digits7_of_scalars),
+                      (8, msm_jax.signed_digits_of_scalars)):
+        half = 1 << (c - 1)
+        d = recode(_edge_polys(c, 2, 64)[1], 64).astype(np.int64) - half
+        assert d.min() == -half and d.max() == half - 1
+
+
+@pytest.mark.parametrize("c,batch", [
+    (7, 1), (7, 2), (7, 5), (8, 2),
+    pytest.param(8, 1, marks=pytest.mark.tier2),
+    pytest.param(8, 5, marks=pytest.mark.tier2)])
+def test_commit_through_the_table_is_the_oracles_and_the_ladders(
+        table_bases, table_ctxs, c, batch):
+    """A batched commit served from the table equals the host oracle's MSM
+    (what PythonBackend commits with), point for point: zero scalars, the
+    extreme digits (-64, 63; -128, 127) and a polynomial shorter than the
+    key included. At B = 2, the batch that holds the extreme digits, also
+    the ladder path's (every other batch too under DPT_TIER2=1: a batch
+    width is a compile of the pipeline for each path, half a minute of
+    XLA:CPU each; c = 8 at B = 1 and 5 likewise, and the mesh tests
+    commit through a c = 8 table in tier 1)."""
+    import os
+
+    with_table, ladder = table_ctxs[c]
+    polys = _edge_polys(c, batch, _TABLE_N - 2)
+    got = with_table.msm_many(polys)
+    assert got == [C.g1_msm(table_bases[:len(s)], s) for s in polys]
+    if batch == 2 or os.environ.get("DPT_TIER2") == "1":
+        assert got == ladder.msm_many(polys)
+    if batch >= 3:
+        assert got[2] is None
+
+
+def _scan_lengths(jaxpr):
+    """Lengths of every lax.scan in a jaxpr, nested ones included, and the
+    number of while loops (a fori_loop or a scan lowered otherwise)."""
+    scans, whiles = [], 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            scans.append(eqn.params["length"])
+        whiles += eqn.primitive.name == "while"
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    s, w = _scan_lengths(inner)
+                    scans += s
+                    whiles += w
+    return scans, whiles
+
+
+@pytest.mark.parametrize("c", msm_jax.C_CHOICES)
+def test_finish_of_a_table_context_is_the_running_sum_alone(table_ctxs, c):
+    """Structure, so that the ladder cannot come back unseen: the
+    `msm_finish` a table context runs holds ONE scan, of buckets + 1 steps
+    (65 at c=7, 129 at c=8); the ladder context's holds the same scan and
+    c * (W - 1) + log2(W) steps more."""
+    import jax.numpy as jnp
+
+    with_table, ladder = table_ctxs[c]
+    wins, buckets, batch = -(-256 // c), 1 << (c - 1), 2
+
+    def planes(lanes):
+        return [jax.ShapeDtypeStruct((24, lanes, buckets), jnp.uint32)] * 3
+
+    fn = with_table._finish_fn(batch)
+    assert fn.__name__ == "msm_finish"
+    assert _scan_lengths(jax.make_jaxpr(fn)(*planes(batch)).jaxpr) \
+        == ([buckets + 1], 0)
+    tree = (wins - 1).bit_length()
+    assert _scan_lengths(
+        jax.make_jaxpr(ladder._finish_fn(batch))(*planes(batch * wins)).jaxpr
+    ) == ([buckets + 1, c * (wins - 1) + tree], 0)
+    # and the scan program gives the finish one plane a polynomial
+    digits = jax.ShapeDtypeStruct((batch, wins, _TABLE_N), jnp.uint32)
+    group = msm_jax._group_size_batch(_TABLE_N, batch, c, signed=True)
+    out = jax.eval_shape(with_table._chunk_fn(_TABLE_N, group),
+                         *with_table.table, with_table.point[2], digits)
+    assert [o.shape for o in out] == [(24, batch, buckets)] * 3

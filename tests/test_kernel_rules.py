@@ -67,6 +67,146 @@ def test_auto_rule(knob_free, backend, decision):
     assert resolve() == want[backend]
 
 
+# --- the window table's rule ---------------------------------------------------
+# msm_jax.use_window_table(signed, kernel, n, c): what a commit context can
+# observe, and nothing else, decides whether it holds 2^(c*w) * P_i for
+# every window (and so ends a commit in the bucket running sum alone).
+
+_GIB = 1 << 30
+
+_TABLE_RULE = {
+    # the served key: 16,416 points at c = 7 is 117 MB of table
+    "served-2p14": ((True, "xla", 16416, 7), True),
+    "mesh-2p14-c8": ((True, "xla", 16448, 8), True),
+    # the source's own 2^18 is inside the budget, 2^20 (7.5 GB) outside
+    "source-2p18": ((True, "xla", (1 << 18) + 32, 7), True),
+    "over-budget-2p20": ((True, "xla", (1 << 20) + 32, 7), False),
+    # the unsigned small-window path (a key under 256 points)
+    "unsigned": ((False, "xla", 200, 4), False),
+    # the fused Pallas scan takes one point a step
+    "pallas-kernel": ((True, "pallas", 16416, 7), False),
+}
+
+
+@pytest.mark.parametrize("row", sorted(_TABLE_RULE))
+def test_window_table_rule(knob_free, row):
+    args, want = _TABLE_RULE[row]
+    assert MJ.use_window_table(*args) is want
+
+
+def test_window_table_budget_is_bytes_of_the_table():
+    assert MJ.table_bytes(16416, 7) == 37 * 16416 * 192 == 116_619_264
+    assert MJ.table_bytes((1 << 18) + 32, 7) < MJ._TABLE_BYTES_BUDGET \
+        == 2 * _GIB < MJ.table_bytes((1 << 20) + 32, 7)
+
+
+def _ctx_wide_key(mp):
+    ctx = MJ.MsmContext([(1, 2)] * 300)
+    assert ctx.signed and ctx.padded_n == 304      # whole 8-row tiles
+    assert ctx.table is not None and ctx._preweighted()
+    assert ctx.table[0].shape == (304 // 8, 8, 24 * 37)
+    # a kernel flipped after the build: the table stays, and is not served
+    mp.setattr(MJ, "_MSM_KERNEL", "pallas")
+    assert not ctx._preweighted()
+
+
+def _ctx_small_key(mp):
+    ctx = MJ.MsmContext([(1, 2)] * 8)
+    assert not ctx.signed and ctx.table is None and not ctx._preweighted()
+
+
+def _ctx_pallas_kernel(mp):
+    mp.setattr(MJ, "_MSM_KERNEL", "pallas")
+    ctx = MJ.MsmContext([(1, 2)] * 300)
+    assert ctx.signed and ctx.table is None and not ctx._preweighted()
+
+
+def _ctx_over_budget(mp):
+    mp.setattr(MJ, "_TABLE_BYTES_BUDGET", MJ.table_bytes(304, 7) - 1)
+    assert MJ.MsmContext([(1, 2)] * 300).table is None
+    mp.setattr(MJ, "_TABLE_BYTES_BUDGET", MJ.table_bytes(304, 7))
+    assert MJ.MsmContext([(1, 2)] * 300).table is not None
+
+
+@pytest.mark.parametrize("case", [
+    _ctx_wide_key, _ctx_small_key, _ctx_pallas_kernel, _ctx_over_budget],
+    ids=lambda f: f.__name__[len("_ctx_"):])
+def test_window_table_follows_the_rule_in_a_context(knob_free, case):
+    case(knob_free)
+
+
+def _preweighted_pct(before, after):
+    """`msm_preweighted_pct` as the benchmark reads it: its data file
+    through the `service_metric` reader over two METRICS snapshots."""
+    import json
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark.lib import readers
+    with open(os.path.join(repo, "benchmark", "layer_metrics",
+                           "msm_preweighted_pct.json")) as f:
+        spec = json.load(f)
+    assert spec == {"kind": "service_metric",
+                    "counter": "msm_commit_polys_preweighted",
+                    "percent_of": "msm_commit_polys",
+                    "layer": "kernels", "moves": "proofs_per_s"}
+    return readers.read_service_metric(
+        spec, readers.Evidence(metrics_open=before, metrics_close=after))
+
+
+def test_commit_counters_reach_the_service_and_read_as_a_share(knob_free):
+    """The one-chip half of ISSUE 36's counter: a pool worker hands its
+    JaxBackend the service's Metrics when it starts (`attach`, the hook a
+    leased MeshBackend already had), the backend's commit contexts count
+    `msm_commit_polys` and, when the window table served the commit,
+    `msm_commit_polys_preweighted`, and the benchmark's data file reads
+    the share: 100 where every commit came from a table, less where a
+    narrow key's did not, nothing where no commit ran. (A served prove at
+    a toy size commits over a narrow key: test_chip_smoke reads its 13
+    polynomials and a share of 0.)"""
+    from distributed_plonk_tpu import curve as C
+    from distributed_plonk_tpu.backend.jax_backend import JaxBackend
+    from distributed_plonk_tpu.service import ProofService
+
+    be = JaxBackend()
+    assert be.metrics is None
+    svc = ProofService(port=0, prover_workers=1,
+                       backend_factory=lambda: be).start()
+    try:
+        for _ in range(600):
+            if be.metrics is not None:
+                break
+            threading.Event().wait(0.05)
+        assert be.metrics is svc.metrics
+        rng = random.Random(36)
+        pts = [C.g1_mul(C.G1_GEN, rng.randrange(1, 1 << 200))
+               for _ in range(4)]
+        wide, narrow = (pts * 64)[:256], pts * 2
+        polys = [[rng.randrange(1 << 250) for _ in range(200)], [5, 0, 7]]
+        s0 = svc.metrics.snapshot()
+        assert _preweighted_pct(s0, s0) is None         # no commit ran
+        assert "msm_commit_polys" not in s0["counters"]
+        assert be.commit_many(wide, polys) == [
+            C.g1_msm(wide[:len(p)], p) for p in polys]
+        s1 = svc.metrics.snapshot()
+        assert s1["counters"]["msm_commit_polys"] == 2
+        assert s1["counters"]["msm_commit_polys_preweighted"] == 2
+        assert _preweighted_pct(s0, s1) == 100.0
+        assert _preweighted_pct(s1, s1) is None
+        be.commit_many(narrow, [p[:8] for p in polys])   # an 8-point key
+        s2 = svc.metrics.snapshot()
+        assert s2["counters"]["msm_commit_polys"] == 4
+        assert s2["counters"]["msm_commit_polys_preweighted"] == 2
+        assert _preweighted_pct(s0, s2) == 50.0
+        assert _preweighted_pct(s1, s2) == 0.0
+        # a service of the parent commit has neither counter
+        assert _preweighted_pct({"counters": {"jobs_completed": 0}},
+                                {"counters": {"jobs_completed": 9}}) is None
+    finally:
+        svc.shutdown()
+
+
 # --- an explicit knob wins over the rule --------------------------------------
 
 def _inner_mul_width(lanes):

@@ -193,3 +193,48 @@ def test_mesh_msm_programs_are_named_and_its_collective_is_counted(mesh8):
     planes = counted["mesh_all_gather_bytes"] // (8 * 7)
     assert planes * 8 * 7 == counted["mesh_all_gather_bytes"]
     assert planes % (3 * 24 * 4 * ctx.windows) == 0
+
+
+def test_mesh_commit_through_the_window_table_on_four_devices():
+    """ISSUE 36: a mesh context whose per-device slice runs the signed
+    pipeline (272 points a device here) holds the window table of its key,
+    dealt like the points with the window axis whole; it commits what
+    `MsmContext` and the host oracle commit, a chip adds its windows'
+    planes BEFORE the all_gather, which therefore moves one (24, B, 128)
+    plane a coordinate and not thirty-two, and the finish on the one chip
+    is the running sum alone. Both commit counters count every polynomial."""
+    from distributed_plonk_tpu.backend import msm_jax
+    from test_curve_msm_jax import _scan_lengths
+
+    n, d, batch = 1030, 4, 2
+    distinct = [C.g1_mul(C.G1_GEN, RNG.randrange(1, R_MOD)) for _ in range(12)]
+    bases = (distinct * 86)[:n - 2] + [None, None]
+    counted = {}
+
+    def count(name, by=1):
+        counted[name] = counted.get(name, 0) + by
+
+    ctx = MeshMsmContext(make_mesh(d, platform="cpu"), bases, count=count)
+    assert (ctx.c, ctx.signed, ctx.local_n) == (8, True, 272)
+    wins, buckets = 32, 128
+    for t in ctx.table:
+        assert t.shape == (d, ctx.local_n // 8, 8, 24 * wins)
+        assert t.sharding.spec == jax.sharding.PartitionSpec(
+            "shards", None, None, None)
+        assert {s.data.shape for s in t.addressable_shards} == {
+            (1, ctx.local_n // 8, 8, 24 * wins)}
+    half = 128
+    polys = [[RNG.randrange(R_MOD) for _ in range(n)],
+             [half, half - 1, 0, 1, R_MOD - 1]
+             + [RNG.randrange(R_MOD) for _ in range(300)]]
+    want = [C.g1_msm(bases[:len(s)], s) for s in polys]
+    assert ctx.msm_many(polys) == want
+    assert msm_jax.MsmContext(bases).msm_many(polys) == want
+    assert counted == {
+        "msm_commit_polys": batch, "msm_commit_polys_preweighted": batch,
+        "mesh_msm_chunks": 1,
+        "mesh_all_gather_bytes": d * (d - 1) * 3 * 24 * batch * buckets * 4}
+    (finish,) = ctx._finish_fns.values()
+    planes = [jax.ShapeDtypeStruct((24, batch, buckets), "uint32")] * 3
+    assert _scan_lengths(jax.make_jaxpr(finish)(*planes).jaxpr) \
+        == ([buckets + 1], 0)
