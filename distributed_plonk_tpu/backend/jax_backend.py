@@ -148,8 +148,9 @@ class JaxBackend:
     def _make_msm_ctx(self, bases):
         """MSM context factory hook (the mesh backend overrides this to
         build a mesh-sharded context; the caching in _ctx is shared).
-        `count=`: the context says how many polynomials it committed and
-        how many of them from its window table."""
+        `count=`: the context says how many polynomials it committed, how
+        many of them from its window table, and in how many calls and
+        bucket-scan device calls."""
         return MsmContext(bases, count=self._count)
 
     def _ctx(self, bases):

@@ -43,8 +43,6 @@ and the number of reduction phases — and every memory access is regular.
 """
 
 import os
-import threading
-import time
 from functools import partial
 
 import numpy as np
@@ -885,8 +883,10 @@ class MsmContext:
     served from the table ends in `finish_preweighted`; without one, in
     `finish`.
 
-    count(name, by): where `msm_commit_polys` and
-    `msm_commit_polys_preweighted` go (JaxBackend._count), if anywhere."""
+    count(name, by): where `msm_commit_polys`,
+    `msm_commit_polys_preweighted`, `msm_commit_calls` (one a
+    `_exec_chunked`) and `msm_commit_chunks` (the bucket-scan device calls
+    it made) go (JaxBackend._count), if anywhere."""
 
     def __init__(self, bases, count=None):
         self._count = count or (lambda name, by=1: None)
@@ -920,7 +920,6 @@ class MsmContext:
             # re-upload the whole sliced key on every _exec_chunked call
             self.point = tuple(jax.device_put(p)
                                for p in points_to_device(bases, pad))
-        self._platform = next(iter(self.point[0].devices())).platform
         self.table = (window_table(*self.point, self.c_batch)
                       if use_window_table(self.signed, self._mode(),
                                           self.padded_n, self.c_batch)
@@ -945,7 +944,6 @@ class MsmContext:
         self._digits_many_fn = FJ.named_jit(
             "msm_digits_many", jax.vmap(self._digits_batch_fn))
         self._chunk_fns = {}
-        self._chunk_calls = {}  # (nc, g) -> times executed (warm detection)
         self._finish_fns = {}
         self._merge_fn = FJ.named_jit(
             "msm_merge", lambda a, b: CJ.proj_add(tuple(a), tuple(b)))
@@ -954,15 +952,12 @@ class MsmContext:
     # rounds 2-5 killed executions in the ~60 s range ("TPU worker process
     # crashed"), observed for single calls at 2^19 points and above on the
     # round-2 integer kernels; whether today's does is not measured, and
-    # the chunking stays until it is. The budget is ADAPTIVE: the first
-    # chunk is timed (fenced by a tiny transfer) and subsequent chunks
-    # resize toward DPT_MSM_CALL_S seconds/call — the f32 kernel rewrite
-    # moved the adds/s rate by an order of magnitude, and a static budget
-    # would either waste dispatches or trip the kill limit.
-    _CALL_ADDS = int(os.environ.get("DPT_MSM_CALL_ADDS", "8000000"))
-    _CALL_TARGET_S = float(os.environ.get("DPT_MSM_CALL_S", "20"))
-    _CALL_ADDS_MAX = int(os.environ.get("DPT_MSM_CALL_ADDS_MAX",
-                                        str(1 << 28)))
+    # the chunking stays until it is. The budget is ONE constant, so a
+    # commit's chunk shapes follow from its shape alone: 2^27 lane-adds is
+    # about 20 s of the v5e's bucket scan (6.3 M lane-adds/s, PERF.md
+    # sec. 5), and every size served today (2^13 to 2^16 points, B <= 8)
+    # is one call under it.
+    _CALL_ADDS = int(os.environ.get("DPT_MSM_CALL_ADDS", str(1 << 27)))
     # default 7 (37 windows x 64 buckets): chip A/B at 2^20
     # (msm_c7_ab_r05.json) measured 29.8 s vs 31.4 s for c=8 (~5%), same
     # result point, both host-oracle-checked at 2^12
@@ -1009,27 +1004,10 @@ class MsmContext:
                 partial(finish_batch, batch=batch, signed=self.signed))
         return self._finish_fns[key]
 
-    # adds/s measured from the first fenced chunk call; class-level so every
-    # context on the process shares the calibration. Keyed by
-    # (platform, signed, c_batch): a CPU-mesh context must not size chunks
-    # from a TPU rate (or a signed rate from an unsigned shape), and the
-    # write is lock-guarded because fleet workers run MSMs from multiple
-    # connection threads.
-    _measured_adds_per_s = {}
-    _calib_lock = threading.Lock()
-
-    def _calib_key(self):
-        # the fused kernel's adds/s is far from the XLA scan's: a rate
-        # latched under one kernel must not size the other's chunks
-        return (self._platform, self.signed, self.c_batch, self._mode())
-
     def _chunk_lanes(self, B, W):
-        """Current per-call point budget (1024-aligned)."""
-        budget = self._CALL_ADDS
-        rate = MsmContext._measured_adds_per_s.get(self._calib_key())
-        if rate is not None:
-            budget = min(self._CALL_ADDS_MAX, int(rate * self._CALL_TARGET_S))
-        return max(1024, (budget // (B * W)) & ~1023)
+        """Points a device call takes of a commit of B polynomials in W
+        windows (1024-aligned)."""
+        return max(1024, (self._CALL_ADDS // (B * W)) & ~1023)
 
     def _exec_chunked(self, digits):
         """digits (B, W, padded_n) -> ((24, B),)*3 totals, in as many
@@ -1045,45 +1023,23 @@ class MsmContext:
         else:
             (ax, ay), axis, tile = self.point[:2], 1, 1
         self._count("msm_commit_polys", B)
+        self._count("msm_commit_calls")
 
         def cut(a, i0, nc, axis=0, tile=1):
             return a if nc == tile * a.shape[axis] else lax.slice_in_dim(
                 a, i0 // tile, (i0 + nc) // tile, axis=axis)
 
+        chunk = self._chunk_lanes(B, W)
         acc = None
-        i0 = 0
-        while i0 < n:
-            chunk = self._chunk_lanes(B, W)
+        for i0 in range(0, n, chunk):
             nc = min(chunk, n - i0)
             g = _group_size_batch(nc, B, -(-SCALAR_BITS // W),
                                   signed=self.signed, kernel=self._mode())
-            fn = self._chunk_fn(nc, g)
-            # calibrate once, on a WARM shape only: a first call's
-            # wall-clock is dominated by XLA compilation and would wildly
-            # under-read the device rate.
-            warm = self._chunk_calls.get(self._chunk_key(nc, g), 0) > 0
-            calibrate = (self._calib_key() not in
-                         MsmContext._measured_adds_per_s
-                         and nc >= 8192 and warm)
-            if calibrate:
-                if acc is not None:  # drain queued async work first, or
-                    np.asarray(acc[0][:1, :1, :1])  # dt covers prior chunks
-                t0 = time.perf_counter()
-            part = fn(cut(ax, i0, nc, axis, tile), cut(ay, i0, nc, axis, tile),
-                      cut(ainf, i0, nc), cut(digits, i0, nc, 2))
-            if calibrate:
-                np.asarray(part[0][:1, :1, :1])  # fence (tiny transfer)
-                # clamp: a sub-latency reading still LATCHES (at an
-                # optimistic rate bounded by _CALL_ADDS_MAX) so the fence
-                # never re-runs on later chunks
-                dt = max(time.perf_counter() - t0, 0.02)
-                with MsmContext._calib_lock:
-                    MsmContext._measured_adds_per_s.setdefault(
-                        self._calib_key(), B * W * nc / dt)
-            ck = self._chunk_key(nc, g)
-            self._chunk_calls[ck] = self._chunk_calls.get(ck, 0) + 1
+            part = self._chunk_fn(nc, g)(
+                cut(ax, i0, nc, axis, tile), cut(ay, i0, nc, axis, tile),
+                cut(ainf, i0, nc), cut(digits, i0, nc, 2))
+            self._count("msm_commit_chunks")
             acc = part if acc is None else tuple(self._merge_fn(acc, part))
-            i0 += nc
         return self._finish_fn(B)(*acc)
 
     def aot_compile(self, batch_sizes=(1,), digit_widths=None):
@@ -1095,12 +1051,10 @@ class MsmContext:
         one shape and cost a real bucket-scan pass). Executables land in
         the persistent compilation cache like the NTT AOT path.
 
-        Chunk/finish/merge shapes match a COLD context's first calls (the
-        adaptive chunk budget resizes once the adds/s calibration latches,
-        so post-calibration chunk shapes still compile at runtime; warmup's
-        job is the cold start, where compile time dominates). Digit
-        extraction jit-caches per EXACT handle width, so `digit_widths`
-        must be the coefficient-handle widths the caller will commit
+        Chunk/finish/merge shapes are those of a full-width commit's first
+        chunk (`_chunk_lanes`). Digit extraction jit-caches per EXACT
+        handle width, so `digit_widths` must be the coefficient-handle
+        widths the caller will commit
         (`warm_stages` passes the prover's n+2/n+3 blinded widths);
         default: this key's full padded width.
 
@@ -1215,11 +1169,9 @@ class MsmContext:
         every host-side projective decode moves into the returned
         _MsmPending's force(). This is the async commit path: the
         pipelined prover dispatches a member's round commits, then runs
-        another member's host work before forcing. The one exception is
-        the calibration fence below, which must block either way — a
-        fence-drained batch rides the pending as already-decoded points."""
+        another member's host work before forcing."""
         # one entry per batch chunk, in item order; a drain rewrites the
-        # entry in place so deferred and eager decodes can interleave
+        # entry in place
         parts = []  # ["dev", batch_width, device totals] | ["done", points]
         pending = None  # last parts entry still awaiting decode
         batch_chunk = chunk or self._BATCH_CHUNK
@@ -1229,15 +1181,6 @@ class MsmContext:
                 part[:] = ["done", _decode_totals(part[1], part[2])]
 
         for i in range(0, len(items), batch_chunk):
-            # until the one-shot adds/s calibration has latched, drain the
-            # previous batch BEFORE launching (old behavior): otherwise the
-            # calibration fence inside _exec_chunked would time the timed
-            # chunk PLUS the whole queued previous batch and latch a
-            # permanently under-read rate
-            if (pending is not None and self._calib_key()
-                    not in MsmContext._measured_adds_per_s):
-                drain(pending)
-                pending = None
             part_items = items[i:i + batch_chunk]
             if stacked and len({it.shape for it in part_items}) == 1:
                 digits = self._digits_many_fn(jnp.stack(part_items))
@@ -1304,8 +1247,7 @@ def _decode_totals(B, totals):
 class _MsmPending:
     """Deferred MSM results from _run_batches(defer=True): every launch is
     already enqueued; force() walks the batch parts in item order and
-    performs the host-side decodes (parts the calibration fence already
-    drained pass through). Exactly one consumer forces — the prover
+    performs the host-side decodes. Exactly one consumer forces — the prover
     member's host-finalize."""
 
     __slots__ = ("_parts",)

@@ -36,9 +36,8 @@ Kernel dispatch and device tuning (backend/, parallel/):
     DPT_MSM_GROUP_MAX         max MSM group size (512)
     DPT_MSM_PLANE_MB          bucket-plane HBM budget in MB (1536)
     DPT_MSM_PALLAS_VMEM_MB    pallas MSM VMEM budget in MB
-    DPT_MSM_CALL_ADDS         target bucket adds per device call (8e6)
-    DPT_MSM_CALL_ADDS_MAX     hard cap on adds per device call
-    DPT_MSM_CALL_S            target seconds per MSM device call (20)
+    DPT_MSM_CALL_ADDS         lane-add budget of one MSM device call (2^27;
+                              per device on a mesh: 8e6)
     DPT_BUCKET_UPDATE         bucket update strategy: auto|onehot|put
     DPT_PLANE_PACK            packed bucket planes (1)
     DPT_FIXED_BASE_CHUNK      fixed-base table build chunk size

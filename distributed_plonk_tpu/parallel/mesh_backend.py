@@ -60,8 +60,9 @@ service Metrics), nothing of its own:
     from the all-gather program and both from the inherited round math;
   - counters, in the attached Metrics (none without one):
     `mesh_ntt_calls`, `mesh_ntt_sharded`, `mesh_all_to_all_bytes`,
-    `mesh_msm_chunks`, `mesh_all_gather_bytes`, and the two every commit
-    context counts, `msm_commit_polys` / `msm_commit_polys_preweighted`.
+    `mesh_msm_chunks`, `mesh_all_gather_bytes`, and the four every commit
+    context counts, `msm_commit_polys` / `msm_commit_polys_preweighted` /
+    `msm_commit_calls` / `msm_commit_chunks`.
 """
 
 import functools

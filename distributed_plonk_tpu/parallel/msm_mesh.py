@@ -78,8 +78,9 @@ class MeshMsmContext:
 
     def __init__(self, mesh, bases, count=None):
         # count(name, by): where `mesh_msm_chunks`, `mesh_all_gather_bytes`,
-        # `msm_commit_polys` and `msm_commit_polys_preweighted` go
-        # (MeshBackend._count), if anywhere
+        # `msm_commit_polys`, `msm_commit_polys_preweighted`,
+        # `msm_commit_calls` (one an `_exec`) and `msm_commit_chunks` (its
+        # bucket-scan device calls) go (MeshBackend._count), if anywhere
         self._count = count or (lambda name, by=1: None)
         self.mesh = mesh
         self.d = d = mesh.devices.size
@@ -257,6 +258,7 @@ class MeshMsmContext:
         W = self.windows
         ax, ay, ainf = self.point
         self._count("msm_commit_polys", B)
+        self._count("msm_commit_calls")
         if self.table is not None:
             self._count("msm_commit_polys_preweighted", B)
         chunk = max(16, (self._CALL_ADDS // (B * W)) & ~15)
@@ -275,6 +277,7 @@ class MeshMsmContext:
             part = fn(*bases, ainf[:, j0:j0 + jc],
                       digits[:, :, :, j0:j0 + jc])
             self._count("mesh_msm_chunks")
+            self._count("msm_commit_chunks")
             # the all_gather: each of d chips takes the other d-1 chips'
             # bucket planes, which have the shape of the folded `part`
             self._count("mesh_all_gather_bytes",

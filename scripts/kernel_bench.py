@@ -70,15 +70,13 @@ def bench_msm(log_n, reps=2):
     bases = (distinct * (n // len(distinct) + 1))[:n]
     ctx = MsmContext(bases)
     scalars = [rng.randrange(R_MOD) for _ in range(n)]
-    ctx.msm(scalars)  # compile + warm + adaptive-chunk calibration
+    ctx.msm(scalars)  # compile + warm
     t0 = time.perf_counter()
     for _ in range(reps):
         ctx.msm(scalars)
     dt = (time.perf_counter() - t0) / reps
     return {"kernel": f"msm_2p{log_n}", "s": round(dt, 3),
-            "points_per_s": round(n / dt),
-            "adds_per_s_calibrated": {
-                str(k): v for k, v in MsmContext._measured_adds_per_s.items()}}
+            "points_per_s": round(n / dt)}
 
 
 def bench_ntt(log_n, reps=3):

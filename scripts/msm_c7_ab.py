@@ -37,15 +37,13 @@ got = small.msm(scalars[:1 << 12])
 assert got == C.g1_msm(bases[:1 << 12], scalars[:1 << 12]), "oracle mismatch"
 
 ctx = MsmContext(bases)
-ctx.msm(scalars)  # compile + warm + adaptive calibration
+ctx.msm(scalars)  # compile + warm
 t0 = time.perf_counter()
 pt = ctx.msm(scalars)
 dt = time.perf_counter() - t0
 print("RESULT " + json.dumps({
     "c": MsmContext._C_BATCH, "msm_s": round(dt, 3),
     "points_per_s": round(N / dt),
-    "adds_per_s": {str(k): round(v) for k, v in
-                   MsmContext._measured_adds_per_s.items()},
     "oracle_2p12_ok": True,
     "point_x_mod": pt[0] %% 0xFFFFFFFF if pt else None}))
 """

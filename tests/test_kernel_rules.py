@@ -3,8 +3,9 @@ module attribute (latched from its DPT_* variable at import, patchable
 here) and, where the answer depends on the platform,
 jax.default_backend(). This file is the table of those rules, the
 precedence of an explicit knob over them, the memo keys that follow the
-resolved mode, the MSM chunk budget and its latch, the compile cache's
-partition, and a start of the service and of a joined worker on a store
+resolved mode, the MSM chunk rule (a commit's chunk sizes from its shape
+alone) and its counters, the compile cache's partition, and a start of
+the service and of a joined worker on a store
 that still holds an older run's `autotune:<fp>` artifact."""
 
 import hashlib
@@ -135,9 +136,10 @@ def test_window_table_follows_the_rule_in_a_context(knob_free, case):
     case(knob_free)
 
 
-def _preweighted_pct(before, after):
-    """`msm_preweighted_pct` as the benchmark reads it: its data file
-    through the `service_metric` reader over two METRICS snapshots."""
+def _commit_share(metric, counter, base, before, after):
+    """A commit counter's share as the benchmark reads it: the metric's
+    data file through the `service_metric` reader over two METRICS
+    snapshots."""
     import json
     import sys
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -145,27 +147,29 @@ def _preweighted_pct(before, after):
         sys.path.insert(0, repo)
     from benchmark.lib import readers
     with open(os.path.join(repo, "benchmark", "layer_metrics",
-                           "msm_preweighted_pct.json")) as f:
+                           metric + ".json")) as f:
         spec = json.load(f)
-    assert spec == {"kind": "service_metric",
-                    "counter": "msm_commit_polys_preweighted",
-                    "percent_of": "msm_commit_polys",
+    assert spec == {"kind": "service_metric", "counter": counter,
+                    "percent_of": base,
                     "layer": "kernels", "moves": "proofs_per_s"}
     return readers.read_service_metric(
         spec, readers.Evidence(metrics_open=before, metrics_close=after))
 
 
-def test_commit_counters_reach_the_service_and_read_as_a_share(knob_free):
-    """The one-chip half of ISSUE 36's counter: a pool worker hands its
-    JaxBackend the service's Metrics when it starts (`attach`, the hook a
-    leased MeshBackend already had), the backend's commit contexts count
-    `msm_commit_polys` and, when the window table served the commit,
-    `msm_commit_polys_preweighted`, and the benchmark's data file reads
-    the share: 100 where every commit came from a table, less where a
-    narrow key's did not, nothing where no commit ran. (A served prove at
-    a toy size commits over a narrow key: test_chip_smoke reads its 13
-    polynomials and a share of 0.)"""
-    from distributed_plonk_tpu import curve as C
+def _preweighted_pct(before, after):
+    return _commit_share("msm_preweighted_pct", "msm_commit_polys_preweighted",
+                         "msm_commit_polys", before, after)
+
+
+def _chunks_pct(before, after):
+    return _commit_share("msm_chunks_pct", "msm_commit_chunks",
+                         "msm_commit_calls", before, after)
+
+
+@pytest.fixture
+def served_backend():
+    """(backend, service): a started service whose one pool worker has
+    handed its JaxBackend the service's Metrics (`attach`)."""
     from distributed_plonk_tpu.backend.jax_backend import JaxBackend
     from distributed_plonk_tpu.service import ProofService
 
@@ -179,32 +183,48 @@ def test_commit_counters_reach_the_service_and_read_as_a_share(knob_free):
                 break
             threading.Event().wait(0.05)
         assert be.metrics is svc.metrics
-        rng = random.Random(36)
-        pts = [C.g1_mul(C.G1_GEN, rng.randrange(1, 1 << 200))
-               for _ in range(4)]
-        wide, narrow = (pts * 64)[:256], pts * 2
-        polys = [[rng.randrange(1 << 250) for _ in range(200)], [5, 0, 7]]
-        s0 = svc.metrics.snapshot()
-        assert _preweighted_pct(s0, s0) is None         # no commit ran
-        assert "msm_commit_polys" not in s0["counters"]
-        assert be.commit_many(wide, polys) == [
-            C.g1_msm(wide[:len(p)], p) for p in polys]
-        s1 = svc.metrics.snapshot()
-        assert s1["counters"]["msm_commit_polys"] == 2
-        assert s1["counters"]["msm_commit_polys_preweighted"] == 2
-        assert _preweighted_pct(s0, s1) == 100.0
-        assert _preweighted_pct(s1, s1) is None
-        be.commit_many(narrow, [p[:8] for p in polys])   # an 8-point key
-        s2 = svc.metrics.snapshot()
-        assert s2["counters"]["msm_commit_polys"] == 4
-        assert s2["counters"]["msm_commit_polys_preweighted"] == 2
-        assert _preweighted_pct(s0, s2) == 50.0
-        assert _preweighted_pct(s1, s2) == 0.0
-        # a service of the parent commit has neither counter
-        assert _preweighted_pct({"counters": {"jobs_completed": 0}},
-                                {"counters": {"jobs_completed": 9}}) is None
+        yield be, svc
     finally:
         svc.shutdown()
+
+
+def test_commit_counters_reach_the_service_and_read_as_a_share(
+        knob_free, served_backend):
+    """The one-chip half of ISSUE 36's counter: a pool worker hands its
+    JaxBackend the service's Metrics when it starts (`attach`, the hook a
+    leased MeshBackend already had), the backend's commit contexts count
+    `msm_commit_polys` and, when the window table served the commit,
+    `msm_commit_polys_preweighted`, and the benchmark's data file reads
+    the share: 100 where every commit came from a table, less where a
+    narrow key's did not, nothing where no commit ran. (A served prove at
+    a toy size commits over a narrow key: test_chip_smoke reads its 13
+    polynomials and a share of 0.)"""
+    from distributed_plonk_tpu import curve as C
+
+    be, svc = served_backend
+    rng = random.Random(36)
+    pts = [C.g1_mul(C.G1_GEN, rng.randrange(1, 1 << 200)) for _ in range(4)]
+    wide, narrow = (pts * 64)[:256], pts * 2
+    polys = [[rng.randrange(1 << 250) for _ in range(200)], [5, 0, 7]]
+    s0 = svc.metrics.snapshot()
+    assert _preweighted_pct(s0, s0) is None         # no commit ran
+    assert "msm_commit_polys" not in s0["counters"]
+    assert be.commit_many(wide, polys) == [
+        C.g1_msm(wide[:len(p)], p) for p in polys]
+    s1 = svc.metrics.snapshot()
+    assert s1["counters"]["msm_commit_polys"] == 2
+    assert s1["counters"]["msm_commit_polys_preweighted"] == 2
+    assert _preweighted_pct(s0, s1) == 100.0
+    assert _preweighted_pct(s1, s1) is None
+    be.commit_many(narrow, [p[:8] for p in polys])   # an 8-point key
+    s2 = svc.metrics.snapshot()
+    assert s2["counters"]["msm_commit_polys"] == 4
+    assert s2["counters"]["msm_commit_polys_preweighted"] == 2
+    assert _preweighted_pct(s0, s2) == 50.0
+    assert _preweighted_pct(s1, s2) == 0.0
+    # a service of the parent commit has neither counter
+    assert _preweighted_pct({"counters": {"jobs_completed": 0}},
+                            {"counters": {"jobs_completed": 9}}) is None
 
 
 # --- an explicit knob wins over the rule --------------------------------------
@@ -260,7 +280,7 @@ def _knob_msm_c(mp):
     assert MJ.MsmContext([(1, 2)] * 300).c_batch == 7
     mp.setattr(MJ.MsmContext, "_C_BATCH", 8)
     ctx = MJ.MsmContext([(1, 2)] * 300)
-    assert ctx.c_batch == 8 and ctx.signed and ctx._calib_key()[2] == 8
+    assert ctx.c_batch == 8 and ctx.signed
     # a tiny key keeps the unsigned small-window scan whatever the knob
     assert MJ.MsmContext([(1, 2)] * 8).c_batch == MJ.window_bits(8)
 
@@ -338,61 +358,185 @@ def _site_msm_chunk(mp):
     assert ctx._chunk_fn(8, 4) is fx
 
 
-def _site_msm_calib(mp):
+def _site_msm_finish(mp):
     ctx = MJ.MsmContext([(1, 2)] * 300)
-    kx = ctx._calib_key()
-    assert kx == (ctx._platform, True, 7, "xla")
-    mp.setattr(MJ, "_MSM_KERNEL", "pallas")
-    assert ctx._calib_key() == kx[:3] + ("pallas",)
+    assert ctx._preweighted()
+    fx = ctx._finish_fn(5)
+    mp.setattr(MJ, "_MSM_KERNEL", "pallas")     # the table was built for xla
+    assert not ctx._preweighted()
+    fp = ctx._finish_fn(5)
+    assert fp is not fx and set(ctx._finish_fns) == {(5, True), (5, False)}
+    mp.setattr(MJ, "_MSM_KERNEL", "xla")
+    assert ctx._finish_fn(5) is fx
 
 
 @pytest.mark.parametrize("site", [
     _site_ntt_plan, _site_mesh_ntt_plan, _site_stage_kernels,
-    _site_msm_chunk, _site_msm_calib],
+    _site_msm_chunk, _site_msm_finish],
     ids=lambda f: f.__name__[len("_site_"):])
 def test_memo_key_follows_resolved_mode(knob_free, site):
     site(knob_free)
 
 
-# --- the MSM chunk budget and its latch ---------------------------------------
+# --- the MSM chunk rule: a commit's chunk sizes from its shape alone ----------
 
-B, W = 5, 37
-
-
-@pytest.fixture
-def ctx(knob_free):
-    """A wide-window context and an empty class-level latch."""
-    knob_free.setattr(MJ.MsmContext, "_measured_adds_per_s", {})
-    return MJ.MsmContext([(1, 2)] * 300)
+W = 37            # windows of a 255-bit scalar at c = 7
+SPLIT_ADDS = 80_000   # a budget under which a 1,104-point commit is one
+#                       call at B = 1 and two (1,024 + 80) at B = 5
 
 
-def _aligned(budget):
-    return max(1024, (budget // (B * W)) & ~1023)
+def _chunks(budget, n, B):
+    """The chunk sizes `_exec_chunked` cuts a commit of n points into."""
+    chunk = max(1024, (budget // (B * W)) & ~1023)
+    return [min(chunk, n - i0) for i0 in range(0, n, chunk)]
 
 
-def test_chunk_budget_unlatched(ctx):
-    assert ctx._chunk_lanes(B, W) == _aligned(ctx._CALL_ADDS)
-    assert ctx._chunk_lanes(B, W) % 1024 == 0
+class _Counted:
+    """A wide-window context over real points that counts into a dict."""
+
+    def __init__(self, n=1100):
+        from distributed_plonk_tpu import curve as C
+        rng = random.Random(38)
+        pts = [C.g1_mul(C.G1_GEN, rng.randrange(1, 1 << 200))
+               for _ in range(4)]
+        self.bases = (pts * (n // 4 + 1))[:n]
+        self.counted = {}
+        self.ctx = MJ.MsmContext(self.bases, count=self._count)
+        self.polys = [[rng.randrange(1 << 250) for _ in range(n - 7 * j)]
+                      for j in range(5)]
+
+    def _count(self, name, by=1):
+        self.counted[name] = self.counted.get(name, 0) + by
+
+    def commit(self, polys):
+        """(points, device calls made) of one `_exec_chunked`."""
+        before = dict(self.counted)
+        out = self.ctx.msm_many(polys)
+        assert self.counted["msm_commit_calls"] \
+            == before.get("msm_commit_calls", 0) + 1
+        return out, (self.counted["msm_commit_chunks"]
+                     - before.get("msm_commit_chunks", 0))
 
 
-@pytest.mark.parametrize("rate", [3e6, 1e12], ids=["rate", "rate-capped"])
-def test_chunk_budget_latched(ctx, rate):
-    MJ.MsmContext._measured_adds_per_s[ctx._calib_key()] = rate
-    want = min(ctx._CALL_ADDS_MAX, int(rate * ctx._CALL_TARGET_S))
-    assert ctx._chunk_lanes(B, W) == _aligned(want)
-    assert ctx._chunk_lanes(B, W) != _aligned(ctx._CALL_ADDS)
+@pytest.fixture(scope="module")
+def counted():
+    return _Counted()
 
 
-def test_chunk_budget_floor(ctx):
-    MJ.MsmContext._measured_adds_per_s[ctx._calib_key()] = 1.0
-    assert ctx._chunk_lanes(B, W) == 1024
-    assert ctx._chunk_lanes(64, 64) == 1024
+def _rule_served_shapes(mp, counted):
+    # the commit keys of the sizes served today (2^13, 2^14, 2^16: n + 3
+    # points padded to whole tiles), at every batch width a prove or a
+    # batch of proves commits: one device call each
+    assert MJ.MsmContext._CALL_ADDS == 1 << 27
+    for n in (8224, 16416, 65568):
+        for B in (1, 2, 5, 8):
+            assert counted.ctx._chunk_lanes(B, W) >= n
+            assert _chunks(1 << 27, n, B) == [n]
 
 
-def test_chunk_budget_other_kernels_rate_unused(ctx):
-    other = ctx._calib_key()[:3] + ("pallas",)
-    MJ.MsmContext._measured_adds_per_s[other] = 1e12
-    assert ctx._chunk_lanes(B, W) == _aligned(ctx._CALL_ADDS)
+def _rule_is_the_shape_alone(mp, counted):
+    # one expression of (budget, B, W): no context state, no history
+    other = MJ.MsmContext([(1, 2)] * 8)      # a narrow key, no table
+    for B, w in ((1, 37), (5, 37), (8, 32), (64, 64)):
+        want = max(1024, ((1 << 27) // (B * w)) & ~1023)
+        assert counted.ctx._chunk_lanes(B, w) == want
+        assert other._chunk_lanes(B, w) == want
+    assert not any("adds_per_s" in k or "calib" in k
+                   for k in vars(MJ.MsmContext))
+
+
+def _rule_floor(mp, counted):
+    mp.setattr(MJ.MsmContext, "_CALL_ADDS", 1)
+    assert counted.ctx._chunk_lanes(5, W) == 1024
+    mp.setattr(MJ.MsmContext, "_CALL_ADDS", 1 << 27)
+    assert counted.ctx._chunk_lanes(1 << 20, 64) == 1024
+
+
+def _rule_alignment(mp, counted):
+    for budget in (8_000_000, 12_345_678, 1 << 27):
+        mp.setattr(MJ.MsmContext, "_CALL_ADDS", budget)
+        for B in (1, 2, 5, 8):
+            lanes = counted.ctx._chunk_lanes(B, W)
+            assert lanes % 1024 == 0 and lanes * B * W <= budget
+            assert (lanes + 1024) * B * W > budget
+    # the budget this tree had before: a 2^16 commit at B = 5 was two calls
+    assert _chunks(8_000_000, 65568, 5) == [43008, 22560]
+
+
+def _rule_budget_is_the_one_knob(mp, counted):
+    # what DPT_MSM_CALL_ADDS sets, on one chip and (per device) on a mesh
+    from distributed_plonk_tpu.parallel.msm_mesh import MeshMsmContext
+    assert MeshMsmContext._CALL_ADDS == 8_000_000
+    mp.setattr(MJ.MsmContext, "_CALL_ADDS", SPLIT_ADDS)
+    n = counted.ctx.padded_n
+    assert n == 1104
+    assert _chunks(SPLIT_ADDS, n, 1) == [n]
+    assert _chunks(SPLIT_ADDS, n, 5) == [1024, 80]
+    assert counted.ctx._chunk_lanes(5, W) == 1024
+
+
+def _rule_a_split_commit_equals_the_one_call(mp, counted):
+    from distributed_plonk_tpu import curve as C
+    whole, calls = counted.commit(counted.polys)
+    assert calls == 1
+    mp.setattr(MJ.MsmContext, "_CALL_ADDS", SPLIT_ADDS)
+    split, calls = counted.commit(counted.polys)
+    assert calls == 2
+    assert split == whole == [C.g1_msm(counted.bases[:len(p)], p)
+                              for p in counted.polys]
+
+
+@pytest.mark.parametrize("case", [
+    _rule_served_shapes, _rule_is_the_shape_alone, _rule_floor,
+    _rule_alignment, _rule_budget_is_the_one_knob,
+    _rule_a_split_commit_equals_the_one_call],
+    ids=lambda f: f.__name__[len("_rule_"):])
+def test_chunk_rule(knob_free, counted, case):
+    case(knob_free, counted)
+
+
+@pytest.mark.parametrize("order", [(5, 1, 5, 1), (1, 5, 1, 5)],
+                         ids=["wide-first", "narrow-first"])
+def test_chunks_do_not_follow_the_process_history(knob_free, counted, order):
+    """What the rate latch got wrong: a process whose first warm call was
+    slow (a compile, another job's queued work) ran every later commit in
+    several small chunks. Here the process first runs a commit and waits
+    for it on the host, the fence the latch timed; then commits at B = 5
+    and B = 1 in either order make the device calls their shapes give, and
+    give the same points."""
+    knob_free.setattr(MJ.MsmContext, "_CALL_ADDS", SPLIT_ADDS)
+    want = {5: 2, 1: 1}
+    first, calls = counted.commit(counted.polys)        # slow, fenced
+    assert calls == want[5]
+    for B in order:
+        got, calls = counted.commit(counted.polys[:B])
+        assert calls == want[B]
+        assert got == first[:B]
+
+
+def test_chunk_counters_reach_the_service_and_read_as_a_share(
+        knob_free, counted, served_backend):
+    """`msm_commit_calls` and `msm_commit_chunks` through a started
+    service, read by the benchmark's data file: 100 when every commit of
+    the window was one device call, 200 when each was two."""
+    be, svc = served_backend
+    s0 = svc.metrics.snapshot()
+    assert _chunks_pct(s0, s0) is None              # no commit ran
+    whole = be.commit_many(counted.bases, counted.polys)
+    s1 = svc.metrics.snapshot()
+    assert s1["counters"]["msm_commit_calls"] == 1
+    assert s1["counters"]["msm_commit_chunks"] == 1
+    assert _chunks_pct(s0, s1) == 100.0
+    knob_free.setattr(MJ.MsmContext, "_CALL_ADDS", SPLIT_ADDS)
+    assert be.commit_many(counted.bases, counted.polys) == whole
+    s2 = svc.metrics.snapshot()
+    assert s2["counters"]["msm_commit_calls"] == 2
+    assert s2["counters"]["msm_commit_chunks"] == 3
+    assert _chunks_pct(s1, s2) == 200.0
+    assert _chunks_pct(s0, s2) == 150.0
+    # a service of the parent commit has neither counter
+    assert _chunks_pct({"counters": {"msm_commit_polys": 0}},
+                       {"counters": {"msm_commit_polys": 9}}) is None
 
 
 # --- the compile cache's partition --------------------------------------------

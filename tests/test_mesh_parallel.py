@@ -232,7 +232,7 @@ def test_mesh_commit_through_the_window_table_on_four_devices():
     assert msm_jax.MsmContext(bases).msm_many(polys) == want
     assert counted == {
         "msm_commit_polys": batch, "msm_commit_polys_preweighted": batch,
-        "mesh_msm_chunks": 1,
+        "msm_commit_calls": 1, "msm_commit_chunks": 1, "mesh_msm_chunks": 1,
         "mesh_all_gather_bytes": d * (d - 1) * 3 * 24 * batch * buckets * 4}
     (finish,) = ctx._finish_fns.values()
     planes = [jax.ShapeDtypeStruct((24, batch, buckets), "uint32")] * 3

@@ -109,9 +109,12 @@ def test_tcp_round_trip_and_bucket_reuse(service):
             assert header["job_id"] == jid
             assert _verify_wire_result(header, blob)
         m = c.metrics()
-    # two shapes -> exactly two key builds, the same-shape job reused one
+    # two shapes -> exactly two key builds, the same-shape job reused one:
+    # from memory, or, when the second worker took it while the first was
+    # still building (a loaded machine), by waiting on that build's latch
     assert m["counters"]["bucket_misses"] == 2
-    assert m["counters"]["bucket_hits"] >= 1
+    assert (m["counters"]["bucket_hits"]
+            + m["counters"].get("bucket_latch_waits", 0)) >= 1
     assert m["counters"]["jobs_completed"] == 3
     assert "queue_depth" in m["gauges"]
     assert m["histograms"]["job_wait"]["count"] == 3
