@@ -14,7 +14,10 @@ job; this one
     values `rescue.permutation_gadget` would create, in its order) keyed by
     its input state: the tree's digests and the gadget's wire values read
     the same trace, and the gadget finds a node's trace by the child values
-    it arranged, not by where the node sits;
+    it arranged, not by where the node sits. The trace is computed in
+    native code (`runtime.native.RescueTrace`, over `rescue.py`'s own
+    constants), which holds no GIL; `permutation_trace` below is its
+    plain-Python oracle;
   - fills the witness in the order the plain builder creates variables, and
     runs the plain builder's guard, `check_satisfiability()`, on every
     circuit it returns. The guard recomputes each gate from the raw witness
@@ -33,8 +36,9 @@ import threading
 from ..circuit import PlonkCircuit
 from ..constants import R_MOD
 from ..merkle import BRANCH, LEAF_TAG
-from ..rescue import (ALPHA, ALPHA_INV, NUM_ROUNDS, ROUND_KEYS, STATE_WIDTH,
-                      _affine)
+from ..rescue import (ALPHA, ALPHA_INV, MDS, NUM_ROUNDS, ROUND_KEYS,
+                      STATE_WIDTH, _affine)
+from ..runtime.native import RescueTrace
 from ..workload import generate_circuit
 
 # a deployment serves a handful of shapes; past this many the oldest
@@ -43,6 +47,9 @@ MAX_TEMPLATES = 8
 
 _lock = threading.Lock()
 _templates = {}     # (height, num_proofs, num_leaves) -> _Template
+
+# `permutation_trace` in native code, over rescue.py's constants
+_native_trace = RescueTrace(R_MOD, ROUND_KEYS, MDS, ALPHA, ALPHA_INV)
 
 
 class _Template:
@@ -95,16 +102,19 @@ def permutation_trace(state):
 
 
 class _Hasher:
-    """hash3 with a memory: one permutation per distinct input state."""
+    """hash3 with a memory: one permutation per distinct input state, its
+    trace computed natively (`native` counts those)."""
 
     def __init__(self):
         self.traces = {}
+        self.native = 0
 
     def trace(self, a, b, c):
         key = (a, b, c)
         found = self.traces.get(key)
         if found is None:
-            found = self.traces[key] = permutation_trace([a, b, c, 0])
+            found = self.traces[key] = _native_trace([a, b, c, 0])
+            self.native += 1
         return found
 
     def digest(self, a, b, c):
@@ -127,11 +137,11 @@ def _tree_levels(hasher, payloads, height):
 
 
 def _witness(height, num_proofs, payloads):
-    """(witness, root, permutations computed) of the job, in the order
-    `generate_circuit` creates variables: 0, 1, the root, then per proof
-    the payload, the index, the leaf hash's trace, and per level the
-    position bits, their sum, the two siblings, `_select3`'s six values
-    and the node hash's trace."""
+    """(witness, root, permutations computed, of those computed natively)
+    of the job, the witness in the order `generate_circuit` creates
+    variables: 0, 1, the root, then per proof the payload, the index, the
+    leaf hash's trace, and per level the position bits, their sum, the two
+    siblings, `_select3`'s six values and the node hash's trace."""
     hasher = _Hasher()
     levels = _tree_levels(hasher, payloads, height)
     root = levels[-1][0]
@@ -162,7 +172,7 @@ def _witness(height, num_proofs, payloads):
             w += trace
             cur = trace[-STATE_WIDTH]
             idx //= BRANCH
-    return w, root, len(hasher.traces)
+    return w, root, len(hasher.traces), hasher.native
 
 
 def _template(shape, seed):
@@ -195,7 +205,8 @@ def build(params, seed, metrics=None):
     rng = random.Random(seed)
     payloads = [rng.randrange(R_MOD) for _ in range(num_leaves)]
     template, plain = _template((height, num_proofs, num_leaves), seed)
-    witness, root, permutations = _witness(height, num_proofs, payloads)
+    witness, root, permutations, native = _witness(height, num_proofs,
+                                                   payloads)
     ckt = template.circuit(witness, [root])
     # raised, not asserted: the guard is part of what the service promises
     # and must not go with `python -O`
@@ -210,4 +221,5 @@ def build(params, seed, metrics=None):
         metrics.inc("circuit_builds")
         metrics.inc("circuit_template_hits", int(plain is None))
         metrics.inc("circuit_build_permutations", permutations)
+        metrics.inc("circuit_build_permutations_native", native)
     return ckt

@@ -59,6 +59,9 @@ def lib():
         L.dpt_recv_payload.argtypes = [ctypes.c_int, u8p, ctypes.c_uint64]
         L.dpt_set_timeout.argtypes = [ctypes.c_int, ctypes.c_int]
         L.dpt_close.argtypes = [ctypes.c_int]
+        L.rescue_trace.argtypes = [u8p, u8p, u8p, u8p, u8p, ctypes.c_uint64,
+                                   u8p, u8p]
+        L.rescue_trace.restype = ctypes.c_int
     return _lib
 
 
@@ -99,6 +102,55 @@ def transpose(arr):
     out = np.empty((cols, rows), dtype=np.uint32)
     lib().transpose_u32(_u32(arr), rows, cols, _u32(out))
     return out
+
+
+# --- Rescue permutation trace ------------------------------------------------
+
+_FR_BYTES = 32
+
+
+def _elements(values):
+    """Canonical field elements -> one buffer of 32-byte LE residues."""
+    raw = b"".join(v.to_bytes(_FR_BYTES, "little") for v in values)
+    return np.frombuffer(raw, dtype=np.uint8)
+
+
+class RescueTrace:
+    """A width-4 Rescue permutation's trace in native code, value for value
+    `circuits/merkle_witness.permutation_trace`: called on a state, the list
+    of 4 + 12 x rounds ints. The instance's constants (modulus, round keys,
+    MDS matrix, the two S-box exponents) are packed once; a call shares
+    nothing else, and releases the GIL while it runs."""
+
+    WIDTH = 4
+
+    def __init__(self, modulus, round_keys, mds, alpha, alpha_inv):
+        if (len(mds) != self.WIDTH or len(round_keys) % 2 != 1
+                or any(len(row) != self.WIDTH
+                       for row in list(mds) + list(round_keys))):
+            raise ValueError("a width-4 Rescue instance takes a 4x4 MDS "
+                             "matrix and 2 x rounds + 1 keys of 4")
+        self.modulus = modulus
+        self.rounds = len(round_keys) // 2
+        self._length = self.WIDTH + 3 * self.WIDTH * self.rounds
+        self._consts = [_elements([modulus]),
+                        _elements([x for key in round_keys for x in key]),
+                        _elements([x for row in mds for x in row]),
+                        _elements([alpha]), _elements([alpha_inv])]
+
+    def __call__(self, state):
+        if len(state) != self.WIDTH:
+            raise ValueError(f"a state of {len(state)} elements, not 4")
+        inp = _elements([x % self.modulus for x in state])
+        out = np.empty(self._length * _FR_BYTES, dtype=np.uint8)
+        if lib().rescue_trace(*map(_u8, self._consts), self.rounds,
+                              _u8(inp), _u8(out)) != 0:
+            raise ValueError("rescue_trace: a constant is not below the "
+                             "modulus, or the modulus is not odd and "
+                             "below 2^255")
+        raw = out.tobytes()
+        return [int.from_bytes(raw[i:i + _FR_BYTES], "little")
+                for i in range(0, len(raw), _FR_BYTES)]
 
 
 # --- transport ---------------------------------------------------------------

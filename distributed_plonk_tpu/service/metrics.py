@@ -64,6 +64,11 @@ Job lifecycle (service/server.py, service/pool.py, service/queue.py):
                                                      distinct tree node of a
                                                      job (the plain build of
                                                      a miss is not in it)
+    circuit_build_permutations_native                of those, the ones whose
+                                                     trace the native code
+                                                     computed (runtime/
+                                                     native.RescueTrace): all
+                                                     of them
 
 Scheduler + shape buckets (service/scheduler.py):
     batches_dispatched / batch_size                  shape-batch activity

@@ -10,6 +10,8 @@
 //     -> a minimal length-prefixed framed message transport (TCP_NODELAY),
 //        enough to express the dispatcher<->worker control plane; bulk
 //        data rides the same frames
+// and one piece of the witness build: a Rescue permutation's trace in
+// Montgomery arithmetic, over constants the caller hands in.
 //
 // Exposed as a C ABI for ctypes (no pybind11 in this environment).
 //
@@ -224,5 +226,199 @@ int dpt_set_timeout(int fd, int ms) {
 }
 
 int dpt_close(int fd) { return close(fd); }
+
+// --- Rescue permutation trace ------------------------------------------------
+// One Rescue-Prime permutation of a width-4 state, recorded as the values
+// rescue.permutation_gadget creates, in its order (the Python oracle is
+// circuits/merkle_witness.py::permutation_trace): the key-0 injection, then
+// per round the forward half-round's outputs, the inverse S-box's roots and
+// the affine layer's outputs, 4 + 12 x rounds values. Every constant comes
+// from the caller: the odd modulus p < 2^255, the 2 x rounds + 1 round keys
+// (4 each), the 4x4 MDS matrix row-major, and the two S-box exponents. An
+// element is a 32-byte little-endian residue, canonical (< p) on the way in
+// and out; inside, 4 x 64-bit limbs in Montgomery form. Stateless, so
+// threads may call it at once. Returns 0, or -1 where the modulus is not
+// odd and below 2^255 or an input element is not below it.
+
+static const int kLimbs = 4;   // 64-bit limbs of an element
+static const int kWidth = 4;   // state width
+
+typedef unsigned __int128 u128;
+
+static const uint64_t kOne[kLimbs] = {1, 0, 0, 0};
+
+struct Field {
+    uint64_t p[kLimbs];
+    uint64_t n0;               // -p^-1 mod 2^64
+    uint64_t r2[kLimbs];       // 2^512 mod p: into Montgomery form
+};
+
+static void load_le(uint64_t* x, const uint8_t* b) {
+    for (int i = 0; i < kLimbs; ++i) {
+        x[i] = 0;
+        for (int k = 7; k >= 0; --k) x[i] = (x[i] << 8) | b[8 * i + k];
+    }
+}
+
+static void store_le(uint8_t* b, const uint64_t* x) {
+    for (int i = 0; i < kLimbs; ++i)
+        for (int k = 0; k < 8; ++k) b[8 * i + k] = (uint8_t)(x[i] >> (8 * k));
+}
+
+static bool geq(const uint64_t* a, const uint64_t* b) {
+    for (int i = kLimbs - 1; i >= 0; --i)
+        if (a[i] != b[i]) return a[i] > b[i];
+    return true;
+}
+
+static void sub_in_place(uint64_t* a, const uint64_t* b) {
+    uint64_t borrow = 0;
+    for (int i = 0; i < kLimbs; ++i) {
+        u128 d = (u128)a[i] - b[i] - borrow;
+        a[i] = (uint64_t)d;
+        borrow = (uint64_t)(d >> 64) & 1;
+    }
+}
+
+// a + b mod p; a, b < p < 2^255, so the sum has no carry out of 256 bits
+static void add_mod(uint64_t* out, const uint64_t* a, const uint64_t* b,
+                    const Field& f) {
+    uint64_t carry = 0;
+    for (int i = 0; i < kLimbs; ++i) {
+        u128 s = (u128)a[i] + b[i] + carry;
+        out[i] = (uint64_t)s;
+        carry = (uint64_t)(s >> 64);
+    }
+    if (geq(out, f.p)) sub_in_place(out, f.p);
+}
+
+// a * b / 2^256 mod p (CIOS); out may alias a or b
+static void mont_mul(uint64_t* out, const uint64_t* a, const uint64_t* b,
+                     const Field& f) {
+    uint64_t t[kLimbs + 2] = {0, 0, 0, 0, 0, 0};
+    for (int i = 0; i < kLimbs; ++i) {
+        uint64_t c = 0;
+        for (int j = 0; j < kLimbs; ++j) {
+            u128 s = (u128)a[j] * b[i] + t[j] + c;
+            t[j] = (uint64_t)s;
+            c = (uint64_t)(s >> 64);
+        }
+        u128 s = (u128)t[kLimbs] + c;
+        t[kLimbs] = (uint64_t)s;
+        t[kLimbs + 1] = (uint64_t)(s >> 64);
+        const uint64_t m = t[0] * f.n0;
+        s = (u128)m * f.p[0] + t[0];
+        c = (uint64_t)(s >> 64);
+        for (int j = 1; j < kLimbs; ++j) {
+            s = (u128)m * f.p[j] + t[j] + c;
+            t[j - 1] = (uint64_t)s;
+            c = (uint64_t)(s >> 64);
+        }
+        s = (u128)t[kLimbs] + c;
+        t[kLimbs - 1] = (uint64_t)s;
+        t[kLimbs] = t[kLimbs + 1] + (uint64_t)(s >> 64);
+    }
+    if (t[kLimbs] || geq(t, f.p)) sub_in_place(t, f.p);   // t < 2p
+    memcpy(out, t, sizeof(uint64_t) * kLimbs);
+}
+
+static bool field_init(Field& f, const uint8_t* modulus) {
+    load_le(f.p, modulus);
+    if (!(f.p[0] & 1) || (f.p[kLimbs - 1] >> 63)) return false;
+    uint64_t inv = 1;                          // Newton: 1, 2, 4 ... 64 bits
+    for (int i = 0; i < 6; ++i) inv *= 2 - f.p[0] * inv;
+    f.n0 = (uint64_t)0 - inv;
+    uint64_t x[kLimbs];
+    memcpy(x, kOne, sizeof(x));
+    for (int i = 0; i < 512; ++i) {            // 2^512 mod p by doubling
+        uint64_t top = 0;
+        for (int j = 0; j < kLimbs; ++j) {
+            const uint64_t out = x[j] >> 63;
+            x[j] = (x[j] << 1) | top;
+            top = out;
+        }
+        if (geq(x, f.p)) sub_in_place(x, f.p);
+    }
+    memcpy(f.r2, x, sizeof(x));
+    return true;
+}
+
+// a canonical element from its bytes into Montgomery form; false if >= p
+static bool load_mont(uint64_t* x, const uint8_t* b, const Field& f) {
+    load_le(x, b);
+    if (geq(x, f.p)) return false;
+    mont_mul(x, x, f.r2, f);
+    return true;
+}
+
+static void store_canonical(uint8_t* b, const uint64_t* x, const Field& f) {
+    uint64_t y[kLimbs];
+    mont_mul(y, x, kOne, f);
+    store_le(b, y);
+}
+
+// x^e, x in Montgomery form, e a 256-bit little-endian exponent
+static void mont_pow(uint64_t* out, const uint64_t* x, const uint64_t* e,
+                     const uint64_t* one_m, const Field& f) {
+    int top = 64 * kLimbs - 1;
+    while (top >= 0 && !((e[top / 64] >> (top % 64)) & 1)) --top;
+    uint64_t acc[kLimbs];
+    memcpy(acc, one_m, sizeof(acc));
+    for (int i = top; i >= 0; --i) {
+        mont_mul(acc, acc, acc, f);
+        if ((e[i / 64] >> (i % 64)) & 1) mont_mul(acc, acc, x, f);
+    }
+    memcpy(out, acc, sizeof(acc));
+}
+
+int rescue_trace(const uint8_t* modulus, const uint8_t* round_keys,
+                 const uint8_t* mds, const uint8_t* alpha,
+                 const uint8_t* alpha_inv, uint64_t rounds,
+                 const uint8_t* state, uint8_t* trace) {
+    Field f;
+    if (!field_init(f, modulus)) return -1;
+    uint64_t one_m[kLimbs], e_fwd[kLimbs], e_inv[kLimbs];
+    mont_mul(one_m, kOne, f.r2, f);
+    load_le(e_fwd, alpha);
+    load_le(e_inv, alpha_inv);
+    const int el = 8 * kLimbs;                 // bytes of an element
+    uint64_t m[kWidth][kWidth][kLimbs];
+    for (int i = 0; i < kWidth; ++i)
+        for (int j = 0; j < kWidth; ++j)
+            if (!load_mont(m[i][j], mds + (i * kWidth + j) * el, f)) return -1;
+    uint64_t s[kWidth][kLimbs], t[kWidth][kLimbs], key[kLimbs];
+    uint64_t emitted = 0;
+    for (int i = 0; i < kWidth; ++i) {
+        if (!load_mont(s[i], state + i * el, f)) return -1;
+        if (!load_mont(key, round_keys + i * el, f)) return -1;
+        add_mod(s[i], s[i], key, f);
+        store_canonical(trace + el * emitted++, s[i], f);
+    }
+    // out = MDS x in + the key row k; false if a key is not below p
+    auto affine = [&](uint64_t (*out)[kLimbs], uint64_t (*in)[kLimbs],
+                      uint64_t k) -> bool {
+        for (int i = 0; i < kWidth; ++i) {
+            if (!load_mont(out[i], round_keys + (k * kWidth + i) * el, f))
+                return false;
+            for (int j = 0; j < kWidth; ++j) {
+                uint64_t prod[kLimbs];
+                mont_mul(prod, m[i][j], in[j], f);
+                add_mod(out[i], out[i], prod, f);
+            }
+            store_canonical(trace + el * emitted++, out[i], f);
+        }
+        return true;
+    };
+    for (uint64_t r = 0; r < rounds; ++r) {
+        for (int i = 0; i < kWidth; ++i) mont_pow(t[i], s[i], e_fwd, one_m, f);
+        if (!affine(s, t, 2 * r + 1)) return -1;
+        for (int i = 0; i < kWidth; ++i) {
+            mont_pow(t[i], s[i], e_inv, one_m, f);
+            store_canonical(trace + el * emitted++, t[i], f);
+        }
+        if (!affine(s, t, 2 * r + 2)) return -1;
+    }
+    return 0;
+}
 
 }  // extern "C"
