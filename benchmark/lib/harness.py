@@ -200,16 +200,21 @@ class Session:
         if trace:
             os.environ["DPT_JAX_TRACE"] = "1"
         self.devs, self.device, self.peaks = look_for_chip(cell, require_tpu)
-        # a worker for each of the oracle's proves, which run side by side
+        # a worker for each of the oracle's proves, which run side by side,
+        # and each prove fanned out over the host's usable cores less two
+        # for the service's set-up, split between the proves
+        self.oracle_workers = max(
+            1, (len(os.sched_getaffinity(0)) - 2) // self.oracle_jobs)
         self.ref = RefPool(
             max(ref_workers, self.oracle_jobs) if ref_workers else 0,
-            os.path.join(root, M.bench_dir(man), ".state", "reference"))
+            os.path.join(root, M.bench_dir(man), ".state", "reference"),
+            fanout=self.oracle_workers)
 
     def oracle(self, seed, precision="full"):
         """Start the host oracle's prove of the sampled jobs of a window of
         `seed` (the first job of each sampled client): {client: future}.
-        They run in jax-free workers beside the set-up (105 s at 2^14, each
-        on one of the host's cores, none of it on the chip) and are read
+        They run in jax-free workers beside the set-up, each fanned out
+        over `oracle_workers` more, none of it on the chip, and are read
         only once the window has closed."""
         return {c: self.ref.oracle_proof(
             W.draw_spec(self.cell.job_mix, seed, "window", c, 0), precision)
@@ -319,7 +324,11 @@ class Session:
                           and served.proof is not None else None,
                           got["proof"]))
             st = (served.status or {}) if served is not None else {}
+            # on the one monotonic clock: negative where the prove ran on
+            # into the window, beside the service under test
             say(phase="oracle", client=c, oracle_prove_s=got["seconds"],
+                oracle_workers=self.oracle_workers,
+                ended_before_window_s=win.t_open - got["ended"],
                 placement=st.get("placement"), batch_size=st.get("batch_size"),
                 pipelined=any(k.endswith("_finalize")
                               for k in st.get("rounds") or ()))

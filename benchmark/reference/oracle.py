@@ -6,7 +6,9 @@ so it is the program's own second engine and not an independent one: what
 the byte comparison holds the served path to is the device arithmetic and
 every later change of the program, not a fault the two engines shared on
 the day of the copy. The independent side is `benchmark/plain`. All of it
-runs in jax-free worker processes (`benchmark/lib/refpool.py`). A spec is
+runs in jax-free worker processes (`benchmark/lib/refpool.py`), and a prove
+may fan its independent pieces out over more (`benchmark/lib/fanout.py`),
+with the same bytes. A spec is
 the wire dict the client SUBMITs: `{"kind": "merkle", "height": h,
 "num_proofs": p, "seed": s}` (or the toy kind the CPU tests use).
 """
@@ -17,6 +19,7 @@ import pickle
 import random
 import time
 
+from ..lib import fanout
 from . import kzg
 from .backend.python_backend import PythonBackend
 from .circuit import PlonkCircuit
@@ -112,10 +115,16 @@ def _keys(spec, cache_dir):
     return _KEYS[name]
 
 
-def oracle_proof(spec, cache_dir=None, precision="full"):
+def oracle_proof(spec, cache_dir=None, precision="full", workers=0):
     """The bytes the host oracle serves for this spec: the circuit and the
     blinding both drawn from `spec["seed"]`, as the service's pool worker
-    draws them. Returns {"proof": bytes, "seconds": float}.
+    draws them. Returns {"proof": bytes, "seconds": float, "ended": the
+    `time.monotonic()` at which the prove finished}.
+
+    workers=0 proves on the serial `PythonBackend`, the yardstick of the
+    tests; with `workers` the prove's independent pieces run on a pool of
+    that many spawned processes (`fanout.FanoutBackend`), and the bytes
+    are the same.
 
     precision="reused_blinding" is the control: the same prove blinded from
     one fixed seed and not from the job's, a proof that verifies and is not
@@ -128,6 +137,13 @@ def oracle_proof(spec, cache_dir=None, precision="full"):
         rng = random.Random(REUSED_BLINDING_SEED)
     elif precision != "full":
         raise ValueError(f"unknown precision {precision!r}")
-    proof = prove(rng, build_circuit(spec), pk, PythonBackend())
-    return {"proof": serialize_proof(proof),
-            "seconds": time.monotonic() - t0}
+    ckt = build_circuit(spec)
+    if workers:
+        with fanout.pool(workers) as executor:
+            proof = prove(rng, ckt, pk,
+                          fanout.FanoutBackend(executor, workers))
+    else:
+        proof = prove(rng, ckt, pk, PythonBackend())
+    ended = time.monotonic()
+    return {"proof": serialize_proof(proof), "seconds": ended - t0,
+            "ended": ended}

@@ -42,7 +42,18 @@ def test_sound_run_is_correct_and_prints_the_contracts_object(toy_root, capfd):
                                                     "answered")
                for name, c in res["checks"].items())
     assert res["checks"]["oracle_compared"] == {"value": 1, "limit": 1}
-    err = capfd.readouterr().err.strip().splitlines()
+    out, err = capfd.readouterr()
+    err = err.strip().splitlines()
+    # the oracle's prove, fanned out by the width rule, ended before the
+    # window opened, and its line says so on the one monotonic clock
+    oracle = [json.loads(l) for l in out.splitlines()
+              if '"phase": "oracle"' in l]
+    assert len(oracle) == 1
+    jobs = M.load_json(os.path.join(toy_root, "benchmark", "configs",
+                                    "toy.json"))["check"]["oracle_jobs"]
+    assert oracle[0]["oracle_workers"] == max(
+        1, (len(os.sched_getaffinity(0)) - 2) // jobs)
+    assert oracle[0]["ended_before_window_s"] >= 0
     assert err[-1] == "correct: true"
     assert err[-2].startswith("check answered: ")
     json.dumps(res)

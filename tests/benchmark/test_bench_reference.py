@@ -6,18 +6,25 @@ which it has to agree with without sharing a line; its proving side
 the control of the comparison that decides `correct`, at a size a test can
 hold."""
 
+import functools
 import hashlib
 import os
 import random
 import subprocess
 import sys
+import time
+from concurrent.futures import Executor, Future
 
 import pytest
 
 from bench_toy import REPO, TOY_JOB
-from benchmark.lib import check, manifest, served
+from benchmark.lib import check, manifest, refpool, served
+from benchmark.lib.fanout import FanoutBackend, ranges
 from benchmark.plain import bls, merlin, statement, verifier
-from benchmark.reference import oracle
+from benchmark.reference import curve as C, oracle
+from benchmark.reference.backend.python_backend import PythonBackend
+from benchmark.reference.constants import R_MOD
+from benchmark.reference.poly import Domain
 
 REF = os.path.join(REPO, "benchmark", "reference")
 TAU = 0xDEADBEEF
@@ -43,6 +50,7 @@ def test_the_proving_side_is_a_copy_of_the_host_oracle():
 
 @pytest.mark.parametrize("module, banned", [
     ("benchmark.reference.oracle", ("jax", "distributed_plonk_tpu")),
+    ("benchmark.lib.fanout", ("jax", "distributed_plonk_tpu", "numpy")),
     ("benchmark.plain.verifier", ("jax", "distributed_plonk_tpu",
                                   "benchmark.reference", "numpy")),
     ("benchmark.plain.statement", ("jax", "distributed_plonk_tpu",
@@ -192,3 +200,120 @@ def test_wrong_answers_are_told_from_right_ones(tmp_path):
     assert check.sample_clients(5, 4, 1) == check.sample_clients(5, 4, 1)
     assert check.sample_clients(5, 4, 4) == [0, 1, 2, 3]
     assert len(check.sample_clients(5, 4, 9)) == 4
+
+
+class _Inline(Executor):
+    """An executor that runs each task as it is submitted."""
+
+    def submit(self, fn, /, *args, **kwargs):
+        fut = Future()
+        fut.set_result(fn(*args, **kwargs))
+        return fut
+
+
+@functools.cache
+def _serial_proof(spec_items, precision="full"):
+    return oracle.oracle_proof(dict(spec_items), precision=precision)["proof"]
+
+
+@pytest.mark.parametrize("workers", [2, 3])
+@pytest.mark.parametrize("seed", [8, 2 ** 31 + 9])
+@pytest.mark.parametrize("job", [TOY_JOB, MERKLE], ids=["toy", "merkle"])
+def test_the_fanout_prove_has_the_serial_bytes(job, seed, workers):
+    spec = dict(job, seed=seed)
+    got = oracle.oracle_proof(spec, workers=workers)
+    assert got["proof"] == _serial_proof(tuple(sorted(spec.items())))
+    assert got["ended"] <= time.monotonic()
+
+
+@pytest.mark.parametrize("seed", [3, 2 ** 31 + 4])
+def test_the_control_through_the_fanout_is_still_not_correct(seed):
+    spec = dict(TOY_JOB, seed=seed)
+    ctl = oracle.oracle_proof(spec, precision="reused_blinding", workers=2)
+    assert ctl["proof"] == _serial_proof(tuple(sorted(spec.items())),
+                                         "reused_blinding")
+    full = _serial_proof(tuple(sorted(spec.items())))
+    assert check.byte_diffs(ctl["proof"], full) > 100
+
+
+def _points(count):
+    pts = [C.g1_mul(C.G1_GEN, 3 * i + 1) for i in range(count)]
+    pts[count // 2] = C.INF              # the SRS's zero padding
+    return pts
+
+
+@pytest.mark.parametrize("points, width, lengths", [
+    (11, 3, [11, 11, 11]),               # uneven ranges: 4, 4, 3
+    (11, 4, [11, 7, 0]),                 # lists shorter than the key
+    (3, 5, [3, 2]),                      # fewer points than workers
+    (1, 2, [1]),
+], ids=["uneven", "short-lists", "fewer-points", "one-point"])
+def test_a_chunked_commit_is_the_frozen_msm(points, width, lengths):
+    ck = _points(points)
+    rng = random.Random(points * 100 + width)
+    lists = [[rng.choice((0, 0, 1, R_MOD - 1, rng.randrange(R_MOD)))
+              for _ in range(n)] for n in lengths]
+    lists.append([0] * lengths[0])       # every scalar zero: infinity
+    want = [C.g1_msm(ck[:len(s)], s) for s in lists]
+    assert FanoutBackend(_Inline(), width).commit_many(ck, lists) == want
+    assert want[-1] is None
+    # handles a coefficient short of the key, as the prover's are
+    hs = [s[:max(1, len(s) - 1)] for s in lists if s]
+    assert FanoutBackend(_Inline(), width).commit_many_h(ck, hs) == \
+        PythonBackend().commit_many_h(ck, hs)
+
+
+@pytest.mark.parametrize("width", [3, 17, 32])
+def test_the_chunked_quotient_is_the_frozen_loop(width):
+    """n = 16 on a quotient domain of 128, so z is read 8 places on and
+    wraps for the top 8 indices: widths 17 and 32 put a range's boundary
+    inside that wrap (121 and 124), 3 does not."""
+    n, m = 16, 128
+    dom = Domain(m)
+    rng = random.Random(width)
+
+    def plane():
+        return [rng.randrange(R_MOD) for _ in range(m)]
+
+    args = (n, m, dom, [rng.randrange(R_MOD) for _ in range(5)],
+            rng.randrange(R_MOD), rng.randrange(R_MOD), rng.randrange(R_MOD),
+            rng.randrange(R_MOD), [plane() for _ in range(13)],
+            [plane() for _ in range(5)], [plane() for _ in range(5)], plane(),
+            plane())
+    bounds = [hi for _lo, hi in ranges(m, width)][:-1]
+    assert any(m - m // n < b < m for b in bounds) == (width != 3)
+    assert FanoutBackend(_Inline(), width).quotient(*args) == \
+        PythonBackend().quotient(*args)
+
+
+def test_a_killed_pool_leaves_no_fanout_process_behind():
+    """`RefPool.close(kill=True)` in the middle of a fanned-out prove ends
+    the worker and every process of the pool below it."""
+    ref = refpool.RefPool(1, None, fanout=2)
+    try:
+        fut = ref.oracle_proof(dict(MERKLE, seed=31))
+        worker = list(ref._pool._processes.values())[0].pid
+        below = []
+        deadline = time.monotonic() + 240
+        while len(below) < 2 and not fut.done() and \
+                time.monotonic() < deadline:
+            time.sleep(0.05)
+            below = refpool.descendants([worker])
+        assert len(below) >= 2, "the prove never fanned out"
+    finally:
+        ref.close(kill=True)
+    deadline = time.monotonic() + 30
+    left = [pid for pid in below + [worker] if _running(pid)]
+    while left and time.monotonic() < deadline:
+        time.sleep(0.1)
+        left = [pid for pid in left if _running(pid)]
+    assert left == []
+
+
+def _running(pid):
+    """Alive and not a zombie waiting to be reaped."""
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            return f.read().rpartition(")")[2].split()[0] != "Z"
+    except OSError:
+        return False
