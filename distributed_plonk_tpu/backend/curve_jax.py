@@ -300,6 +300,23 @@ def batch_to_affine(p):
     return ax, ay, inf
 
 
+def batch_to_host_affine(p, width):
+    """Jacobian (24, n) Montgomery on the device -> list[(x, y) | None] of
+    n host points, normalized by `batch_to_affine` (one field inverse
+    crosses to the host, not one a point). The points are padded with the
+    identity to `width` >= n first, so that a caller runs the two programs
+    at a width they are compiled for already."""
+    n = p[0].shape[1]
+    assert width >= n
+    if width > n:
+        p = tuple(jnp.pad(c, ((0, 0), (0, width - n))) for c in p)
+    ax, ay, inf = batch_to_affine(p)
+    xs = limbs_to_ints(np.asarray(ax)[:, :n])
+    ys = limbs_to_ints(np.asarray(ay)[:, :n])
+    return [None if i else (x * _MONT_R_INV % Q_MOD, y * _MONT_R_INV % Q_MOD)
+            for x, y, i in zip(xs, ys, np.asarray(inf)[:n].tolist())]
+
+
 # --- host boundary helpers (tests / debugging; oracle-grade, not hot) --------
 
 def affine_to_device(points):

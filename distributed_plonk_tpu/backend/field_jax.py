@@ -88,6 +88,13 @@ configure_compile_cache(os.environ.get(
     "DPT_JAX_CACHE_DIR",
     os.path.normpath(os.path.join(
         os.path.dirname(__file__), "..", "..", ".jax_cache"))))
+# A Pallas kernel's Mosaic body rides in its program's compile-cache key
+# with the source locations of the trace. With full tracebacks those hold
+# every frame down from whoever first called the program, so a program that
+# a key build traces first and a later process reaches from a prove would
+# be two cache entries, compiled again on every restart. Innermost frames
+# only: the key is the program's own, whoever traced it.
+jax.config.update("jax_include_full_tracebacks_in_locations", False)
 
 from ..constants import (
     LIMB_BITS,

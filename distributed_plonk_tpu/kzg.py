@@ -109,10 +109,18 @@ class DeviceSrs:
         self.tau_g2 = tau_g2
 
     def powers_affine(self):
-        """Host affine list (test/oracle boundary only: one inversion per
-        point on the host)."""
+        """Host affine list, normalized on the device with one batched
+        inversion, at the width of the commit key `pad_commit_key` makes of
+        these powers: the width whose programs the key's window table runs
+        (backend/msm_jax.window_table)."""
         from .backend import curve_jax as CJ
-        return CJ.device_to_affine(self.jac_powers)
+        return CJ.batch_to_host_affine(
+            self.jac_powers, self.count + (-self.count) % 32)
+
+    def to_host(self):
+        """The UniversalSrs the host walk gives for the same tau: the same
+        affine powers, so the same commit key layout and store blob."""
+        return UniversalSrs(self.powers_affine(), self.g2, self.tau_g2)
 
 
 def universal_setup_device(max_degree, rng=None, tau=None):

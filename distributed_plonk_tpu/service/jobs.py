@@ -213,15 +213,34 @@ def build_circuit(spec, metrics=None):
     return merkle_witness.build(spec.params, spec.seed, metrics=metrics)
 
 
-def build_bucket_keys(spec, backend=None):
+def build_bucket_keys(spec, backend=None, metrics=None):
     """(srs, pk, vk) for a spec's SHAPE — seed-independent, so the server's
     scheduler and a verifying client derive identical keys. Uses the
-    canonical seed-0 circuit purely as the structure donor."""
+    canonical seed-0 circuit purely as the structure donor.
+
+    On the jax backend the [tau^i]G1 walk is one device batch
+    (kzg.universal_setup_device: seconds where the host walk is minutes at
+    2^16) and comes back as the host affine powers the host walk gives, so
+    the key has the one layout a store load gives too; any other backend
+    (None, PythonBackend) walks on the host. `metrics` observes
+    bucket_build_srs and bucket_build_preprocess and counts
+    bucket_builds_srs_device."""
     from .. import kzg
     canonical = JobSpec(spec.kind, dict(spec.params), seed=0)
     ckt = build_circuit(canonical)
-    srs = kzg.universal_setup(ckt.n + 3, tau=TEST_TAU)
+    t0 = time.monotonic()
+    on_device = getattr(backend, "name", None) == "jax"
+    if on_device:
+        srs = kzg.universal_setup_device(ckt.n + 3, tau=TEST_TAU).to_host()
+    else:
+        srs = kzg.universal_setup(ckt.n + 3, tau=TEST_TAU)
+    t1 = time.monotonic()
     pk, vk = kzg.preprocess(srs, ckt, backend=backend)
+    if metrics is not None:
+        metrics.observe("bucket_build_srs", t1 - t0)
+        metrics.observe("bucket_build_preprocess", time.monotonic() - t1)
+        if on_device:
+            metrics.inc("bucket_builds_srs_device")
     return srs, pk, vk
 
 

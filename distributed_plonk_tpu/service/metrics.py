@@ -117,6 +117,12 @@ Placement + cross-job batched proving (service/placement.py, pool.py):
                                                      in-flight key setup
     bucket_mem_evictions / buckets_resident (gauge)  memory-tier LRU
     bucket_build / bucket_disk_load (histograms)     tier latencies
+    bucket_build_srs / bucket_build_preprocess       a build's parts: the
+      / bucket_build_store (histograms)              SRS walk, the 18 iFFTs
+                                                     and commits, the store
+                                                     write
+    bucket_builds_srs_device                         builds whose SRS came
+                                                     from the device walk
     bucket_build_errors                              key builds that failed
     store_write_errors                               best-effort artifact
                                                      writes that failed

@@ -69,7 +69,9 @@ class BucketCache:
     needs the cap). Tier 2 is the on-disk ArtifactStore (`store`), where
     keys persist across process restarts and are shared with warmup jobs;
     integrity failures there self-heal (the corrupt entry is deleted and
-    the build tier repopulates it). Tier 3 is `jobs.build_bucket_keys`.
+    the build tier repopulates it). Tier 3 is `jobs.build_bucket_keys`,
+    on `backend` (the service's own, from start_service: the jax backend
+    walks the SRS on the device) or on the host where there is none.
 
     Concurrency: the load/peer-fetch/build tiers run OUTSIDE the cache
     lock behind a per-key latch. Concurrent first-touch of one shape
@@ -82,7 +84,8 @@ class BucketCache:
     Metrics: bucket_hits (memory), bucket_disk_hits, bucket_misses
     (full build), bucket_latch_waits (blocked on another caller's
     in-flight setup of the same shape), bucket_mem_evictions, plus the
-    store's own store_* counters/gauges.
+    store's own store_* counters/gauges; a build's seconds by part in
+    bucket_build_srs / _preprocess / _store.
     """
 
     def __init__(self, metrics, backend=None, store=None, max_entries=None,
@@ -189,18 +192,22 @@ class BucketCache:
                                        meta.get("build_s") or 0.0), "disk"
         self.metrics.inc("bucket_misses")
         t0 = time.monotonic()
-        srs, pk, vk = J.build_bucket_keys(spec, backend=self.backend)
+        srs, pk, vk = J.build_bucket_keys(spec, backend=self.backend,
+                                          metrics=self.metrics)
         build_s = time.monotonic() - t0
         self.metrics.observe("bucket_build", build_s)
         res = BucketResources(key, srs, pk, vk, vk.domain_size, build_s)
         if self.store is not None:
             # persistence is best-effort: a full disk or unwritable store
             # must degrade to cold starts, never fail the build's jobs
+            t0 = time.monotonic()
             try:
                 KC.store_bucket(self.store, key, srs, pk, vk,
                                 build_s=build_s)
             except Exception:  # pragma: no cover - environmental
                 self.metrics.inc("store_write_errors")
+            self.metrics.observe("bucket_build_store",
+                                 time.monotonic() - t0)
         return res, "built"
 
     # per-peer dial+transfer budget for the fetch tier. Peer fetch runs

@@ -58,11 +58,12 @@ def start_service(backend="jax", **service_kwargs):
     start-up path scripts/serve.py and chip_smoke.py share. Every pool
     worker proves on the SAME backend instance, so SRS, proving keys and
     domain tables live on the device once however many worker threads
-    feed it (JaxBackend's caches are lock-guarded for exactly this).
+    feed it (JaxBackend's caches are lock-guarded for exactly this), and a
+    shape's key build runs on it too (jobs.build_bucket_keys).
     Returns (service, runtime) where runtime names what the backend it
     built runs on: {"backend", "platform", "device_kind", "devices"}."""
     be = make_backend(backend)
-    svc = ProofService(backend_factory=lambda: be, **service_kwargs).start()
+    svc = ProofService(backend=be, **service_kwargs).start()
     return svc, dict(be.device_info(), backend=be.name)
 
 
@@ -75,7 +76,7 @@ class ProofService:
                  store_dir=None, store_byte_budget=None, bucket_cap=64,
                  store_peers=None, faults=None, journal_dir=None,
                  devices=None, mesh_backend_factory=None,
-                 self_verify=None, verify_remote=False):
+                 self_verify=None, verify_remote=False, backend=None):
         self.host = host
         self.port = port
         self.chaos = chaos
@@ -109,6 +110,13 @@ class ProofService:
             self.journal = JN.JobJournal(journal_dir, metrics=self.metrics,
                                          retain_terminal=finished_retention,
                                          chaos=self.faults)
+        # backend: ONE instance every pool worker proves on and every key
+        # build runs on (start_service's); backend_factory alone gives each
+        # worker its own and leaves the key builds on the host
+        if backend is not None:
+            if backend_factory is not None:
+                raise TypeError("give backend or backend_factory, not both")
+            backend_factory = lambda: backend
         self.pool = WorkerPool(
             self.metrics, prover_workers=prover_workers,
             max_retries=max_retries, job_timeout_s=job_timeout_s,
@@ -121,8 +129,8 @@ class ProofService:
         # bucket miss tries a network copy from a warm peer before paying
         # for a full key build (elastic scale-out: a fresh host serves
         # warm after one fetch)
-        self.buckets = BucketCache(self.metrics, store=self.store,
-                                   max_entries=bucket_cap,
+        self.buckets = BucketCache(self.metrics, backend=backend,
+                                   store=self.store, max_entries=bucket_cap,
                                    peers=store_peers)
         # placement-aware scheduling (service/placement.py): small shape
         # buckets prove data-parallel (cross-job batched kernel launches,

@@ -39,8 +39,12 @@ def test_served_flow_jax_backend_matches_oracle(aot_warm):
     assert metrics["counters"]["jobs_completed"] == 1
     # a proof commits 13 polynomials (5 wires, the permutation product, 5
     # quotient splits, 2 openings), counted by the commit context of the
-    # job's key; a toy key is under 256 points and holds no window table
-    assert metrics["counters"]["msm_commit_polys"] == 13
+    # job's key, which the key build filled first with its 18 selector and
+    # sigma commitments: start_service builds a new shape's key on the
+    # backend it proves on, SRS walk included. A toy key is under 256
+    # points and holds no window table
+    assert metrics["counters"]["bucket_builds_srs_device"] == 1
+    assert metrics["counters"]["msm_commit_polys"] == 18 + 13
     assert "msm_commit_polys_preweighted" not in metrics["counters"]
     assert runtime["backend"] == "jax" and runtime["platform"] == "cpu"
     assert runtime["domain_size"] == 16
@@ -145,6 +149,49 @@ def test_auto_msm_lowers_for_tpu(monkeypatch):
     assert "tpu_custom_call" in mlir
 
 
+_TRACE_FROM = """
+import hashlib, os, re, sys
+sys.path.insert(0, sys.argv[2])
+import jax, jax.numpy as jnp
+from jax import export
+from distributed_plonk_tpu.backend import field_jax as FJ
+jax.default_backend = lambda: "tpu"
+arg = jax.ShapeDtypeStruct((FJ.FQ.n_limbs, 1 << 13), jnp.uint32)
+program = FJ.named_jit("probe", lambda a, b: FJ.mont_mul(FJ.FQ, a, b))
+
+
+def lower():
+    return export.export(program, platforms=["tpu"])(arg, arg).mlir_module()
+
+
+def from_a_key_build():
+    def build():
+        return lower()
+    return build()
+
+
+text = lower() if sys.argv[1] == "prove" else from_a_key_build()
+body = "".join(re.findall(r'backend_config = "([^"]*)"', text))
+print(len(body), hashlib.sha256(body.encode()).hexdigest())
+"""
+
+
+def test_a_pallas_programs_cache_key_does_not_follow_its_caller():
+    """A Pallas kernel's Mosaic body is part of its program's compile-cache
+    key, with the source locations of the trace in it. A key build on the
+    device traces programs that a prove uses too; where those locations
+    held the frames of the caller, a restart that first reached the same
+    program from the prover missed the cache and compiled it again. Two
+    processes lower one program for the TPU from two call paths: one
+    Mosaic body."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", DPT_PALLAS_INTERPRET="0")
+    bodies = [subprocess.run(
+        [sys.executable, "-c", _TRACE_FROM, path, str(REPO)], env=env,
+        capture_output=True, text=True, timeout=300, check=True,
+        cwd=str(REPO)).stdout.split()[-2:] for path in ("prove", "build")]
+    assert int(bodies[0][0]) > 0 and bodies[0] == bodies[1]
+
+
 def test_compile_cache_env_is_never_overridden(monkeypatch, tmp_path):
     """With JAX_COMPILATION_CACHE_DIR set, no code path repoints
     jax.config.jax_compilation_cache_dir: the guard lives inside
@@ -214,7 +261,7 @@ def test_daemon_defaults_share_one_jax_backend():
 
     # daemon defaults (two workers, max_batch 8); queued before the
     # scheduler starts so the three pop as one shape batch
-    svc = ProofService(port=0, backend_factory=lambda: be)
+    svc = ProofService(port=0, backend=be)
     jobs = [svc.submit_local(s) for s in specs[2:]]
     svc.start()
     try:

@@ -225,7 +225,7 @@ def jax_service():
     from distributed_plonk_tpu.service import ProofService
     be = JaxBackend()
     svc = ProofService(port=0, prover_workers=1, self_verify="1",
-                       backend_factory=lambda: be)
+                       backend=be)
     yield svc, be
     svc.pool.shutdown()
     be.device_ledger.close_threads()

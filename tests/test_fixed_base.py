@@ -33,6 +33,20 @@ def test_device_srs_matches_host_setup():
     assert srs_d.tau_g2 == srs_h.tau_g2
 
 
+def test_device_srs_over_several_chunks_matches_host_setup(monkeypatch):
+    """A walk longer than one device call (2^16 keys take three of 2^15
+    lanes) pads its last chunk with zero scalars and slices them off; the
+    powers, normalized at the width of their commit key, are the host's."""
+    from distributed_plonk_tpu.backend.fixed_base import FixedBaseContext
+    monkeypatch.setattr(FixedBaseContext, "_CHUNK", 16)
+    srs_h = kzg.universal_setup(40, tau=987654321)
+    srs_d = kzg.universal_setup_device(40, tau=987654321)
+    assert srs_d.count == 41 and srs_d.jac_powers[0].shape == (24, 41)
+    host = srs_d.to_host()
+    assert host.powers_of_g1 == srs_h.powers_of_g1
+    assert host.tau_g2 == srs_h.tau_g2 and host.g2 == srs_h.g2
+
+
 @pytest.mark.tier2
 def test_device_preprocess_matches_host(proven_inputs):
     """Device SRS + backend preprocess produce the identical pk/vk (and so

@@ -176,7 +176,7 @@ def served_backend():
     be = JaxBackend()
     assert be.metrics is None
     svc = ProofService(port=0, prover_workers=1,
-                       backend_factory=lambda: be).start()
+                       backend=be).start()
     try:
         for _ in range(600):
             if be.metrics is not None:

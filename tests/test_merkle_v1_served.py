@@ -41,7 +41,10 @@ def test_a_served_v1_job_equals_the_oracle_and_verifies_plainly():
     """SUBMIT -> RESULT over TCP on the jax backend (XLA:CPU here), one job
     alone in the service as the one-client cell sends them: pool placement,
     four commits of one device call each, all thirteen polynomials from the
-    window table (a key of 1,027 points is wide enough for one)."""
+    window table (a key of 1,027 points is wide enough for one). The key
+    was built on the same backend first: its SRS walk on the device, its
+    18 selector and sigma commitments in three calls (8 + 8 + 2) through
+    the same commit context."""
     import chip_smoke
     from benchmark.lib import readers, served
     from benchmark.plain import statement
@@ -61,10 +64,11 @@ def test_a_served_v1_job_equals_the_oracle_and_verifies_plainly():
                                job["header"]["public_input"], TAU) == {
         "pub_equal": True, "verified": True, "why": ""}
     counters = metrics["counters"]
-    assert counters["msm_commit_calls"] == 4
-    assert counters["msm_commit_chunks"] == 4
-    assert counters["msm_commit_polys"] == 13
-    assert counters["msm_commit_polys_preweighted"] == 13
+    assert counters["bucket_builds_srs_device"] == 1
+    assert counters["msm_commit_calls"] == 3 + 4
+    assert counters["msm_commit_chunks"] == 3 + 4
+    assert counters["msm_commit_polys"] == 18 + 13
+    assert counters["msm_commit_polys_preweighted"] == 18 + 13
     with open(os.path.join(REPO, "benchmark", "layer_metrics",
                            "msm_chunks_pct.json")) as f:
         chunks_pct = json.load(f)

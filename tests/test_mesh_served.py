@@ -34,7 +34,7 @@ def served():
     mp.setattr(PL, "LARGE_MIN", 512)
     mp.setattr(PL, "MESH_LEASE", 2)
     be = JaxBackend()
-    svc = ProofService(port=0, prover_workers=2, backend_factory=lambda: be,
+    svc = ProofService(port=0, prover_workers=2, backend=be,
                        devices=jax.devices()[:2]).start()
     try:
         before = svc.metrics.snapshot()["counters"]

@@ -111,10 +111,14 @@ def test_tcp_round_trip_and_bucket_reuse(service):
         m = c.metrics()
     # two shapes -> exactly two key builds, the same-shape job reused one:
     # from memory, or, when the second worker took it while the first was
-    # still building (a loaded machine), by waiting on that build's latch
+    # still building (a loaded machine), by waiting on that build's latch,
+    # or, when the scheduler popped both same-shape jobs as one batch (a
+    # slow scheduler thread), through that batch's one lookup
     assert m["counters"]["bucket_misses"] == 2
-    assert (m["counters"]["bucket_hits"]
-            + m["counters"].get("bucket_latch_waits", 0)) >= 1
+    sizes = m["histograms"]["batch_size"]
+    rode_along = round(sizes["sum_s"]) - sizes["count"]
+    assert (m["counters"].get("bucket_hits", 0)
+            + m["counters"].get("bucket_latch_waits", 0) + rode_along) >= 1
     assert m["counters"]["jobs_completed"] == 3
     assert "queue_depth" in m["gauges"]
     assert m["histograms"]["job_wait"]["count"] == 3
